@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..parallel.mesh import shard_map
+from ..parallel.mesh import mesh_jit_kwargs, shard_map
 
 from .. import types as T
 from ..columnar import ColumnarBatch, DeviceColumn
@@ -636,7 +636,7 @@ class TpuMeshAggregateExec(_MeshStage):
                     in_specs=tuple([P(AXIS)] * nin + [P(AXIS)]),
                     out_specs=P(AXIS),
                 )
-                return jax.jit(fn), out_layouts
+                return jax.jit(fn, **mesh_jit_kwargs()), out_layouts
 
             sig = tuple((str(a.dtype), a.shape) for a in global_cols)
             fn, out_layouts = _cached_program(
@@ -753,7 +753,7 @@ class TpuMeshSortExec(_MeshStage):
                 return jax.jit(shard_map(
                     shard_fn, mesh=mesh,
                     in_specs=tuple([P(AXIS)] * (nin + 1)),
-                    out_specs=P(AXIS))), out_layouts
+                    out_specs=P(AXIS)), **mesh_jit_kwargs()), out_layouts
 
             sig = tuple((str(a.dtype), a.shape) for a in global_cols)
             fn, out_layouts = _cached_program(
@@ -873,7 +873,7 @@ class TpuMeshWindowExec(_MeshStage):
                 return jax.jit(shard_map(
                     shard_fn, mesh=mesh,
                     in_specs=tuple([P(AXIS)] * (nin + 1)),
-                    out_specs=P(AXIS))), out_layouts
+                    out_specs=P(AXIS)), **mesh_jit_kwargs()), out_layouts
 
             sig = tuple((str(a.dtype), a.shape) for a in global_cols)
             fn, out_layouts = _cached_program(
@@ -1027,7 +1027,7 @@ class TpuMeshHashJoinExec(_MeshStage):
                 return jax.jit(shard_map(
                     shard_fn, mesh=mesh,
                     in_specs=tuple([P(AXIS)] * nin),
-                    out_specs=P(AXIS))), out_layouts
+                    out_specs=P(AXIS)), **mesh_jit_kwargs()), out_layouts
 
             out_layouts: dict = {}
             sig = (

@@ -372,17 +372,23 @@ def _feeds_iota(mod: Module, ins: Instr) -> bool:
     CPU while-lowering feeds its carry as one tuple) is an iota — the
     signature of a ROW-INDEX update stream, which data scatters never
     have."""
-    for op in ins.operands:
-        ref = mod.by_name.get(op)
+    def is_iota(ref: Optional[Instr]) -> bool:
         if ref is None:
-            continue
+            return False
         if ref.opcode == "iota":
             return True
-        if ref.opcode in ("tuple", "fusion"):
-            for op2 in ref.operands:
-                r2 = mod.by_name.get(op2)
-                if r2 is not None and r2.opcode == "iota":
-                    return True
+        # jax 0.9's CPU dialect wraps the bare iota in an operand-less
+        # kLoop fusion (%wrapped_iota = fusion(), calls=...)
+        return (ref.opcode == "fusion" and not ref.operands and any(
+            "iota" in _opcode_bag(mod, c) for c in ref.called))
+
+    for op in ins.operands:
+        ref = mod.by_name.get(op)
+        if is_iota(ref):
+            return True
+        if ref is not None and ref.opcode in ("tuple", "fusion"):
+            if any(is_iota(mod.by_name.get(op2)) for op2 in ref.operands):
+                return True
     return False
 
 

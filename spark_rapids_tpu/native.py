@@ -16,10 +16,13 @@ from typing import Optional
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
+#: why the library is unavailable (compiler stderr / loader error) — kept
+#: so callers can REPORT a missing host decoder instead of guessing
+_LOAD_ERROR: Optional[str] = None
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _LOAD_ERROR
     with _LOCK:
         if _TRIED:
             return _LIB
@@ -55,13 +58,22 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
             _LIB = lib
-        except Exception:
+        except Exception as e:
             _LIB = None
+            stderr = getattr(e, "stderr", None)
+            _LOAD_ERROR = (stderr.decode(errors="replace").strip()
+                           if stderr else f"{type(e).__name__}: {e}")
         return _LIB
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> Optional[str]:
+    """The build/load failure that made the library unavailable (None
+    when it loaded, or before the first load attempt)."""
+    return _LOAD_ERROR
 
 
 def lz4_compress(data: bytes) -> bytes:

@@ -50,8 +50,20 @@ BLOCK_B = 256
 _U32_MAX = 0xFFFFFFFF
 
 
+#: what Mosaic (jax 0.9.0 / libtpu 0.0.34, asked for a v5e without the
+#: chip, PR 23) said of these kernels at cap 2^21, B=128: the sum/float
+#: kernels die on the x64 index maps ("failed to legalize operation
+#: 'func.return' ... (i32, i64)"), the winner kernel on its u32 min/max
+MOSAIC_REFUSAL = (
+    "failed to legalize operation 'func.return' (i32, i64) [sum/float "
+    "kernels]; Reductions over unsigned integers not implemented "
+    "[winner kernel]")
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    from ..envinfo import pallas_interpret
+
+    return pallas_interpret("ops/pallas_groupby", MOSAIC_REFUSAL)
 
 
 def _pad_rows(arrs: Sequence[jax.Array], n: int, r: int, fill):

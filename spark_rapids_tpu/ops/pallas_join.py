@@ -28,6 +28,17 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..envinfo import pallas_interpret
+
+#: what Mosaic (jax 0.9.0 / libtpu 0.0.34, asked for a v5e without the
+#: chip, PR 23) said of the probe kernel at 2^21 x 2^21 rows
+MOSAIC_REFUSAL = "RecursionError: maximum recursion depth exceeded"
+
+
+def _interpret() -> bool:
+    return pallas_interpret("ops/pallas_join", MOSAIC_REFUSAL)
+
+
 #: probe rows / build rows per grid step (the VMEM equality-mask extent)
 BLOCK_P = 256
 BLOCK_BUILD = 256
@@ -104,7 +115,7 @@ def pallas_probe_ranges(
         ],
         out_specs=(pl.BlockSpec((rp,), lambda pi, bi: (pi,)),
                    pl.BlockSpec((rp,), lambda pi, bi: (pi,))),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
     )(phi_p, plo_p, plive_p, bhi_p, blo_p, blive_p)
     first, cnt = first[:m], cnt[:m]
     lo = jnp.where(cnt > 0, first, 0)

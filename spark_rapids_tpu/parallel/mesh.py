@@ -6,10 +6,9 @@ Reference analog: GpuShuffleEnv / the UCX transport bring-up
 devices, collectives riding ICI. There is no connection establishment, no
 management port, no bounce-buffer pool to size; XLA owns the wire.
 
-This module is also the ONE home of the jax version shim for
-``shard_map`` (moved between jax releases, and the replication-check
-kwarg was renamed) — every caller (exec/mesh.py, the tests, the dryrun)
-imports it from here instead of guessing the jax API.
+This module also owns the engine's ``shard_map`` wrapper (replication
+check off) — every caller (exec/mesh.py, the tests, the dryrun) imports
+it from here.
 """
 from __future__ import annotations
 
@@ -17,13 +16,7 @@ from typing import Optional
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.6: top-level export, check_vma kwarg
-    from jax import shard_map as _shard_map_impl  # type: ignore[attr-defined]
-    _SM_KW = {"check_vma": False}
-except ImportError:  # older jax: experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SM_KW = {"check_rep": False}
+from jax import shard_map as _shard_map_impl
 
 AXIS = "shards"
 
@@ -31,11 +24,28 @@ _MESH_CACHE: dict = {}
 
 
 def shard_map(f, mesh, in_specs, out_specs, **_ignored):
-    """Version-portable ``shard_map`` with the replication check off (row
-    counts vary per shard; the static check can't see through the
-    sort/segment kernels). Extra kwargs from either API era are ignored."""
+    """``jax.shard_map`` with the replication check off (row counts vary
+    per shard; the static check can't see through the sort/segment
+    kernels). Extra kwargs are ignored."""
     return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_SM_KW)
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)
+
+
+def mesh_jit_kwargs() -> dict:
+    """Extra ``jax.jit`` keywords for a ``shard_map`` program. On the TPU
+    the compiler's ``conditional-code-motion`` pass rewrites a conditional
+    operand that carries a 64-bit value (a u32 pair after the x64
+    rewrite) into a 4-tuple its branch computation does not take, and the
+    next ``HloReplicationAnalysis`` CHECK-fails — the whole process
+    aborts (found compiling ``TpuMeshAggregateExec`` for four v5e chips,
+    jax 0.9.0 / libtpu 0.0.34, PR 23; single-device programs run no such
+    analysis). The pass is an optimisation; with it off the program
+    compiles."""
+    if jax.default_backend() == "tpu":
+        return {"compiler_options": {
+            "xla_disable_hlo_passes": "conditional-code-motion"}}
+    return {}
 
 
 def device_count() -> int:

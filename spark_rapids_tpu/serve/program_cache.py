@@ -157,23 +157,18 @@ def _register_pytree_serialization() -> None:
     if _PYTREES_REGISTERED:
         return
     _PYTREES_REGISTERED = True
-    try:
-        from jax import export as _export
+    from jax import export as _export
 
-        from ..expr.values import ColV, DictV, StrV
+    from ..expr.values import ColV, DictV, StrV
 
-        _export.register_namedtuple_serialization(
-            ColV, serialized_name="srtpu.ColV")
-        _export.register_namedtuple_serialization(
-            StrV, serialized_name="srtpu.StrV")
-        _export.register_pytree_node_serialization(
-            DictV, serialized_name="srtpu.DictV",
-            serialize_auxdata=lambda aux: json.dumps(list(aux)).encode(),
-            deserialize_auxdata=lambda b: tuple(json.loads(b.decode())))
-    except Exception:
-        # older jax without the registration API: string/dict programs
-        # fall back to plain compilation, fixed-width ones still cache
-        pass
+    _export.register_namedtuple_serialization(
+        ColV, serialized_name="srtpu.ColV")
+    _export.register_namedtuple_serialization(
+        StrV, serialized_name="srtpu.StrV")
+    _export.register_pytree_node_serialization(
+        DictV, serialized_name="srtpu.DictV",
+        serialize_auxdata=lambda aux: json.dumps(list(aux)).encode(),
+        deserialize_auxdata=lambda b: tuple(json.loads(b.decode())))
 
 
 # ---------------------------------------------------------------------------
@@ -824,24 +819,21 @@ def install(conf_: RapidsConf) -> Optional[ProgramCache]:
         _register_pytree_serialization()
         import jax
 
-        try:
-            if _PREV_JAX_CACHE is None:
-                _PREV_JAX_CACHE = (
-                    jax.config.jax_compilation_cache_dir,
-                    jax.config.jax_persistent_cache_min_entry_size_bytes,
-                    jax.config.jax_persistent_cache_min_compile_time_secs)
+        if _PREV_JAX_CACHE is None:
+            _PREV_JAX_CACHE = (
+                jax.config.jax_compilation_cache_dir,
+                jax.config.jax_persistent_cache_min_entry_size_bytes,
+                jax.config.jax_persistent_cache_min_compile_time_secs)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            # an operator-chosen JAX_COMPILATION_CACHE_DIR is THE cache
+            # directory: the backend compiles land there and no code
+            # points jax elsewhere
             jax.config.update("jax_compilation_cache_dir",
                               os.path.join(cache.dir, "xla"))
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:
-            # older jax without the persistent-cache knobs (the
-            # snapshot reads degrade too, not just the updates): export
-            # artifacts still skip the re-trace, the backend compile
-            # just isn't disk-cached
-            pass
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", 0.0)
         _ACTIVE = cache
         _ENABLED = True
         return cache
@@ -858,12 +850,9 @@ def uninstall() -> None:
             import jax
 
             d, sz, secs = _PREV_JAX_CACHE
-            try:
-                jax.config.update("jax_compilation_cache_dir", d)
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", sz)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", secs)
-            except Exception:
-                pass
+            jax.config.update("jax_compilation_cache_dir", d)
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes", sz)
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", secs)
             _PREV_JAX_CACHE = None

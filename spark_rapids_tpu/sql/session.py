@@ -483,24 +483,29 @@ class TpuSession:
                      sql_hash=hashlib.sha1(
                          repr(node).encode()).hexdigest()[:12],
                      env=_envinfo.environment_info())
-        meta = self.overrides.last_meta
-        if meta is not None:
-            fallbacks = []
-
-            def walk(m):
-                if m.reasons:
-                    name = m.rule.name if m.rule else m.wrapped.node_name
-                    fallbacks.append({"op": name,
-                                      "reasons": list(m.reasons)})
-                for c in m.child_metas:
-                    walk(c)
-
-            walk(meta)
+        if self.overrides.last_meta is not None:
             _events.emit("plan_tagged", query_id=qid, on_tpu=is_tpu,
-                         fallbacks=fallbacks)
+                         fallbacks=self.plan_fallbacks())
         if self.last_analysis is not None:
             _events.emit("plan_analysis", query_id=qid,
                          **self.last_analysis.event_fields())
+
+    def plan_fallbacks(self) -> List[dict]:
+        """Every operator of the last tagged plan that stays on the CPU,
+        with its reasons: ``[{"op": name, "reasons": [...]}]`` (empty =
+        the whole plan runs on the device)."""
+        fallbacks: List[dict] = []
+
+        def walk(m):
+            if m.reasons:
+                name = m.rule.name if m.rule else m.wrapped.node_name
+                fallbacks.append({"op": name, "reasons": list(m.reasons)})
+            for c in m.child_metas:
+                walk(c)
+
+        if self.overrides.last_meta is not None:
+            walk(self.overrides.last_meta)
+        return fallbacks
 
     _PENDING_UNSET = object()
 

@@ -22,6 +22,7 @@ from __future__ import annotations
 from typing import Callable, Optional, Sequence
 
 from .. import types as T
+from ..envinfo import pallas_interpret
 from ..expr import expressions as E
 
 
@@ -71,6 +72,16 @@ def _is_space(b):
     )
 
 
+#: what Mosaic (jax 0.9.0 / libtpu 0.0.34, asked for a v5e without the
+#: chip, PR 23) said of the word-start kernel over 2^24 chars
+MOSAIC_REFUSAL = (
+    "Not implemented: changeBitwidth when minor tiling is not 128")
+
+
+def _interpret() -> bool:
+    return pallas_interpret("udf/native word_starts", MOSAIC_REFUSAL)
+
+
 def _word_starts_pallas(chars):
     """(nchars,) int32 word-start flags via the Pallas kernel (interpret
     mode off-TPU so the same kernel runs under the CPU test mesh)."""
@@ -85,7 +96,7 @@ def _word_starts_pallas(chars):
     # byte BEFORE each position (space before position 0: row handling is
     # done by the ragged reduction, which re-bases at row starts)
     prev = jnp.concatenate([jnp.full(1, 0x20, jnp.uint8), c[:-1]])
-    interpret = jax.default_backend() not in ("tpu",)
+    interpret = _interpret()
     flags = pl.pallas_call(
         _word_start_kernel,
         out_shape=jax.ShapeDtypeStruct((total,), jnp.int32),

@@ -73,7 +73,6 @@ ROOFLINE_PEAK_TFLOPS = conf(
 #: figure so the fallback backend still classifies limiters
 BACKEND_PEAKS: Dict[str, Tuple[float, float]] = {
     "tpu": (819.0, 197.0),
-    "gpu": (900.0, 19.5),
     "cpu": (100.0, 1.0),
 }
 

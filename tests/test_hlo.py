@@ -850,7 +850,9 @@ ENTRY %main (off: s64[4096,1], finit: s32[16384], cinit: s32[16384], ones: s32[4
 
 def test_compiled_direct_join_build_classifies_join_table():
     """The REAL compiled direct-address build (this backend's dialect —
-    on CPU a pair of while/DUS loops) must classify join-table end to
+    on CPU, jax 0.9.0, a pair of `wrapped_scatter` fusions whose update
+    stream is an operand-less `wrapped_iota` fusion) must classify
+    join-table end to
     end, and a min+count scatter AGGREGATION over data values must NOT
     (the iota update stream is the discriminator)."""
     import jax

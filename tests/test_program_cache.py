@@ -506,6 +506,19 @@ def test_uninstall_restores_jax_cache_config(tmp_path):
     assert jax.config.jax_compilation_cache_dir == before
 
 
+def test_install_leaves_an_operator_chosen_jax_cache_dir_alone(
+        tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: no code points jax elsewhere."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "op"))
+    before = jax.config.jax_compilation_cache_dir
+    PC.install(RapidsConf(_conf(tmp_path)))
+    try:
+        assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        PC.uninstall()
+    assert jax.config.jax_compilation_cache_dir == before
+
+
 # ---------------------------------------------------------------------------
 # 8. the mesh tuple path + single-flight + diff gates
 # ---------------------------------------------------------------------------
