@@ -115,13 +115,22 @@ class ColumnarBatch:
         copy is one contiguous DMA, GpuColumnarToRowExec.scala:38)."""
         import jax
 
-        leaves = list(leaves)
-        if len(leaves) <= 1:
-            return [jax.device_get(x) for x in leaves]
-        from concurrent.futures import ThreadPoolExecutor
+        from ..exec.base import phase
 
-        with ThreadPoolExecutor(max_workers=min(16, len(leaves))) as pool:
-            return list(pool.map(jax.device_get, leaves))
+        leaves = list(leaves)
+        # the d2h boundary of whichever exec pulls (the collect boundary,
+        # mostly): it ends when the data is on the host
+        with phase("d2h") as span:
+            if span.on:
+                span.set(bytes=sum(
+                    int(getattr(x, "nbytes", 0)) for x in leaves))
+            if len(leaves) <= 1:
+                return [jax.device_get(x) for x in leaves]
+            from concurrent.futures import ThreadPoolExecutor
+
+            with ThreadPoolExecutor(
+                    max_workers=min(16, len(leaves))) as pool:
+                return list(pool.map(jax.device_get, leaves))
 
     def host_columns(self) -> List[Any]:
         """Fetch every column (and a lazy row count) in ONE round trip —

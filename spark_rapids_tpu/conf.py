@@ -200,8 +200,13 @@ SEMAPHORE_ACQUIRE_TIMEOUT_MS = conf(
     check=lambda v: None if v >= 0 else "must be >= 0")
 ENABLE_TRACE = conf(
     "spark.rapids.tpu.sql.trace.enabled", False,
-    "Wrap operator hot sections in jax.profiler TraceAnnotations "
-    "(reference: NvtxWithMetrics.scala).")
+    "Wrap operator hot sections and the host phases inside them (file "
+    "read, page planning, host decode, upload, merge parts, d2h) in "
+    "jax.profiler TraceAnnotations named <Exec>.<section>, each carrying "
+    "query=<id> and its counts (bytes, columns, cache hits); one "
+    "TpuSession.query span a query (reference: NvtxWithMetrics.scala). "
+    "Off, no annotation is built; program and scope names are always on. "
+    "docs/tuning.md lists the names.")
 METRICS_DEVICE_SYNC = conf(
     "spark.rapids.tpu.metrics.deviceSync.enabled", False,
     "Device-accurate operator timing: every operator blocks until its "

@@ -34,6 +34,7 @@ from .base import (
     batch_from_vals,
     batch_signature,
     count_scalar,
+    program,
     vals_of_batch,
 )
 
@@ -234,27 +235,32 @@ def _agg_pipeline(
     chain_t = tuple(chain)
 
     def build():
+        @program("agg_update")
         def run(cols, num_rows, side_args):
             from ..ops.filter_gather import elide_validity, live_of
 
             live = live_of(num_rows, cap)
             cols = elide_validity(cols, live, nonnull)
-            for e, s in zip(chain_t, side_args):
-                cols, live = e.lower_batch(cols, live, cap, s)
-            keys = [lower(e, cols, cap) for e in key_exprs]
-            vals: List[Optional[ColV]] = []
-            for e in value_exprs:
-                vals.append(None if e is None else lower(e, cols, cap))
-            if key_exprs:
-                return groupby_ops.groupby_agg(
-                    keys, list(key_dtypes), vals, list(ops), live,
-                    str_max_lens, approx_float_sum=approx_float_sum,
-                    str_val_max_lens=str_val_max_lens,
-                    strategy=strategy,
-                )
-            outs = groupby_ops.reduce_no_keys(
-                vals, list(ops), live, str_val_max_lens=str_val_max_lens)
-            return [], outs, jnp.int32(1)
+            with jax.named_scope("fused_chain"):
+                for e, s in zip(chain_t, side_args):
+                    cols, live = e.lower_batch(cols, live, cap, s)
+            with jax.named_scope("agg_update"):
+                keys = [lower(e, cols, cap) for e in key_exprs]
+                vals: List[Optional[ColV]] = []
+                for e in value_exprs:
+                    vals.append(
+                        None if e is None else lower(e, cols, cap))
+                if key_exprs:
+                    return groupby_ops.groupby_agg(
+                        keys, list(key_dtypes), vals, list(ops), live,
+                        str_max_lens, approx_float_sum=approx_float_sum,
+                        str_val_max_lens=str_val_max_lens,
+                        strategy=strategy,
+                    )
+                outs = groupby_ops.reduce_no_keys(
+                    vals, list(ops), live,
+                    str_val_max_lens=str_val_max_lens)
+                return [], outs, jnp.int32(1)
 
         return jax.jit(run, donate_argnums=donate)
 
@@ -286,13 +292,18 @@ def _fused_agg_trace(key_exprs, key_dts, value_exprs, update_ops, merge_ops,
         a_ = groupby_ops.reduce_no_keys(vals, list(ops_), live)
         return list(a_), jnp.int32(1)
 
+    # the phases carry the words their programs carry when they run alone
+    # (base.SCOPE_WORDS), so a trace of the fused program still tells
+    # decode, chain, update and merge apart
     def update_batch(cols, live, cap, side_args):
-        for e, s in zip(chain_t, side_args):
-            cols, live = e.lower_batch(cols, live, cap, s)
-        keys = [lower(e, cols, cap) for e in key_exprs]
-        vals = [None if e is None else lower(e, cols, cap)
-                for e in value_exprs]
-        return agg_once(keys, vals, update_ops, live)
+        with jax.named_scope("fused_chain"):
+            for e, s in zip(chain_t, side_args):
+                cols, live = e.lower_batch(cols, live, cap, s)
+        with jax.named_scope("agg_update"):
+            keys = [lower(e, cols, cap) for e in key_exprs]
+            vals = [None if e is None else lower(e, cols, cap)
+                    for e in value_exprs]
+            return agg_once(keys, vals, update_ops, live)
 
     def finish(partial_sets):
         if len(partial_sets) == 1:
@@ -308,15 +319,17 @@ def _fused_agg_trace(key_exprs, key_dts, value_exprs, update_ops, merge_ops,
             counts = [p[1] for p in partial_sets]
             pcaps = [p[0][0].validity.shape[0] for p in partial_sets]
             out_cap = choose_capacity(sum(pcaps), bucket_min)
-            cols2, mask, _ = concat_ops.concat_padded_cols(
-                col_parts, counts, out_cap)
-            merged_vals, nseg = agg_once(
-                cols2[:nkeys], cols2[nkeys:], merge_ops, mask)
+            with jax.named_scope("agg_merge"):
+                cols2, mask, _ = concat_ops.concat_padded_cols(
+                    col_parts, counts, out_cap)
+                merged_vals, nseg = agg_once(
+                    cols2[:nkeys], cols2[nkeys:], merge_ops, mask)
         if eval_exprs is not None:
             ocap = (merged_vals[0].validity.shape[0]
                     if merged_vals else 1)
-            return [lower(e, merged_vals, ocap)
-                    for e in eval_exprs], nseg
+            with jax.named_scope("project"):
+                return [lower(e, merged_vals, ocap)
+                        for e in eval_exprs], nseg
         return merged_vals, nseg
 
     return update_batch, finish
@@ -598,9 +611,10 @@ class TpuHashAggregateExec(TpuExec):
         the device (a host pull costs a host round trip per batch)."""
         caps = [max(1, b.capacity) for b in partials]
         out_cap = choose_capacity(sum(caps), self.conf.shape_bucket_min)
-        cols, mask, total = concat_ops.concat_padded_cols(
-            [vals_of_batch(b) for b in partials],
-            [count_scalar(b.num_rows_lazy) for b in partials], out_cap)
+        with self.section("merge.concat"):
+            cols, mask, total = concat_ops.concat_padded_cols(
+                [vals_of_batch(b) for b in partials],
+                [count_scalar(b.num_rows_lazy) for b in partials], out_cap)
         merged_in = batch_from_vals(cols, self._buffer_schema, total)
         nk = len(self._key_fields)
         merge_exprs: List[Optional[E.Expression]] = [
@@ -613,8 +627,9 @@ class TpuHashAggregateExec(TpuExec):
             for i, f in enumerate(self._key_fields)
         ]
         try:
-            return self._run_batch(
-                merged_in, self._merge_ops, merge_exprs, live=mask)
+            with self.section("merge.reduce"):
+                return self._run_batch(
+                    merged_in, self._merge_ops, merge_exprs, live=mask)
         finally:
             self._bound_keys = saved_bound
 
@@ -627,7 +642,8 @@ class TpuHashAggregateExec(TpuExec):
         if len(partials) > 1:
             from .base import materialized_batch
 
-            partials = [materialized_batch(b) for b in partials]
+            with self.section("merge.materialize"):
+                partials = [materialized_batch(b) for b in partials]
         str_cols = [
             j for j, f in enumerate(self._buffer_schema.fields)
             if isinstance(f.dataType, (T.StringType, T.BinaryType))
@@ -648,16 +664,22 @@ class TpuHashAggregateExec(TpuExec):
             # length (each separate pull pays a host round trip)
             from .base import host_pull
 
-            head = [count_scalar(b.num_rows_lazy) for b in partials]
             nb = len(partials)
-            for b in partials:
-                for j in str_cols:
-                    c = b.columns[j]
-                    nr = b.num_rows_lazy
-                    idx = (min(nr, c.offsets.shape[0] - 1)
-                           if isinstance(nr, int) else nr)
-                    head.append(c.offsets[idx])
-            pulled = [int(x) for x in host_pull(head)]
+            with self.section("merge.lengths"):
+                # one eager slice a partial and string column: a dispatch
+                # each, before the pull can start
+                head = [count_scalar(b.num_rows_lazy) for b in partials]
+                for b in partials:
+                    for j in str_cols:
+                        c = b.columns[j]
+                        nr = b.num_rows_lazy
+                        idx = (min(nr, c.offsets.shape[0] - 1)
+                               if isinstance(nr, int) else nr)
+                        head.append(c.offsets[idx])
+            # the one place the merge waits for the device: every update
+            # dispatched so far has to finish before the counts arrive
+            with self.section("merge.pull"):
+                pulled = [int(x) for x in host_pull(head)]
             lengths = pulled[:nb]
             for b, n in zip(partials, lengths):
                 if not isinstance(b.num_rows_lazy, int):
@@ -675,10 +697,11 @@ class TpuHashAggregateExec(TpuExec):
                 choose_capacity(max(1, sum(bl[k] for bl in byte_lengths)), 128)
                 for k in range(len(str_cols))
             ]
-            cols, n = concat_ops.concat_batches_cols(
-                [vals_of_batch(b) for b in partials], lengths, byte_lengths,
-                out_cap, out_char_caps,
-            )
+            with self.section("merge.concat"):
+                cols, n = concat_ops.concat_batches_cols(
+                    [vals_of_batch(b) for b in partials], lengths,
+                    byte_lengths, out_cap, out_char_caps,
+                )
             merged_in = batch_from_vals(cols, self._buffer_schema, n)
             nk = len(self._key_fields)
             merge_exprs: List[Optional[E.Expression]] = [
@@ -691,9 +714,9 @@ class TpuHashAggregateExec(TpuExec):
                 for i, f in enumerate(self._key_fields)
             ]
             try:
-                partials = [
-                    self._run_batch(merged_in, self._merge_ops, merge_exprs)
-                ]
+                with self.section("merge.reduce"):
+                    partials = [self._run_batch(
+                        merged_in, self._merge_ops, merge_exprs)]
             finally:
                 self._bound_keys = saved_bound
         return partials[0]
@@ -798,6 +821,7 @@ class TpuHashAggregateExec(TpuExec):
             metas = tuple(rg_meta)
             runs_t = tuple(tuple(r) for r in all_runs)
 
+            @program("agg_stage")
             def run(args_nested, side_args):
                 from ..ops.filter_gather import live_of
 
@@ -899,6 +923,7 @@ class TpuHashAggregateExec(TpuExec):
                 self.conf.shape_bucket_min, chain_t, strategy=strategy)
             caps_t = caps
 
+            @program("agg_plan")
             def run(all_cols, all_nr, side_args):
                 from ..ops.filter_gather import live_of
 
@@ -1091,8 +1116,10 @@ class TpuHashAggregateExec(TpuExec):
 
         def merge_and_eval():
             merged = self._merge(partials)
-            return merged if self.mode == A.PARTIAL \
-                else self._evaluate(merged)
+            if self.mode == A.PARTIAL:
+                return merged
+            with self.section("merge.eval"):
+                return self._evaluate(merged)
 
         with self.op_timed("merge"):
             # the merge consumes compacted partials (group-cardinality

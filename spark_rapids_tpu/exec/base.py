@@ -9,6 +9,7 @@ the exchange layer) decides where partitions run.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -256,6 +257,134 @@ def in_planning() -> bool:
     return getattr(_PLANNING, "on", False)
 
 
+# ---------------------------------------------------------------------------
+# Names on the profiler's clock. One vocabulary serves three places: a
+# program is named after its cached_pipeline site (``program``), a phase
+# inside a program is a ``jax.named_scope`` of the same word, and a host
+# span is ``<Exec>.<section>`` (op_timed). docs/tuning.md lists them.
+# ---------------------------------------------------------------------------
+#: every word a program may be jitted under: the cached_pipeline sites,
+#: plus a word for the two exchange programs whose site is None
+PROGRAM_WORDS = (
+    "agg_update", "agg_stage", "agg_plan", "pq_decode", "upload_unpack",
+    "fused_chain", "project", "sort", "window", "exchange",
+    "exchange_slice", "exchange_concat", "join", "mesh_agg", "mesh_sort",
+    "mesh_window", "mesh_join",
+)
+#: programs jitted outside cached_pipeline, under stable names of their own
+#: (columnar/column.py's dictionary expansion, expr/eval.py's evaluator)
+OTHER_PROGRAM_WORDS = ("materialize_dict", "eval_exprs")
+#: the named_scope words: the pieces a fused program is built from. A
+#: piece has one word whether it runs alone (its program's name) or fused
+SCOPE_WORDS = ("pq_decode", "upload_unpack", "fused_chain", "agg_update",
+               "agg_merge", "project")
+
+
+def program(site: str):
+    """Decorator for the callable a build site hands to ``jax.jit``: the
+    program takes its site's name, so the profiler's ``XLA Modules`` line
+    reads ``jit_<site>(<fingerprint>)`` and not ``jit_run`` for all of
+    them. A name, not a conf: it costs nothing at run time."""
+    assert site in PROGRAM_WORDS, site
+
+    def name_it(fn):
+        fn.__name__ = fn.__qualname__ = site
+        return fn
+
+    return name_it
+
+
+#: per thread: ``op`` — the exec whose op_timed section is open here (what
+#: ``phase`` times into), ``query`` — the id every span of the drain carries
+_AMBIENT = threading.local()
+
+
+def current_query() -> Optional[int]:
+    return getattr(_AMBIENT, "query", None)
+
+
+@contextlib.contextmanager
+def query_scope(qid: Optional[int]):
+    """Set once per plan+drain by the session: every span opened on this
+    thread (and on pool threads through ``carry``) carries ``query=qid``."""
+    prev = getattr(_AMBIENT, "query", None)
+    _AMBIENT.query = qid
+    try:
+        yield
+    finally:
+        _AMBIENT.query = prev
+
+
+def carry(fn: Callable) -> Callable:
+    """Bind the submitting thread's open section and query to a pool
+    task, so work on the decode/prefetch pools times into the same exec
+    and carries the same span names and ``query`` there."""
+    op = getattr(_AMBIENT, "op", None)
+    qid = getattr(_AMBIENT, "query", None)
+    if op is None and qid is None:
+        return fn
+
+    def task(*args, **kwargs):
+        prev = (getattr(_AMBIENT, "op", None),
+                getattr(_AMBIENT, "query", None))
+        _AMBIENT.op, _AMBIENT.query = op, qid
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _AMBIENT.op, _AMBIENT.query = prev
+
+    return task
+
+
+@functools.lru_cache(maxsize=None)
+def section_metric(section: str) -> str:
+    """The metric a named section times into, by one rule:
+    ``read_file`` -> ``readFileTime``, ``merge.pull`` -> ``mergePullTime``."""
+    head, *rest = section.replace(".", "_").split("_")
+    return head + "".join(w.capitalize() for w in rest) + "Time"
+
+
+def phase(section: str):
+    """A named host phase in code BELOW the exec layer (io/, columnar/):
+    times into ``section_metric(section)`` of the exec whose op_timed
+    section is open on this thread and, when that exec traces, is a span
+    ``<Exec>.<section>`` nested in it. With no exec above (a scanner
+    driven directly), nothing."""
+    op = getattr(_AMBIENT, "op", None)
+    if op is None:
+        return contextlib.nullcontext(NO_SPAN)
+    return op.section(section)
+
+
+class _Span:
+    """What an open section yields while it traces: ``set(bytes=n)``
+    attaches counts to the span (``TraceMe.set_metadata``), so a count is
+    recorded at the boundary whose work it sizes, on the trace's clock."""
+
+    __slots__ = ("_annotation",)
+    on = True
+
+    def __init__(self, annotation):
+        self._annotation = annotation
+
+    def set(self, **counts) -> None:
+        self._annotation.set_metadata(**counts)
+
+
+class _NoSpan:
+    """The same with tracing off: nothing is built. Callers guard a count
+    that is not already at hand with ``if span.on``."""
+
+    __slots__ = ()
+    on = False
+
+    def set(self, **counts) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
 class Metric:
     """One named counter. ``kind`` drives explain_metrics() formatting:
     'ns' (rendered as ms), 'bytes', or 'count'; inferred from the name so
@@ -294,20 +423,36 @@ class Metric:
 
 @contextlib.contextmanager
 def timed(metric: Optional[Metric], trace_name: str = "", trace: bool = False,
-          event_op: Optional[str] = None, event_section: str = ""):
+          event_op: Optional[str] = None, event_section: str = "",
+          owner=None, **counts):
     """Time a hot section into a metric; optionally emit a profiler range
     (reference: NvtxWithMetrics.scala -> jax.profiler.TraceAnnotation).
-    ``event_op`` (set only while event logging is on) additionally emits a
-    host-lane ``op_span`` event, so the offline timeline shares the same
-    start/dur the metric accumulated."""
-    ctx = (
-        jax.profiler.TraceAnnotation(trace_name or (metric.name if metric else "op"))
-        if trace
-        else contextlib.nullcontext()
-    )
+    The range carries ``query=<id>`` of the drain it belongs to and the
+    keyword ``counts``; the yielded span takes counts known only later
+    (``span.set(bytes=n)``). With ``trace`` off no annotation is built.
+    ``owner`` is the exec that ``phase`` sections nested on this thread
+    time into. ``event_op`` (set only while event logging is on)
+    additionally emits a host-lane ``op_span`` event, so the offline
+    timeline shares the same start/dur the metric accumulated."""
+    if trace:
+        qid = getattr(_AMBIENT, "query", None)
+        if qid is not None:
+            counts["query"] = qid
+        ctx = jax.profiler.TraceAnnotation(
+            trace_name or (metric.name if metric else "op"), **counts)
+        span = _Span(ctx)
+    else:
+        ctx = contextlib.nullcontext()
+        span = NO_SPAN
+    prev = getattr(_AMBIENT, "op", None)
+    if owner is not None:
+        _AMBIENT.op = owner
     start = time.perf_counter_ns()
-    with ctx:
-        yield
+    try:
+        with ctx:
+            yield span
+    finally:
+        _AMBIENT.op = prev
     dur = time.perf_counter_ns() - start
     if metric is not None:
         metric.add(dur)
@@ -323,8 +468,8 @@ def _op_scoped(inner, op: str):
     op=<node_name> so the roofline report can join XLA bytes/flops
     against the op's measured device lane."""
     with _xla_cost.op_scope(op):
-        with inner:
-            yield
+        with inner as span:
+            yield span
 
 
 @contextlib.contextmanager
@@ -336,8 +481,8 @@ def _obs_timed(inner, op: str, section: str):
     token = _obs.span_open(op, section)
     start = time.perf_counter_ns()
     try:
-        with inner:
-            yield
+        with inner as span:
+            yield span
     finally:
         _obs.span_close(token)
         _obs.add_op_time(op, "host", time.perf_counter_ns() - start)
@@ -469,18 +614,21 @@ class TpuExec:
             return self._register_metric(name, kind)
         return self.metrics[name]
 
-    def op_timed(self, section: str = "", metric_name: str = TOTAL_TIME):
+    def op_timed(self, section: str = "", metric_name: str = TOTAL_TIME,
+                 **counts):
         """Shared hot-section timer: host wall-clock into ``metric_name``
-        plus a profiler TraceAnnotation named after the exec when
+        plus a profiler TraceAnnotation ``<Exec>.<section>`` (with the
+        keyword ``counts`` and the drain's ``query``) when
         sql.trace.enabled is on — EVERY exec wraps its per-batch device
         work in this (reference: NvtxWithMetrics.scala pairing each hot
-        section with a GpuMetric + NVTX range)."""
+        section with a GpuMetric + NVTX range). Yields the span, for
+        counts known only at the section's end."""
         name = self.node_name + ("." + section if section else "")
         # event args attach only while logging is on, so the disabled fast
         # path is byte-for-byte the pre-event-log behavior
         ctx = timed(self.metric(metric_name), name, self._trace,
                     event_op=self.node_name if _events.enabled() else None,
-                    event_section=section)
+                    event_section=section, owner=self, **counts)
         if _obs.enabled():
             # live plane: per-op time counters + the open-span table the
             # stall watchdog samples (wrapper only exists while obs is on)
@@ -495,6 +643,11 @@ class TpuExec:
             # disabled fast path stays the plain timed() context
             ctx = _op_scoped(ctx, self.node_name)
         return ctx
+
+    def section(self, name: str, **counts):
+        """A named part of a hot section with a metric of its own
+        (``section_metric``): ``op_timed`` without the second name."""
+        return self.op_timed(name, section_metric(name), **counts)
 
     def record_batch(self, batch: ColumnarBatch) -> ColumnarBatch:
         nr = batch.num_rows_lazy
@@ -609,7 +762,8 @@ def compile_snapshot() -> tuple:
 
 
 def format_metrics(plan: TpuExec, since: Optional[tuple] = None,
-                   cost_since: Optional[int] = None) -> str:
+                   cost_since: Optional[int] = None,
+                   boundary=None) -> str:
     """Per-operator metrics report — the profiler's user-facing output
     (reference: the SQL-UI metric table GpuExec publishes per node). One
     line per exec with its metrics prettied by kind, plus a derived HBM
@@ -623,7 +777,9 @@ def format_metrics(plan: TpuExec, since: Optional[tuple] = None,
     per-op XLA-compiler columns (xla_bytes/xla_flops/xla_gbps) for
     programs harvested during this run, and a footer reports
     pipeline-cache compile misses by site plus the harvested
-    trace/compile split (relative to the ``since`` compile_snapshot)."""
+    trace/compile split (relative to the ``since`` compile_snapshot).
+    ``boundary``: the ColumnarToRowExec above the plan, whose d2h/to_rows
+    sections print on a line of their own after the tree."""
     lines: List[str] = []
     cost_recs = (_xla_cost.records_since(cost_since)
                  if cost_since is not None else [])
@@ -685,6 +841,12 @@ def format_metrics(plan: TpuExec, since: Optional[tuple] = None,
             walk(c, depth + 1)
 
     walk(plan, 0)
+    if boundary is not None:
+        parts = [f"{m.name}={m.pretty()}"
+                 for m in boundary.metrics.values() if m.value]
+        if parts:
+            lines.append(f"collect boundary ({boundary.node_name}): "
+                         + ", ".join(parts))
     base_total, base_sites = (0, {}) if since is None else since
     now_total, now_sites = COMPILE_COUNTER.snapshot()
     total = now_total - base_total
@@ -841,6 +1003,7 @@ def fused_pipeline(chain: Sequence[TpuExec], sig: tuple, cap: int,
         chain_t = tuple(chain)
         needs_compact = any(e.sparsifies for e in chain_t)
 
+        @program("fused_chain")
         def run(cols, num_rows, side_args):
             from ..ops import filter_gather
 

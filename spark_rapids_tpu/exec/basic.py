@@ -34,6 +34,7 @@ from .base import (
     TpuExec,
     batch_from_vals,
     batch_signature,
+    program,
     vals_of_batch,
 )
 
@@ -179,6 +180,7 @@ def _project_pipeline(exprs: Tuple[E.Expression, ...], sig: tuple, cap: int,
     key = (exprs, sig, cap, nonnull)
 
     def build():
+        @program("project")
         def run(cols, num_rows):
             if nonnull and any(nonnull):
                 live = filter_gather.live_of(num_rows, cap)

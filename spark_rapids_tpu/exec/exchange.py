@@ -45,6 +45,7 @@ from .base import (
     batch_from_vals,
     batch_signature,
     count_scalar,
+    program,
     timed,
     vals_of_batch,
 )
@@ -110,6 +111,7 @@ def _piece_slicer(sig: tuple, pcap: int, ccaps: Tuple[int, ...]):
     key = (sig, pcap, ccaps)
 
     def build():
+        @program("exchange_slice")
         def run(cols, start, n):
             idx = jnp.arange(pcap, dtype=jnp.int32) + start
             valid_slot = jnp.arange(pcap, dtype=jnp.int32) < n
@@ -171,6 +173,7 @@ def concat_pieces(
     key = (sigs, out_cap, out_char_caps)
 
     def build():
+        @program("exchange_concat")
         def run(col_parts, counts, byte_counts):
             return concat_ops.concat_pieces_traced(
                 col_parts, counts, byte_counts, out_cap, out_char_caps)
@@ -244,6 +247,7 @@ class TpuShuffleExchangeExec(TpuExec):
         def build():
             part = self.partitioning
 
+            @program("exchange")
             def run(cols, num_rows, map_index):
                 live = filter_gather.live_of(num_rows, cap)
                 pids = part.partition_ids(

@@ -63,6 +63,7 @@ from .base import (
     batch_from_vals,
     batch_signature,
     count_scalar,
+    program,
     timed,
     vals_of_batch,
 )
@@ -976,7 +977,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         from .base import cached_pipeline
 
         return cached_pipeline(cache, key, "join",
-                               lambda: jax.jit(fn, donate_argnums=donate),
+                               lambda: jax.jit(program("join")(fn),
+                                               donate_argnums=donate),
                                donate=donate)
 
     def _unmatched_build(self, build_cols, build_live_all, matched_any):
@@ -1061,6 +1063,7 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
                 for c in build.columns if c.is_string
             ]
 
+            @program("join")
             def expand(pcols, bcols):
                 j = jnp.arange(out_cap, dtype=jnp.int32)
                 pi = j // nb

@@ -57,7 +57,7 @@ from ..parallel.mesh import AXIS, get_mesh, row_sharding
 from ..types import StructField, StructType
 from ..utils.bucketing import bucket_rows
 from . import aggregate as XA
-from .base import TpuExec
+from .base import TpuExec, program
 
 P = jax.sharding.PartitionSpec
 
@@ -594,6 +594,7 @@ class TpuMeshAggregateExec(_MeshStage):
             group_cap = 0 if gcap >= cap else gcap
 
             def build(group_cap=group_cap, out_layouts=out_layouts):
+                @program("mesh_agg")
                 def shard_fn(*flat):
                     *colflat, cnt = flat
                     cols = self._cols_of_flat(colflat, layout)
@@ -734,6 +735,7 @@ class TpuMeshSortExec(_MeshStage):
             bucket_cap = 0 if bcap >= cap else bcap
 
             def build(bucket_cap=bucket_cap, out_layouts=out_layouts):
+                @program("mesh_sort")
                 def shard_fn(*flat):
                     *colflat, cnt = flat
                     cols = self._cols_of_flat(colflat, layout)
@@ -850,6 +852,7 @@ class TpuMeshWindowExec(_MeshStage):
             bucket_cap = 0 if bcap >= cap else bcap
 
             def build(bucket_cap=bucket_cap, out_layouts=out_layouts):
+                @program("mesh_window")
                 def shard_fn(*flat):
                     *colflat, cnt = flat
                     cols = self._cols_of_flat(colflat, layout)
@@ -991,6 +994,7 @@ class TpuMeshHashJoinExec(_MeshStage):
                 bucket_rows(c * ccap_scale, 128) for c in base_ccaps)
 
             def build(out_cap=out_cap, out_ccaps=out_ccaps, xcaps=xcaps):
+                @program("mesh_join")
                 def shard_fn(*flat):
                     nlp = sum(2 if lay[0] == "f" else 3 for lay in llay)
                     lflat = flat[:nlp]

@@ -19,7 +19,7 @@ from ..columnar.column import DeviceColumn
 from ..conf import RapidsConf
 from ..types import StructType
 from ..columnar.column import choose_capacity
-from .base import TpuExec
+from .base import TpuExec, carry
 
 SCAN_TIME = "scanTime"  # reference metric name (GpuMetricNames)
 DECODE_TIME = "tpuDecodeTime"
@@ -206,7 +206,7 @@ class TpuFileSourceScanExec(TpuExec):
         rt = getattr(self.scanner, "reader_type", lambda: "PERFILE")()
         self._consumed_splits.add(index)
         if rt != "MULTITHREADED" and self._prefetch is None:
-            return self.scanner.read_split_i(index)
+            return self._host_read_split(index)
         if self._prefetch is None:
             from concurrent.futures import ThreadPoolExecutor
 
@@ -218,8 +218,9 @@ class TpuFileSourceScanExec(TpuExec):
             # splits already drained (this one included) stay None: a
             # table rebuilt after invalidate_prefetch must not resubmit
             # reads nobody will consume again
+            read = carry(self._host_read_split)
             self._prefetch = [
-                pool.submit(self.scanner.read_split_i, i)
+                pool.submit(read, i)
                 if i not in self._consumed_splits else None
                 for i in range(self.scanner.num_splits())
             ]
@@ -227,8 +228,21 @@ class TpuFileSourceScanExec(TpuExec):
         fut = self._prefetch[index]
         self._prefetch[index] = None  # free the decoded table once consumed
         if fut is None:  # consumed marker, or invalidated mid-drain
-            return self.scanner.read_split_i(index)
+            return self._host_read_split(index)
         return fut.result()
+
+    def _host_read_split(self, index: int):
+        """The plain reader: every column of the split decodes on the
+        host (pyarrow). Named and counted like the per-column fallback
+        of the device path (io/parquet_device.py)."""
+        with self.section("host_decode",
+                          columns=len(self.output_schema.fields)):
+            return self.scanner.read_split_i(index)
+
+    def _prefetch_split_device(self, index: int):
+        """host_prefetch's task: the same phases, on a pool thread."""
+        with self.section("prefetch"):
+            return self.scanner.read_split_device(index)
 
     def _attach_partition_cols(self, batch: ColumnarBatch, pvals):
         schema = self.output_schema
@@ -378,15 +392,16 @@ class TpuFileSourceScanExec(TpuExec):
             return
         if hasattr(self.scanner, "read_split_device"):
             if self._prefetch_dev is None:
+                read_dev = carry(self._prefetch_split_device)
                 self._prefetch_dev = [
-                    _prefetch_pool().submit(
-                        self.scanner.read_split_device, i)
+                    _prefetch_pool().submit(read_dev, i)
                     if i not in self._consumed_splits else None
                     for i in range(n)
                 ]
         elif self._prefetch is None:
+            read = carry(self._host_read_split)
             self._prefetch = [
-                _prefetch_pool().submit(self.scanner.read_split_i, i)
+                _prefetch_pool().submit(read, i)
                 if i not in self._consumed_splits else None
                 for i in range(n)
             ]

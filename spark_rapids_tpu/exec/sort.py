@@ -29,6 +29,7 @@ from .base import (
     batch_from_vals,
     batch_signature,
     count_scalar,
+    program,
     timed,
     vals_of_batch,
 )
@@ -103,6 +104,7 @@ class TpuSortExec(TpuExec):
         cap = batch.capacity
         sml = self._str_lens(batch)
 
+        @program("sort")
         def run(cols, num_rows):
             live = filter_gather.live_of(num_rows, cap)
             keys = [lower(b, cols, cap) for b in self._bound]
@@ -140,7 +142,7 @@ class TpuSortExec(TpuExec):
             # the harness escalates to the typed verdict.
             return self._sort_batch(concat_batches(self.conf, pieces))
 
-        with self.op_timed():
+        with self.op_timed("sort"):
             out = with_oom_retry(self.node_name, self._sort_batch, batch,
                                  self.conf, combine=combine)
         yield self.record_batch(out)

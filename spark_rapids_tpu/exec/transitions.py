@@ -16,7 +16,7 @@ from ..columnar.batch import batch_from_rows
 from ..conf import MAX_READER_BATCH_SIZE_ROWS, RapidsConf
 from ..cpu.plan import CpuExec
 from ..types import StructType
-from .base import TpuExec
+from .base import Metric, TpuExec, section_metric, timed
 
 
 class RowToColumnarExec(TpuExec):
@@ -58,8 +58,25 @@ class ColumnarToRowExec(CpuExec):
     """Device batches -> host rows (the collect boundary)."""
 
     def __init__(self, conf: RapidsConf, tpu_child: TpuExec):
+        from ..conf import ENABLE_TRACE
+
         super().__init__(conf)
         self.tpu_child = tpu_child
+        self._trace = conf.get(ENABLE_TRACE)
+        self.metrics: dict = {}
+
+    def section(self, name: str, **counts):
+        """TpuExec.section's shape without becoming a TpuExec (this node
+        emits rows, not batches): the same ``timed()``, so the boundary's
+        sections are metrics always and spans under sql.trace.enabled,
+        and ``exec.base.phase`` sections below it (the batch's ``d2h``)
+        time into this node."""
+        metric_name = section_metric(name)
+        metric = self.metrics.get(metric_name)
+        if metric is None:
+            metric = self.metrics[metric_name] = Metric(metric_name)
+        return timed(metric, f"{self.node_name}.{name}", self._trace,
+                     owner=self, **counts)
 
     @property
     def output_schema(self) -> StructType:
@@ -79,4 +96,6 @@ class ColumnarToRowExec(CpuExec):
 
     def execute_rows_partition(self, index: int) -> Iterator[tuple]:
         for batch in self.tpu_child.execute_partition(index):
-            yield from batch.to_rows()
+            with self.section("to_rows"):
+                rows = batch.to_rows()
+            yield from rows

@@ -41,6 +41,7 @@ from .base import (
     batch_from_vals,
     batch_signature,
     count_scalar,
+    program,
     timed,
     vals_of_batch,
 )
@@ -116,7 +117,7 @@ class TpuWindowExec(TpuExec):
         cap = batch.capacity
         all_keys = self._part_keys + self._order_keys
         sml = self._str_lens(batch, all_keys)
-        run = self.window_fn(cap, sml)
+        run = program("window")(self.window_fn(cap, sml))
         key = (batch_signature(batch), cap, sml)
         # the shared pipeline-cache guard: miss accounting + the
         # compiled-program cost plane ride cached_pipeline (xla_cost.py)

@@ -858,10 +858,10 @@ def _col_to_vals(col: DeviceColumn) -> Val:
 def _compiled(exprs: Tuple[E.Expression, ...], cap: int, schema_sig: tuple):
     """One XLA executable per (bound exprs, capacity bucket, input layout)."""
 
-    def run(cols):
+    def eval_exprs(cols):
         return [lower(e, cols, cap) for e in exprs]
 
-    return jax.jit(run)
+    return jax.jit(eval_exprs)
 
 
 @functools.lru_cache(maxsize=512)
@@ -873,14 +873,14 @@ def _compiled_elided(exprs: Tuple[E.Expression, ...], cap: int,
     ops/filter_gather.elide_validity) — the traced row count makes the
     mask, so the plane is never read from HBM."""
 
-    def run(cols, num_rows):
+    def eval_exprs(cols, num_rows):
         from ..ops.filter_gather import elide_validity, live_of
 
         live = live_of(num_rows, cap)
         cols = elide_validity(cols, live, nonnull)
         return [lower(e, cols, cap) for e in exprs]
 
-    return jax.jit(run)
+    return jax.jit(eval_exprs)
 
 
 def tpu_supports(expr: E.Expression, schema: T.StructType) -> Tuple[bool, str]:

@@ -137,6 +137,8 @@ def packed_upload(host_arrays: List[np.ndarray]):
     import jax
     import jax.numpy as jnp
 
+    from ..exec.base import cached_pipeline, phase, program
+
     # staging dtype -> elements staged so far; segments stay 128-byte
     # aligned inside their buffer. Bools ride the u8 buffer.
     sizes: dict = {}
@@ -148,21 +150,24 @@ def packed_upload(host_arrays: List[np.ndarray]):
         layout.append((dt.str, off, a.shape[0], a.dtype.str))
         sizes[dt.str] = off + a.shape[0]
     order = sorted(sizes)
-    bufs = {ds: np.zeros(sizes[ds], np.dtype(ds)) for ds in order}
-    for a, (ds, off, ln, _) in zip(host_arrays, layout):
-        bufs[ds][off: off + ln] = a.reshape(-1).view(np.dtype(ds))
-    nbytes = sum(b.nbytes for b in bufs.values())
     from .. import faults as _faults
-
-    if _faults.enabled():
-        # injected host-link transfer failure (chaos testing)
-        _faults.check("transfer", "packed_upload")
     from ..memory.retry import named_oom
 
-    with named_oom("packed_upload"):
-        # the h2d staging transfers: a device allocation failure here
-        # surfaces as TpuOutOfDeviceMemory naming the site + watermark
-        devs = [jnp.asarray(bufs[ds]) for ds in order]
+    # the h2d boundary of whichever exec is open above (a scan, mostly):
+    # staging + transfer, sized by the bytes that cross the link
+    with phase("upload") as span:
+        bufs = {ds: np.zeros(sizes[ds], np.dtype(ds)) for ds in order}
+        for a, (ds, off, ln, _) in zip(host_arrays, layout):
+            bufs[ds][off: off + ln] = a.reshape(-1).view(np.dtype(ds))
+        nbytes = sum(b.nbytes for b in bufs.values())
+        span.set(bytes=int(nbytes))
+        if _faults.enabled():
+            # injected host-link transfer failure (chaos testing)
+            _faults.check("transfer", "packed_upload")
+        with named_oom("packed_upload"):
+            # the h2d staging transfers: a device allocation failure here
+            # surfaces as TpuOutOfDeviceMemory naming the site + watermark
+            devs = [jnp.asarray(bufs[ds]) for ds in order]
     from .. import events as _events
 
     if _events.enabled():
@@ -184,20 +189,23 @@ def packed_upload(host_arrays: List[np.ndarray]):
     # programs keyed on the same lengths; the miss counter makes it
     # visible in explain_metrics() instead of silent
     def build():
+        @program("upload_unpack")
         def unpack(*staged):
             outs = []
-            for ds, off, ln, dts in key:
-                seg = jax.lax.slice_in_dim(
-                    staged[order.index(ds)], off, off + ln)
-                outs.append(seg != 0 if np.dtype(dts) == np.bool_ else seg)
+            with jax.named_scope("upload_unpack"):
+                for ds, off, ln, dts in key:
+                    seg = jax.lax.slice_in_dim(
+                        staged[order.index(ds)], off, off + ln)
+                    outs.append(
+                        seg != 0 if np.dtype(dts) == np.bool_ else seg)
             return outs
 
         return jax.jit(unpack)
 
-    from ..exec.base import cached_pipeline
 
-    fn = cached_pipeline(_UNPACK_CACHE, key, "upload_unpack", build)
-    return fn(*devs)
+    with phase("unpack_dispatch"):
+        fn = cached_pipeline(_UNPACK_CACHE, key, "upload_unpack", build)
+        return fn(*devs)
 
 
 def arrow_to_batch(table_or_rb, schema: Optional[T.StructType] = None,

@@ -257,8 +257,6 @@ class ParquetScanner:
         ...format.parquet.pipeline.maxInFlight. Reference analog: the GPU
         decode half of GpuParquetScan.scala:1157 plus the coalescing
         reader's copy pipeline (:880-900)."""
-        import pyarrow.parquet as pq
-
         from ..conf import (
             PARQUET_DEVICE_DECODE,
             PARQUET_DICT_STRINGS,
@@ -272,9 +270,6 @@ class ParquetScanner:
         s = self.splits()[i]
         if not s.row_groups:
             return None, s.partition_values
-        from .scan_cache import DeviceScanCache, file_key
-
-        cache = DeviceScanCache.get_instance(self.conf)
         file_cols = [c for c in self.columns if c not in split_pcols(s)]
         nfields = [
             f for f in self.schema.fields if f.name in file_cols
@@ -283,25 +278,11 @@ class ParquetScanner:
         # not re-pay the footer parse / mmap it is cached to avoid
         # (the dict-strings flag is part of the key: the two layouts must
         # never serve each other's cached batches)
-        keys = ([file_key(s.path, rg, file_cols,
-                          "batch-dict" if dict_strings else "batch")
-                 for rg in s.row_groups] if cache is not None else None)
-        batches = [cache.get(k) for k in keys] if cache is not None else [
-            None] * len(s.row_groups)
+        cache, keys, batches = _probe_scan_cache(
+            self.conf, s, file_cols, "batch-dict" if dict_strings else "batch")
         if all(b is not None for b in batches):
             return batches, s.partition_values
-        pf = pq.ParquetFile(s.path)
-        # mmap: plan_chunk touches only the selected chunks' byte ranges,
-        # so the OS pages in just those — no O(splits x file) reads
-        import mmap
-
-        f = open(s.path, "rb")
-        try:
-            file_bytes = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
-        except ValueError:  # empty file
-            file_bytes = b""
-        finally:
-            f.close()
+        pf, file_bytes = _open_mapped(s.path)
         missing = [j for j, b in enumerate(batches) if b is None]
         gen = read_row_groups_pipelined(
             s.path, pf, [s.row_groups[j] for j in missing], file_cols,
@@ -328,8 +309,6 @@ class ParquetScanner:
         ``(num_rows, cap, entries)`` with ``entries`` =
         ``[(args, key, run, field), ...]`` per column, or None when any
         column needs the host decoder (caller uses execute_partition)."""
-        import pyarrow.parquet as pq
-
         from ..conf import PARQUET_DEVICE_DECODE, PARQUET_DICT_STRINGS
         from .parquet_device import row_group_device_plans
 
@@ -339,29 +318,14 @@ class ParquetScanner:
         s = self.splits()[i]
         if not s.row_groups or self.partition_cols:
             return None
-        from .scan_cache import DeviceScanCache, file_key
-
-        cache = DeviceScanCache.get_instance(self.conf)
         file_cols = [c for c in self.columns if c not in split_pcols(s)]
         nfields = [f for f in self.schema.fields if f.name in file_cols]
         # probe the cache BEFORE opening the file (see read_split_device)
-        keys = ([file_key(s.path, rg, file_cols,
-                          "stage-dict" if dict_strings else "stage")
-                 for rg in s.row_groups] if cache is not None else None)
-        out = [cache.get(k) for k in keys] if cache is not None else [
-            None] * len(s.row_groups)
+        cache, keys, out = _probe_scan_cache(
+            self.conf, s, file_cols, "stage-dict" if dict_strings else "stage")
         if all(x is not None for x in out):
             return out
-        pf = pq.ParquetFile(s.path)
-        import mmap
-
-        f = open(s.path, "rb")
-        try:
-            file_bytes = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
-        except ValueError:  # empty file
-            file_bytes = b""
-        finally:
-            f.close()
+        pf, file_bytes = _open_mapped(s.path)
         for i, rg in enumerate(s.row_groups):
             if out[i] is not None:
                 continue
@@ -378,6 +342,48 @@ class ParquetScanner:
             out[i] = stage
         return out
 
+
+
+def _probe_scan_cache(conf, split: FileSplit, file_cols, layout: str):
+    """(cache or None, keys, one cached value or None per row group). The
+    lookup is a span of the scan exec above, with what it found: a later
+    reader tells a warm scan from a cold one by ``cache=hit|miss``."""
+    from ..exec.base import phase
+    from .scan_cache import DeviceScanCache, file_key
+
+    cache = DeviceScanCache.get_instance(conf)
+    if cache is None:
+        return None, None, [None] * len(split.row_groups)
+    with phase("cache_lookup") as span:
+        keys = [file_key(split.path, rg, file_cols, layout)
+                for rg in split.row_groups]
+        found = [cache.get(k) for k in keys]
+        hits = sum(x is not None for x in found)
+        span.set(cache="hit" if hits == len(found) else "miss",
+                 hits=hits, lookups=len(found))
+    return cache, keys, found
+
+
+def _open_mapped(path: str):
+    """(ParquetFile, the file's bytes). mmap: plan_chunk touches only the
+    selected chunks' byte ranges, so the OS pages in just those — no
+    O(splits x file) reads (the page faults land in ``page_plan``)."""
+    import mmap
+
+    import pyarrow.parquet as pq
+
+    from ..exec.base import phase
+
+    with phase("read_file"):
+        pf = pq.ParquetFile(path)
+        f = open(path, "rb")
+        try:
+            file_bytes = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
+        except ValueError:  # empty file
+            file_bytes = b""
+        finally:
+            f.close()
+    return pf, file_bytes
 
 
 def split_pcols(split: FileSplit) -> List[str]:

@@ -686,12 +686,12 @@ def plan_decode(plan: ChunkPlan, dtype_tpu, cap: int,
             def run_empty_str(arglist):
                 return (jnp.zeros(cap + 1, jnp.int32),
                         jnp.zeros(1, jnp.uint8), jnp.zeros(cap, jnp.bool_))
-            return [], ("pqdec0", "str", cap), run_empty_str
+            return [], ("pqdec0", "str", cap), _named_decode(run_empty_str)
         dt = _PHYS_NP[plan.phys]
 
         def run_empty(arglist):
             return jnp.zeros(cap, dt), jnp.zeros(cap, jnp.bool_)
-        return [], ("pqdec0", str(dt), cap), run_empty
+        return [], ("pqdec0", str(dt), cap), _named_decode(run_empty)
 
     keep_dict = bool(dict_strings) and is_str and is_dict
     # streamed fixed-width unpack (tiled_fixed_unpack): bit-expand ->
@@ -855,7 +855,23 @@ def plan_decode(plan: ChunkPlan, dtype_tpu, cap: int,
             arr = jnp.where(validity, arr, jnp.zeros((), arr.dtype))
             return arr, validity
 
-    return args, tuple(key), (run_tiled if tiled else run)
+    return args, tuple(key), _named_decode(run_tiled if tiled else run)
+
+
+def _named_decode(run):
+    """A chunk decode under its word: the program is ``jit_pq_decode`` when
+    it is jitted alone (``_run_decode``), and its operations carry the
+    scope ``pq_decode`` when it is spliced into a fused stage program."""
+    import jax
+
+    from ..exec.base import program
+
+    @program("pq_decode")
+    def decode(arglist):
+        with jax.named_scope("pq_decode"):
+            return run(arglist)
+
+    return decode
 
 
 def stage_decode_args(per_col_args: Sequence[Sequence[np.ndarray]]):
@@ -957,28 +973,34 @@ def _plan_columns(path, pf, rgmd, pqschema, name_to_ci, columns, file_bytes):
             fallback_cols.append(name)
         else:
             candidates.append((name, ci))
+    from ..exec.base import carry, phase
+
     plans: Dict[str, ChunkPlan] = {}
     if candidates:
         if file_bytes is None:
-            with open(path, "rb") as f:
+            with phase("read_file"), open(path, "rb") as f:
                 file_bytes = f.read()
 
         def plan_one(item):
             name, ci = item
             pqcol = pqschema.column(ci)
-            try:
-                return name, plan_chunk(
-                    file_bytes, rgmd.column(ci),
-                    pqcol.max_definition_level, pqcol.max_repetition_level)
-            except Exception:
-                return name, None
+            with phase("page_plan"):
+                try:
+                    return name, plan_chunk(
+                        file_bytes, rgmd.column(ci),
+                        pqcol.max_definition_level,
+                        pqcol.max_repetition_level)
+                except Exception:
+                    return name, None
 
         # chunk planning is native-decode-heavy (the C++ calls release the
         # GIL): plan all columns of the row group in parallel (reference
         # analog: the COALESCING reader's copy thread pool,
         # GpuParquetScan.scala:900)
         if len(candidates) > 1:
-            results = list(_decode_pool().map(plan_one, candidates))
+            with phase("plan_wait"):
+                results = list(
+                    _decode_pool().map(carry(plan_one), candidates))
         else:
             results = [plan_one(candidates[0])]
         for name, plan in results:
@@ -1029,6 +1051,7 @@ def read_row_groups_pipelined(
     from .. import obs as _obs
     from ..columnar.batch import ColumnarBatch
     from ..columnar.column import choose_capacity
+    from ..exec.base import carry, phase
     from ..types import StructType
     from .arrow_convert import arrow_to_batch
 
@@ -1036,7 +1059,7 @@ def read_row_groups_pipelined(
     pqschema = pf.schema
     pool = _decode_pool()
     if file_bytes is None:
-        with open(path, "rb") as f:
+        with phase("read_file"), open(path, "rb") as f:
             file_bytes = f.read()
     fields_by_name = {f.name: f for f in tpu_fields}
 
@@ -1045,12 +1068,14 @@ def read_row_groups_pipelined(
         if ci is None:
             return name, None, 0
         pqcol = pqschema.column(ci)
-        try:
-            plan = plan_chunk(
-                file_bytes, rgmd.column(ci),
-                pqcol.max_definition_level, pqcol.max_repetition_level)
-        except Exception:
-            return name, None, 0
+        with phase("page_plan"):
+            try:
+                plan = plan_chunk(
+                    file_bytes, rgmd.column(ci),
+                    pqcol.max_definition_level,
+                    pqcol.max_repetition_level)
+            except Exception:
+                return name, None, 0
         if _events.enabled():
             _events.emit(
                 "pq_pipeline", stage="decode", rg=rg,
@@ -1072,8 +1097,11 @@ def read_row_groups_pipelined(
             rgmd.column(i).path_in_schema: i
             for i in range(rgmd.num_columns)
         }
+        # the tasks carry this thread's open section and query: the
+        # page plans are spans of the same scan on the pool's threads
+        task = carry(plan_one)
         futs = [
-            pool.submit(plan_one, rg, rgmd, name, name_to_ci.get(name))
+            pool.submit(task, rg, rgmd, name, name_to_ci.get(name))
             for name in columns
         ]
         pending[pos] = (rg, rgmd, futs)
@@ -1124,7 +1152,9 @@ def read_row_groups_pipelined(
 
         remaining = set(futs)
         while remaining:
-            done, remaining = wait(remaining, return_when=FIRST_COMPLETED)
+            with phase("plan_wait"):
+                done, remaining = wait(remaining,
+                                       return_when=FIRST_COMPLETED)
             for fut in done:
                 name, plan, _ = fut.result()
                 resolved.add(name)
@@ -1132,9 +1162,10 @@ def read_row_groups_pipelined(
                     fallback_cols.append(name)
                     continue
                 try:
-                    args, key_t, run = plan_decode(
-                        plan, fields_by_name[name].dataType, cap,
-                        dict_strings)
+                    with phase("page_plan"):
+                        args, key_t, run = plan_decode(
+                            plan, fields_by_name[name].dataType, cap,
+                            dict_strings)
                 except _FallbackError:
                     fallback_cols.append(name)
                     continue
@@ -1156,22 +1187,34 @@ def read_row_groups_pipelined(
         if not plans:
             yield rg, None
             continue
-        host_table = (pf.read_row_groups([rg], columns=fallback_cols)
-                      if fallback_cols else None)
+        # the columns the device decoder declined go through pyarrow on
+        # the host, named and counted (ROADMAP B-I 4)
+        host_cols = {}
+        if fallback_cols:
+            with phase("host_decode") as span:
+                if span.on:
+                    span.set(columns=len(fallback_cols),
+                             names=",".join(fallback_cols))
+                host_table = pf.read_row_groups([rg], columns=fallback_cols)
+                for name in fallback_cols:
+                    b = arrow_to_batch(
+                        host_table.select([name]),
+                        StructType((fields_by_name[name],)))
+                    host_cols[name] = b.columns[0]
 
         t0 = _time.perf_counter_ns()
         cols = []
         fields = []
-        for name, f in zip(columns, tpu_fields):
-            if name in plans:
-                _, key_t, run = decoded[name]
-                cols.append(_run_decode(
-                    plans[name], f.dataType, key_t, run, dev_args[name]))
-            else:
-                sub = host_table.select([name])
-                b = arrow_to_batch(sub, StructType((f,)))
-                cols.append(b.columns[0])
-            fields.append(f)
+        with phase("decode_dispatch"):
+            for name, f in zip(columns, tpu_fields):
+                if name in plans:
+                    _, key_t, run = decoded[name]
+                    cols.append(_run_decode(
+                        plans[name], f.dataType, key_t, run,
+                        dev_args[name]))
+                else:
+                    cols.append(host_cols[name])
+                fields.append(f)
         batch = ColumnarBatch(cols, StructType(tuple(fields)), n)
         if _events.enabled():
             _events.emit("pq_pipeline", stage="unpack", rg=rg, bytes=0,
@@ -1210,11 +1253,14 @@ def row_group_device_plans(
         path, pf, rgmd, pqschema, name_to_ci, columns, file_bytes)
     if fallback_cols or len(plans) != len(columns):
         return None
+    from ..exec.base import phase
+
     staged = []
-    for name, f in zip(columns, tpu_fields):
-        args, key, run = plan_decode(plans[name], f.dataType, cap,
-                                     dict_strings)
-        staged.append((args, key, run, f))
+    with phase("page_plan"):
+        for name, f in zip(columns, tpu_fields):
+            args, key, run = plan_decode(plans[name], f.dataType, cap,
+                                         dict_strings)
+            staged.append((args, key, run, f))
     # ONE host->device transfer for the whole row group's payloads
     dev_args = stage_decode_args([s[0] for s in staged])
     entries = [

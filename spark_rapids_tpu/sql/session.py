@@ -15,8 +15,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 from .. import events as _events  # registers the eventLog.* conf entries
 from .. import faults as _faults  # registers the test.faults.* entries
 from .. import obs as _obs
-from ..conf import (DONATION_WITNESS_ENABLED, RACECHECK_WITNESS_ENABLED,
-                    RapidsConf)
+from ..conf import (DONATION_WITNESS_ENABLED, ENABLE_TRACE,
+                    RACECHECK_WITNESS_ENABLED, RapidsConf)
 from ..cpu import plan as C
 from ..memory import catalog as _catalog  # noqa: F401 — registers the
 # memory.* conf entries (hbm.budgetBytes) BEFORE RapidsConf validates a
@@ -254,6 +254,7 @@ class TpuSession:
 
     def __init__(self, settings: Optional[Dict[str, Any]] = None):
         self.conf = RapidsConf(settings)
+        self._trace = self.conf.get(ENABLE_TRACE)
         self.overrides = TpuOverrides(self.conf)
         self.last_executed_plan = None
         self.last_cpu_plan = None
@@ -359,6 +360,20 @@ class TpuSession:
 
     # -- execution ---------------------------------------------------------
     def _execute(self, node: LNode) -> C.CpuExec:
+        """Plan one query: ``_lower`` + analysis + ``overrides.apply``,
+        under the span ``TpuSession.plan``. The query takes its id here
+        (or from ``_collect``'s ``TpuSession.query`` span around plan and
+        drain) whether or not events/obs are on: every span of the
+        drain carries it."""
+        from ..exec.base import current_query, query_scope, timed
+
+        qid = current_query()
+        if qid is None:
+            qid = _next_query_id()
+        with query_scope(qid), timed(None, "TpuSession.plan", self._trace):
+            return self._plan(node, qid)
+
+    def _plan(self, node: LNode, qid: int) -> C.CpuExec:
         from ..exec.base import compile_snapshot
 
         cpu = _lower(node, self.conf)
@@ -426,7 +441,7 @@ class TpuSession:
 
         _hlo.set_conf_top_k(self.conf)
         if self.events.enabled or obs_on:
-            qid = self._active_query = _next_query_id()
+            self._active_query = qid
             if self.events.enabled:
                 self._emit_query_events(node, qid, digest, is_tpu)
             if obs_on:
@@ -567,9 +582,13 @@ class TpuSession:
     def _collect(self, node: LNode) -> List[tuple]:
         """Plan + drain one query, through the serving scheduler when
         spark.rapids.tpu.serve.enabled is set."""
-        if not self._serve_enabled():
-            return self._run_collect(self._execute(node))
-        return self._collect_serve(node)
+        from ..exec.base import query_scope, timed
+
+        with query_scope(_next_query_id()), \
+                timed(None, "TpuSession.query", self._trace):
+            if not self._serve_enabled():
+                return self._run_collect(self._execute(node))
+            return self._collect_serve(node)
 
     def _collect_serve(self, node: LNode) -> List[tuple]:
         """Serve-path drain with the OOM requeue contract (ROADMAP item
@@ -609,8 +628,13 @@ class TpuSession:
             QueryScheduler.get(self.conf).note_oom_requeue(
                 self.serve_id, self._last_digest or "", observed or None,
                 forecast_source="ledger" if led_peak else "watermark")
-            return self._collect_serve_once(
-                node, forecast_floor=observed or None)
+            # the resubmission is an execution of its own: a fresh id
+            # (its query_start must not repeat the failed attempt's)
+            from ..exec.base import query_scope
+
+            with query_scope(_next_query_id()):
+                return self._collect_serve_once(
+                    node, forecast_floor=observed or None)
 
     def _collect_serve_once(self, node: LNode,
                             forecast_floor: Optional[int] = None
@@ -718,9 +742,10 @@ class TpuSession:
         node = plan.tpu_child if isinstance(plan, ColumnarToRowExec) else plan
         if not isinstance(node, TpuExec):
             return "<last plan ran on CPU; no device metrics>"
-        return format_metrics(node, getattr(self, "_compile_baseline", None),
-                              cost_since=getattr(self, "_cost_baseline",
-                                                 None))
+        return format_metrics(
+            node, getattr(self, "_compile_baseline", None),
+            cost_since=getattr(self, "_cost_baseline", None),
+            boundary=plan if isinstance(plan, ColumnarToRowExec) else None)
 
 
 class GroupedData:

@@ -426,9 +426,9 @@ def test_op_timed_fast_path_unchanged_when_disabled():
     orig = base_mod.timed
 
     def probe(metric, trace_name="", trace=False, event_op=None,
-              event_section=""):
+              event_section="", **kw):
         seen["event_op"] = event_op
-        return orig(metric, trace_name, trace, event_op, event_section)
+        return orig(metric, trace_name, trace, event_op, event_section, **kw)
 
     base_mod.timed = probe
     try:
