@@ -1005,7 +1005,11 @@ class TpuHashAggregateExec(TpuExec):
         if fsp is not None and self._can_fuse_stage() and self._stage_fusion_on():
             stage = fsp(index)
             if stage:
-                with self.op_timed("stage"):
+                with self.op_timed("stage") as span:
+                    if span.on:
+                        from ..io.parquet_device import stage_gathers
+
+                        span.set(gathers=stage_gathers(stage))
                     out = self._run_fused_stage(stage, tuple(chain))
                 yield self.record_batch(out)
                 return

@@ -529,11 +529,12 @@ def _load_dictionary(plan: ChunkPlan, raw: bytes, count: int) -> None:
 # ---------------------------------------------------------------------------
 # device decode (XLA kernels)
 # ---------------------------------------------------------------------------
-#: stream the fixed-width unpack (bit-expand -> dictionary gather ->
-#: validity expand) through one tiled fori_loop instead of materializing
-#: full-width intermediate planes (the cap-sized widened-codes and
-#: present->row index planes). Module-level because plan_decode has no
-#: session conf in scope; tests flip it to diff the flat path.
+#: stream the fixed-width unpack (bit-expand -> code read -> dictionary
+#: look-up -> validity expand) through one tiled fori_loop instead of
+#: materializing full-width intermediate planes (the cap-sized
+#: widened-codes and present->row index planes). Module-level because
+#: plan_decode has no session conf in scope; tests flip it to diff the
+#: flat path.
 TILED_UNPACK = True
 #: below this output capacity the flat program's intermediates are noise
 #: and the loop only costs dispatch overhead
@@ -541,11 +542,21 @@ TILED_UNPACK_MIN_CAP = 1 << 16
 #: test hook: force the unpack tile row count (0 = derive); rounded up
 #: to a multiple of 32 so validity-word slices stay aligned
 FORCE_UNPACK_TILE_ROWS = 0
+#: the longest dictionary of 32-bit planes (INT32, FLOAT, INT64 as two)
+#: whose look-up is a matmul against a one-hot of the code, and the
+#: longest whose look-up is the two-level form, instead of a per-element
+#: gather: where each read at least 2x faster a value than the gather on
+#: a v5e (docs/tuning.md "The streamed (tiled) unpack" has the readings)
+ONEHOT_MAX_D = 1024
+TWOLEVEL_MAX_D = 16384
+#: the most bytes a tile's one-hot operand or two-level product may take
+_LOOKUP_TILE_BYTES = 8 << 20
+_TWOLEVEL_LANES = 128
 
 
 def unpack_bit_words(words, out_cap: int):
     """bits[j] = bit j of the LSB-first u32 word stream — pure reshape/
-    elementwise, ZERO gathers (TPU gathers cost ~15ns/elem)."""
+    elementwise, ZERO gathers (a gather costs ~8 ns a value on a v5e)."""
     import jax.numpy as jnp
 
     need_w = -(-out_cap // 32)
@@ -560,39 +571,184 @@ def unpack_bit_words(words, out_cap: int):
     return bits.reshape(need_w * 32)[:out_cap]
 
 
-def _unpack_tile_rows(cap: int) -> int:
+def _unpack_tile_rows(cap: int, most: int = 0) -> int:
+    """Rows a trip of the streamed unpack handles: a multiple of 32 (the
+    validity-word slices align) and, where the dictionary look-up holds a
+    temporary of several planes a row, at most ``most``."""
     if FORCE_UNPACK_TILE_ROWS:
-        return -(-FORCE_UNPACK_TILE_ROWS // 32) * 32
-    from ..ops.radix_bin import default_tile_rows
+        rows = FORCE_UNPACK_TILE_ROWS
+    else:
+        from ..ops.radix_bin import default_tile_rows
 
-    # the loop body's working set is ~3 tile-sized planes; reuse the
-    # radix-bin sizing rule (fast-memory-resident tiles, 2^12..2^16).
-    # Rounded up to a multiple of 32 so validity-word slices align —
-    # default_tile_rows' own results are powers of two >= 2^12, but a
-    # test driving radix_bin.FORCE_TILE_ROWS (the AGG tiling hook) can
-    # leak a non-multiple through it
-    return -(-max(32, default_tile_rows(cap, 3)) // 32) * 32
+        # the loop body's working set is ~3 tile-sized planes; reuse the
+        # radix-bin sizing rule (fast-memory-resident tiles, 2^12..2^16).
+        # default_tile_rows' own results are powers of two >= 2^12, but a
+        # test driving radix_bin.FORCE_TILE_ROWS (the AGG tiling hook) can
+        # leak a non-multiple of 32 through it
+        rows = max(32, default_tile_rows(cap, 3))
+    if most:
+        rows = min(rows, most)
+    return -(-rows // 32) * 32
 
 
-def tiled_fixed_unpack(vwords, out_dt, n: int, cap: int, has_def: bool,
-                       take_codes):
-    """The streamed fixed-width unpack: ONE ``lax.fori_loop`` walks the
-    output in validity-word-aligned tiles; each trip bit-expands its
-    slice of the packed validity words, derives the present->row index
-    stream IN the tile (a carried present-count + tile-local prefix
-    sum), gathers the narrow codes/values straight from their
-    HBM-resident upload arrays, and writes (data, validity) through a
-    sliding dynamic-update-slice window — the radix-bin loop pattern
-    (ops/radix_bin.py). No cap-sized widened-code plane, no cap-sized
-    cumsum plane, no full-width bit matrix.
+def dict_read_form(phys: str, d: int) -> str:
+    """How a tile looks its codes up in a dictionary of ``d`` values:
+    ``onehot`` and ``twolevel`` are matmuls over the values' 8-bit limbs
+    (32-bit planes only: the chip has no bit-exact route for DOUBLE),
+    ``gather`` the per-element ``jnp.take``."""
+    if phys in ("INT32", "FLOAT", "INT64"):
+        if d <= ONEHOT_MAX_D:
+            return "onehot"
+        if d <= TWOLEVEL_MAX_D:
+            return "twolevel"
+    return "gather"
 
-    ``take_codes(vidx_tile, valid_tile)`` maps the tile's present-value
-    indices to output values of dtype ``out_dt`` (dictionary gather, or
-    a gather into the bitcast PLAIN value array)."""
+
+def _lookup_tile_rows(form: str, d: int, itemsize: int) -> int:
+    """The most rows a tile may have so that the look-up's temporary (the
+    bf16 one-hot ``[d, rows]``, or the two-level f32 product
+    ``[limbs * 128, rows]``) stays under ``_LOOKUP_TILE_BYTES``; a power
+    of two, 0 for no limit."""
+    if form == "onehot":
+        rows = _LOOKUP_TILE_BYTES // (2 * max(1, d))
+    elif form == "twolevel":
+        rows = _LOOKUP_TILE_BYTES // (4 * itemsize * _TWOLEVEL_LANES)
+    else:
+        return 0
+    tile = 32  # a tile, not a capacity: halved until the temporary fits
+    while tile * 2 <= rows:
+        tile *= 2
+    return tile
+
+
+def key_gathers(key) -> int:
+    """Per-element gathers left in the program of one chunk, from the
+    ``("reads", codes, dict)`` tag of its ``plan_decode`` key."""
+    for k in key:
+        if isinstance(k, tuple) and k and k[0] == "reads":
+            return sum(form == "gather" for form in k[1:])
+    return 0
+
+
+def stage_gathers(stage) -> int:
+    """The same over every chunk of a fused stage's row groups
+    (``ParquetScanner.device_stage_plans``), cached ones included: what
+    the span that splices their programs carries as ``gathers``."""
+    return sum(key_gathers(key) for (_, _, entries) in stage
+               for (_, key, _, _) in entries)
+
+
+def _limb_table(dvals, pad_to: int = 0):
+    """``[limbs, D]`` bf16: the 8-bit limbs of a dictionary's 32-bit
+    planes, low limb first (four for INT32/FLOAT, eight for INT64). A limb
+    is exact in bf16, so a matmul of it against a one-hot of the code (one
+    non-zero product a row, f32 accumulation) returns it exactly."""
     import jax.numpy as jnp
     from jax import lax
 
-    tile = min(_unpack_tile_rows(cap), -(-cap // 32) * 32)
+    if dvals.dtype.itemsize == 4:
+        planes = [lax.bitcast_convert_type(dvals, jnp.uint32)]
+    else:
+        from ..ops.filter_gather import _split64_i32
+
+        planes = [lax.bitcast_convert_type(h, jnp.uint32)
+                  for h in _split64_i32(dvals)]
+    limbs = jnp.stack([(p >> jnp.uint32(8 * j)) & jnp.uint32(0xFF)
+                       for p in planes for j in range(4)])
+    if pad_to > limbs.shape[1]:
+        limbs = jnp.pad(limbs, ((0, 0), (0, pad_to - limbs.shape[1])))
+    return limbs.astype(jnp.bfloat16)
+
+
+def _from_limbs(limbs_f32, out_dt):
+    """``[limbs, rows]`` f32 limbs (whole numbers under 256) back to the
+    values whose bit patterns they spell."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    u = limbs_f32.astype(jnp.uint32)
+    planes = [u[i] | (u[i + 1] << jnp.uint32(8)) | (u[i + 2] << jnp.uint32(16))
+              | (u[i + 3] << jnp.uint32(24))
+              for i in range(0, u.shape[0], 4)]
+    if len(planes) == 1:
+        return lax.bitcast_convert_type(planes[0], out_dt)
+    from ..ops.filter_gather import _join64
+
+    return _join64(lax.bitcast_convert_type(planes[0], jnp.int32),
+                   lax.bitcast_convert_type(planes[1], jnp.int32), out_dt)
+
+
+def _onehot_lookup(table, codes_t):
+    """``table[:, codes_t]`` of a ``[limbs, D]`` limb table as a matmul
+    against the one-hot of the tile's codes: ``[limbs, rows]`` f32."""
+    import jax.numpy as jnp
+
+    d = table.shape[1]
+    onehot = (jnp.arange(d, dtype=jnp.int32)[:, None]
+              == codes_t[None, :]).astype(jnp.bfloat16)
+    return jnp.dot(table, onehot, preferred_element_type=jnp.float32)
+
+
+def _twolevel_table(dvals):
+    """The limb table of ``_twolevel_lookup``: ``[limbs * 128, H]`` with
+    ``row (l, j)``, ``column h`` the limb ``l`` of value ``h * 128 + j``."""
+    lanes = _TWOLEVEL_LANES
+    h = -(-dvals.shape[0] // lanes)
+    table = _limb_table(dvals, h * lanes)
+    limbs = table.shape[0]
+    return table.reshape(limbs, h, lanes).transpose(0, 2, 1).reshape(
+        limbs * lanes, h)
+
+
+def _twolevel_lookup(table, codes_t):
+    """The same look-up at a cost that does not grow with ``D``: a code is
+    ``hi * 128 + lo``; the one-hot of ``hi`` picks, by matmul, the 128
+    values of its block (``[limbs * 128, rows]``), and ``lo`` selects one
+    of them (a masked sum with one non-zero term: exact)."""
+    import jax.numpy as jnp
+
+    lanes = _TWOLEVEL_LANES
+    h = table.shape[1]
+    limbs = table.shape[0] // lanes
+    hi, lo = codes_t // lanes, codes_t % lanes
+    onehot = (jnp.arange(h, dtype=jnp.int32)[:, None]
+              == hi[None, :]).astype(jnp.bfloat16)
+    block = jnp.dot(table, onehot, preferred_element_type=jnp.float32)
+    block = block.reshape(limbs, lanes, codes_t.shape[0])
+    pick = jnp.arange(lanes, dtype=jnp.int32)[:, None] == lo[None, :]
+    return jnp.sum(jnp.where(pick[None], block, jnp.float32(0)), axis=1)
+
+
+def _pad_for_slices(x, length: int):
+    """``x`` zero-padded to ``length``: ``dynamic_slice`` clamps a start
+    that runs off the end, which would silently shift a tile's rows."""
+    import jax.numpy as jnp
+
+    short = length - x.shape[0]
+    if short <= 0:
+        return x
+    return jnp.concatenate([x, jnp.zeros(short, x.dtype)])
+
+
+def tiled_fixed_unpack(vwords, out_dt, n: int, cap: int, has_def: bool,
+                       tile: int, read_tile):
+    """The streamed fixed-width unpack: ONE ``lax.fori_loop`` walks the
+    output in validity-word-aligned tiles of ``tile`` rows and writes
+    (data, validity) through a sliding dynamic-update-slice window — the
+    radix-bin loop pattern (ops/radix_bin.py). No cap-sized widened-code
+    plane, no cap-sized cumsum plane, no full-width bit matrix.
+
+    Without nulls (``has_def`` False) row ``i`` holds present value ``i``:
+    a trip reads its values with ``read_tile(start, None)``, a contiguous
+    slice at ``start``. With nulls a trip bit-expands its slice of the
+    packed validity words, derives the present->row index stream IN the
+    tile (a carried present-count + tile-local prefix sum) and reads
+    ``read_tile(None, vidx_tile)``: a true expand of the present-only
+    stream, by gather. ``read_tile`` returns ``tile`` values of dtype
+    ``out_dt``."""
+    import jax.numpy as jnp
+    from jax import lax
+
     trips = -(-cap // tile)
     wpad = -(-(trips * tile) // 32)
     if has_def:
@@ -614,10 +770,10 @@ def tiled_fixed_unpack(vwords, out_dt, n: int, cap: int, has_def: bool,
             bits = ((ws[:, None] >> shifts[None, :]) & jnp.uint32(1)) != 0
             valid_t = bits.reshape(tile) & in_n
             vidx_t = nseen + jnp.cumsum(valid_t.astype(jnp.int32)) - 1
+            data_t = read_tile(None, jnp.clip(vidx_t, 0, None))
         else:
             valid_t = in_n
-            vidx_t = start + row_ids
-        data_t = take_codes(jnp.clip(vidx_t, 0, None), valid_t)
+            data_t = read_tile(start, None)
         data_t = jnp.where(valid_t, data_t, jnp.zeros((), out_dt))
         data_buf = lax.dynamic_update_slice(data_buf, data_t, (start,))
         valid_buf = lax.dynamic_update_slice(valid_buf, valid_t, (start,))
@@ -694,28 +850,46 @@ def plan_decode(plan: ChunkPlan, dtype_tpu, cap: int,
         return [], ("pqdec0", str(dt), cap), _named_decode(run_empty)
 
     keep_dict = bool(dict_strings) and is_str and is_dict
-    # streamed fixed-width unpack (tiled_fixed_unpack): bit-expand ->
-    # dictionary gather -> validity expand fuse into one fori_loop over
-    # output tiles, so no full-width intermediate plane (widened codes,
-    # present->row cumsum, bit matrix) ever materializes
+    if is_dict:
+        # all-null chunks can carry an EMPTY dictionary: pad one zero slot
+        # so the device look-up has a valid (masked-out) target
+        if plan.dict_values is not None and plan.dict_values.shape[0] == 0:
+            plan.dict_values = np.zeros(1, plan.dict_values.dtype)
+        if plan.dict_offsets is not None and plan.dict_offsets.shape[0] < 2:
+            plan.dict_offsets = np.zeros(2, np.int64)
+    # streamed fixed-width unpack (tiled_fixed_unpack): bit-expand -> code
+    # read -> dictionary look-up -> validity expand fuse into one
+    # fori_loop over output tiles, so no full-width intermediate plane
+    # (widened codes, present->row cumsum, bit matrix) ever materializes
     tiled = (TILED_UNPACK and not is_str
              and (cap >= TILED_UNPACK_MIN_CAP or FORCE_UNPACK_TILE_ROWS))
+    # the two reads of a value, each in the cheapest form that what is
+    # observed here allows: a per-element gather only where the index is
+    # really arbitrary (the present-only stream of a chunk with nulls; a
+    # long or DOUBLE dictionary)
+    codes_form = "gather" if has_def else "slice"
+    dict_form = "none"
+    if is_dict and not keep_dict:
+        dict_form = "gather"
+        if tiled:
+            dict_form = dict_read_form(plan.phys, plan.dict_values.shape[0])
+    tile = 0
+    if tiled:
+        tile = _unpack_tile_rows(cap, _lookup_tile_rows(
+            dict_form, plan.dict_values.shape[0] if is_dict else 0,
+            _PHYS_NP[plan.phys].itemsize))
+        tile = min(tile, -(-cap // 32) * 32)
     args: List[Any] = []
     key: List[Any] = ["pqdec", plan.phys, str(dtype_tpu), cap, n, has_def,
                       is_dict, keep_dict,
-                      ("tile", _unpack_tile_rows(cap)) if tiled else False]
+                      ("tile", tile) if tiled else False,
+                      ("reads", codes_form, dict_form)]
 
     if has_def:
         vwords = _pack_validity_words(plan.validity)
         args.append(np.ascontiguousarray(vwords))
         key.append(int(vwords.shape[0]))
     if is_dict:
-        # all-null chunks can carry an EMPTY dictionary: pad one zero slot
-        # so the device gather has a valid (masked-out) target
-        if plan.dict_values is not None and plan.dict_values.shape[0] == 0:
-            plan.dict_values = np.zeros(1, plan.dict_values.dtype)
-        if plan.dict_offsets is not None and plan.dict_offsets.shape[0] < 2:
-            plan.dict_offsets = np.zeros(2, np.int64)
         codes = plan.codes
         pcap = choose_capacity(max(1, codes.shape[0]))
         if codes.shape[0] < pcap:
@@ -754,22 +928,47 @@ def plan_decode(plan: ChunkPlan, dtype_tpu, cap: int,
         if has_def:
             vwords = arglist[ai]
             ai += 1
+        padded = -(-cap // tile) * tile
+
+        def stream_reader(stream):
+            """Present values of a tile from the narrow ``stream``: the
+            slice at ``start`` without nulls, else the gather at the
+            tile's present->row indices."""
+            if not has_def:
+                stream = _pad_for_slices(stream, padded)
+
+            def read(start, vidx_t):
+                if vidx_t is None:
+                    return jax.lax.dynamic_slice(stream, (start,), (tile,))
+                return jnp.take(stream, jnp.clip(
+                    vidx_t, 0, stream.shape[0] - 1), mode="clip")
+
+            return read
+
         if is_dict:
-            codes_n = arglist[ai]  # narrowest dtype, gathered per tile
+            read_codes = stream_reader(arglist[ai])  # narrowest dtype
             dvals_ = arglist[ai + 1]
             D_ = dvals_.shape[0]
-
-            def take_codes(vidx_t, valid_t):
-                ct = jnp.take(codes_n, jnp.clip(
-                    vidx_t, 0, codes_n.shape[0] - 1), mode="clip")
-                return jnp.take(dvals_, jnp.clip(
-                    ct.astype(jnp.int32), 0, D_ - 1), mode="clip")
-
             out_dt = dvals_.dtype
+            if dict_form == "onehot":
+                table = _limb_table(dvals_)
+            elif dict_form == "twolevel":
+                table = _twolevel_table(dvals_)
+
+            def read_tile(start, vidx_t):
+                # clipped BEFORE any look-up: a malformed code reads
+                # dvals[D-1] in every form
+                ct = jnp.clip(read_codes(start, vidx_t).astype(jnp.int32),
+                              0, D_ - 1)
+                if dict_form == "onehot":
+                    return _from_limbs(_onehot_lookup(table, ct), out_dt)
+                if dict_form == "twolevel":
+                    return _from_limbs(_twolevel_lookup(table, ct), out_dt)
+                return jnp.take(dvals_, ct, mode="clip")
         else:
             words_ = arglist[ai]
             # the bitcast view of the uploaded payload is the INPUT
-            # surface itself, not an amplified plane — tiles gather
+            # surface itself, not an amplified plane — tiles read
             # straight from it
             if phys in ("INT32", "FLOAT"):
                 arr = jax.lax.bitcast_convert_type(words_, _PHYS_NP[phys])
@@ -779,14 +978,10 @@ def plan_decode(plan: ChunkPlan, dtype_tpu, cap: int,
                 lo = jax.lax.bitcast_convert_type(words_[0::2], jnp.int32)
                 hi = jax.lax.bitcast_convert_type(words_[1::2], jnp.int32)
                 arr = _join64(lo, hi, jnp.int64)
-
-            def take_codes(vidx_t, valid_t):
-                return jnp.take(arr, jnp.clip(
-                    vidx_t, 0, arr.shape[0] - 1), mode="clip")
-
+            read_tile = stream_reader(arr)
             out_dt = arr.dtype
-        return tiled_fixed_unpack(vwords, out_dt, n, cap, has_def,
-                                  take_codes)
+        return tiled_fixed_unpack(vwords, out_dt, n, cap, has_def, tile,
+                                  read_tile)
 
     def run(arglist):
             ai = 0
@@ -1205,7 +1400,10 @@ def read_row_groups_pipelined(
         t0 = _time.perf_counter_ns()
         cols = []
         fields = []
-        with phase("decode_dispatch"):
+        with phase("decode_dispatch") as span:
+            if span.on:
+                span.set(gathers=sum(key_gathers(decoded[name][1])
+                                     for name in plans))
             for name, f in zip(columns, tpu_fields):
                 if name in plans:
                     _, key_t, run = decoded[name]

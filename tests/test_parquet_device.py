@@ -280,6 +280,299 @@ def test_tiled_unpack_program_classifies_radix_bin_not_scatter():
     assert any(r["class"] == "radix-bin" for r in s["top_fusions"])
 
 
+# ---------------------------------------------------------------------------
+# PR 27: the two reads of a value by form (slice | gather, onehot | twolevel
+# | gather), against the flat program
+# ---------------------------------------------------------------------------
+_TPU_T = {"INT32": T.INT, "INT64": T.LONG, "FLOAT": T.FLOAT,
+          "DOUBLE": T.DOUBLE}
+_FORM_DS = (1, 100, 129, 2400, 16384, 16385)
+
+
+def _synthetic_chunk(phys, d, n, nulls, code_dt, seed=0):
+    """A dictionary chunk of ``n`` rows made by hand: ``d`` values of
+    random BIT PATTERNS (negative zero and NaN payloads among a FLOAT's),
+    codes of ``code_dt`` and, where the code dtype can hold one, a
+    malformed code ``>= d`` in the middle."""
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    rng = np.random.default_rng(seed)
+    dt = PD._PHYS_NP[phys]
+    plan = PD.ChunkPlan(phys=phys, num_values=n, nullable=nulls)
+    present = n
+    if nulls:
+        plan.validity = rng.random(n) < 0.8
+        present = int(plan.validity.sum())
+    bits = rng.integers(0, 2 ** 64, d, dtype=np.uint64)
+    plan.dict_values = bits.astype(
+        np.uint32 if dt.itemsize == 4 else np.uint64).view(dt).copy()
+    if phys == "FLOAT" and d >= 4:
+        plan.dict_values[:4] = np.array(
+            [0x80000000, 0x7FC00001, 0xFFC12345, 0x7F800001],
+            np.uint32).view(np.float32)
+    plan.codes = rng.integers(0, d, present).astype(code_dt)
+    bad = present // 2
+    if d + 5 <= np.iinfo(code_dt).max:
+        plan.codes[bad] = d + 5
+    else:
+        bad = None
+    plan.n_present = present
+    return plan, bad
+
+
+def _decode(plan, phys, cap):
+    import jax
+
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    args, key, run = PD.plan_decode(plan, _TPU_T[phys], cap)
+    data, validity = jax.jit(run)(PD.stage_decode_args([args])[0])
+    return key, np.asarray(data), np.asarray(validity)
+
+
+def _reads(key):
+    return next(k for k in key if isinstance(k, tuple) and k[0] == "reads")
+
+
+def _form_cases():
+    for phys in ("INT32", "FLOAT", "INT64", "DOUBLE"):
+        for nulls in (False, True):
+            for code_dt in (np.uint8, np.uint16, np.int32):
+                for d in _FORM_DS:
+                    if d - 1 > np.iinfo(code_dt).max:
+                        continue
+                    yield pytest.param(
+                        phys, nulls, code_dt, d,
+                        id=f"{phys}-{'nulls' if nulls else 'full'}-"
+                           f"{np.dtype(code_dt).name}-D{d}")
+
+
+@pytest.mark.parametrize("phys,nulls,code_dt,d", list(_form_cases()))
+def test_tiled_reads_match_flat_by_form(phys, nulls, code_dt, d):
+    """Whatever form the two reads take, the streamed unpack returns the
+    flat program's (data, validity) bit for bit: chunks without nulls and
+    with them, every code dtype, 32- and 64-bit dictionaries on both sides
+    of each threshold, ``n`` below the capacity and no multiple of the
+    tile (a last partial tile, and trips past ``n``), a code ``>= D``."""
+    from spark_rapids_tpu.io import parquet_device as PD
+    from spark_rapids_tpu.utils.bucketing import bucket_rows
+
+    n = 5000 + d % 7
+    cap = bucket_rows(n)
+    plan, bad = _synthetic_chunk(phys, d, n, nulls, code_dt, seed=d)
+    prev_tile, prev_on = PD.FORCE_UNPACK_TILE_ROWS, PD.TILED_UNPACK
+    try:
+        PD.TILED_UNPACK = False
+        flat_key, flat_data, flat_valid = _decode(plan, phys, cap)
+        assert _reads(flat_key)[2] == "gather"
+        PD.TILED_UNPACK = True
+        for tile in (32, 96, 4096):
+            PD.FORCE_UNPACK_TILE_ROWS = tile
+            key, data, valid = _decode(plan, phys, cap)
+            assert _reads(key) == (
+                "reads", "gather" if nulls else "slice",
+                PD.dict_read_form(phys, d)), key
+            assert valid.tobytes() == flat_valid.tobytes(), tile
+            assert data.dtype == flat_data.dtype
+            assert data.tobytes() == flat_data.tobytes(), tile
+    finally:
+        PD.FORCE_UNPACK_TILE_ROWS = prev_tile
+        PD.TILED_UNPACK = prev_on
+    if bad is not None:
+        # the malformed code reads the dictionary's last value
+        row = (np.flatnonzero(plan.validity)[bad] if nulls else bad)
+        assert (flat_data[row].tobytes()
+                == plan.dict_values[d - 1].tobytes())
+
+
+@pytest.mark.parametrize("d,form", [(100, "onehot"), (1024, "onehot"),
+                                    (1025, "twolevel"), (16384, "twolevel"),
+                                    (16385, "gather")])
+@pytest.mark.parametrize("nulls", [False, True])
+def test_tiled_reads_match_flat_at_the_derived_tile(d, form, nulls):
+    """The same at a capacity that takes the streamed path by itself
+    (>= 2^16), with the tile the look-up's form derives."""
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    n, cap = 70_001, 1 << 17
+    plan, _ = _synthetic_chunk("INT32", d, n, nulls, np.int32, seed=d)
+    prev_on = PD.TILED_UNPACK
+    try:
+        PD.TILED_UNPACK = False
+        _, flat_data, flat_valid = _decode(plan, "INT32", cap)
+        PD.TILED_UNPACK = True
+        key, data, valid = _decode(plan, "INT32", cap)
+    finally:
+        PD.TILED_UNPACK = prev_on
+    assert _reads(key)[2] == form == PD.dict_read_form("INT32", d)
+    tile = next(k for k in key if isinstance(k, tuple) and k[0] == "tile")[1]
+    assert tile % 32 == 0 and tile == min(
+        32768, PD._lookup_tile_rows(form, d, 4) or 32768)
+    assert (data.tobytes(), valid.tobytes()) == (
+        flat_data.tobytes(), flat_valid.tobytes())
+
+
+def test_plain_chunks_read_by_slice_match_flat(tmp_path):
+    """PLAIN INT32/FLOAT/INT64 chunks without nulls slice their values;
+    with nulls they keep the gather. Both match the flat program."""
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    rng = np.random.default_rng(27)
+    n = 3001
+    nulls = rng.random(n) < 0.2
+    table = pa.table({
+        "i": pa.array(rng.integers(-2 ** 31, 2 ** 31, n).astype(np.int32)),
+        "f": pa.array(rng.normal(size=n).astype(np.float32)),
+        "l": pa.array(rng.integers(-2 ** 62, 2 ** 62, n)),
+        "ln": pa.array(rng.integers(-2 ** 62, 2 ** 62, n), mask=nulls),
+    })
+    path = os.path.join(str(tmp_path), "p.parquet")
+    pq.write_table(table, path, use_dictionary=False)
+    prev_tile, prev_on = PD.FORCE_UNPACK_TILE_ROWS, PD.TILED_UNPACK
+    try:
+        PD.TILED_UNPACK = False
+        flat = _collect(path, {})
+        PD.TILED_UNPACK = True
+        for tile in (32, 96, 4096):
+            PD.FORCE_UNPACK_TILE_ROWS = tile
+            PD._DECODE_CACHE.clear()
+            from spark_rapids_tpu.io.scan_cache import DeviceScanCache
+
+            DeviceScanCache.get_instance(RapidsConf({})).invalidate_path(
+                path)
+            assert _collect(path, {}) == flat, tile
+            forms = {k[1]: _reads(k) for k in PD._DECODE_CACHE}
+            assert forms["INT32"] == forms["FLOAT"] == (
+                "reads", "slice", "none")
+            assert {_reads(k) for k in PD._DECODE_CACHE
+                    if k[1] == "INT64"} == {("reads", "slice", "none"),
+                                            ("reads", "gather", "none")}
+    finally:
+        PD.FORCE_UNPACK_TILE_ROWS = prev_tile
+        PD.TILED_UNPACK = prev_on
+        PD._DECODE_CACHE.clear()
+
+
+def _gather_ops(text):
+    from spark_rapids_tpu.hlo import parse_hlo_module
+
+    mod = parse_hlo_module(text)
+    return sum(ins.opcode == "gather" for comp in mod.computations
+               for ins in mod.instrs(comp))
+
+
+@pytest.mark.parametrize("nulls", [False, True])
+def test_tiled_unpack_program_gathers_only_what_its_key_says(nulls):
+    """A non-null INT32 dictionary chunk of 100 values at a capacity
+    >= 2^16 compiles to a program with NO gather; the same chunk with
+    nulls keeps exactly the code read's. The key's tag counts the same."""
+    import jax
+    from spark_rapids_tpu.hlo import summarize_hlo
+    from spark_rapids_tpu.io import parquet_device as PD
+    from spark_rapids_tpu.utils.bucketing import bucket_rows
+
+    n = 70_000
+    plan, _ = _synthetic_chunk("INT32", 100, n, nulls, np.uint8)
+    cap = bucket_rows(n)
+    assert cap >= PD.TILED_UNPACK_MIN_CAP
+    args, key, run = PD.plan_decode(plan, T.INT, cap)
+    assert _reads(key) == ("reads", "gather" if nulls else "slice",
+                           "onehot")
+    text = jax.jit(run).lower(
+        PD.stage_decode_args([args])[0]).compile().as_text()
+    s = summarize_hlo(text, top_k=64)
+    assert not [r for r in s["top_fusions"] if r["class"] == "gather"]
+    assert s["scatter_count"] == 0
+    assert _gather_ops(text) == PD.key_gathers(key) == int(nulls)
+    # the same chunk under a dictionary past both thresholds: one more
+    long_plan, _ = _synthetic_chunk("INT32", PD.TWOLEVEL_MAX_D + 1, n,
+                                    nulls, np.int32)
+    args, key, run = PD.plan_decode(long_plan, T.INT, cap)
+    text = jax.jit(run).lower(
+        PD.stage_decode_args([args])[0]).compile().as_text()
+    assert _gather_ops(text) == PD.key_gathers(key) == 1 + int(nulls)
+
+
+def test_decode_spans_carry_the_gathers_their_keys_count(tmp_path,
+                                                         monkeypatch):
+    """``TpuFileSourceScanExec.decode_dispatch`` (a program a column) and
+    ``TpuHashAggregateExec.stage`` (the fused stage, served-from-cache row
+    groups included) carry ``gathers``: the sum of ``key_gathers`` over the
+    chunks whose programs they dispatch or splice."""
+    import jax
+
+    from spark_rapids_tpu.expr import aggregates as A
+    from spark_rapids_tpu.expr.expressions import col
+    from spark_rapids_tpu.io import parquet_device as PD
+    from spark_rapids_tpu.sql import TpuSession
+
+    rng = np.random.default_rng(28)
+    n = 70_000  # capacity 2^17: the streamed path by itself
+    mask = rng.random(n) < 0.1
+    table = pa.table({
+        # one-hot, by slice: 0 gathers
+        "q": pa.array(rng.integers(1, 101, n).astype(np.int32)),
+        # DOUBLE dictionary: 1
+        "w": pa.array(rng.integers(0, 300, n) / 4.0),
+        # nulls, one-hot: 1 (the code read)
+        "d": pa.array(rng.integers(0, 50, n).astype(np.int32), mask=mask),
+    })
+    directory = os.path.join(str(tmp_path), "t")
+    os.makedirs(directory)
+    pq.write_table(table, os.path.join(directory, "t.parquet"),
+                   row_group_size=35_000)
+    seen = []
+
+    class Records:
+        def __init__(self, name, **counts):
+            self.name, self.counts = name, dict(counts)
+            seen.append(self)
+
+        def set_metadata(self, **counts):
+            self.counts.update(counts)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Records)
+    keys = []
+    real = PD.plan_decode
+
+    def spy(*args, **kwargs):
+        out = real(*args, **kwargs)
+        keys.append(out[1])
+        return out
+
+    monkeypatch.setattr(PD, "plan_decode", spy)
+    conf = {"spark.rapids.tpu.sql.trace.enabled": True,
+            "spark.rapids.tpu.sql.variableFloatAgg.enabled": True}
+
+    def gathers(section):
+        return [a.counts["gathers"] for a in seen
+                if a.name.endswith("." + section) and "gathers" in a.counts]
+
+    def query(fusion):
+        sess = TpuSession(dict(conf, **{
+            "spark.rapids.tpu.sql.stageFusion": fusion}))
+        return (sess.read.parquet(directory).group_by("q")
+                .agg(A.agg(A.Sum(col("w")), "s"),
+                     A.agg(A.Count(col("d")), "c")).collect())
+
+    assert len(query("OFF")) == 100
+    assert len(keys) == 6 and sum(map(PD.key_gathers, keys)) == 4
+    assert gathers("decode_dispatch") == [2, 2]  # a row group each
+    first = query("ON")
+    assert gathers("stage") == [4]
+    assert sum(map(PD.key_gathers, keys[6:])) == 4
+    # every row group from the scan cache: the same programs are spliced
+    planned = len(keys)
+    assert query("ON") == first and len(keys) == planned
+    assert gathers("stage") == [4, 4]
+
+
 def test_parquet_scan_footprint_and_predict_exec_hbm(tmp_path):
     """The unpack site finally has a layout bound: predict_exec_hbm over
     a live parquet scan tree is non-null (uploaded payloads + decoded
