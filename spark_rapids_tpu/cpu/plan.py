@@ -192,6 +192,39 @@ class CpuFileScanExec(CpuExec):
         yield from zip(*cols) if cols else iter(())
 
 
+class CpuInMemoryTableScanExec(CpuExec):
+    """A plan marked by ``DataFrame.cache()`` (reference: Spark's
+    InMemoryTableScanExec over an InMemoryRelation). ``relation`` is the
+    session's ``sql/cache.CachedRelation``, ``files_key`` the identity of
+    the plan's files as this query found them. The planner's rule hands
+    both to the exec that keeps the relation on the device; on the CPU the
+    child is simply run again, which is what Spark does with a cache it
+    cannot use."""
+
+    def __init__(self, conf: RapidsConf, child: CpuExec, relation,
+                 files_key: tuple = ()):
+        super().__init__(conf, [child])
+        self.relation = relation
+        self.files_key = files_key
+
+    @property
+    def output_schema(self):
+        return self.children[0].output_schema
+
+    def describe(self):
+        return f"CpuInMemoryTableScanExec({self.relation.describe()})"
+
+    def explain_detail(self) -> str:
+        """What ``explain()`` says beside the exec's name."""
+        return "cached: " + self.relation.describe()
+
+    def estimated_size_bytes(self):
+        return self._child_size_estimate()
+
+    def execute_rows_partition(self, index: int) -> Iterator[tuple]:
+        yield from self.children[0].execute_rows_partition(index)
+
+
 class CpuRangeExec(CpuExec):
     def __init__(self, conf: RapidsConf, start: int, end: int, step: int = 1,
                  num_slices: int = 1, name: str = "id"):

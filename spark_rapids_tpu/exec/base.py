@@ -278,6 +278,9 @@ OTHER_PROGRAM_WORDS = ("materialize_dict", "eval_exprs")
 #: piece has one word whether it runs alone (its program's name) or fused
 SCOPE_WORDS = ("pq_decode", "upload_unpack", "fused_chain", "agg_update",
                "agg_merge", "project")
+#: scopes only a program across chips has: the collective exchange
+#: between a mesh stage's partial and final halves (parallel/distributed)
+MESH_SCOPE_WORDS = ("mesh_exchange",)
 
 
 def program(site: str):

@@ -166,6 +166,217 @@ class InMemoryScanExec(TpuExec):
         return InMemoryScanExec(conf, chunks, schema)
 
 
+class TpuInMemoryTableScanExec(TpuExec):
+    """Serves a plan marked by ``DataFrame.cache()`` from the device
+    (reference: the ``InMemoryTableScanExec`` replacement over
+    ``ParquetCachedBatchSerializer``'s columnar cache). The session's
+    ``sql/cache.CachedRelation`` holds what is resident; this exec fills it
+    from the child plan on the first action and serves it on every later
+    one, whichever query's plan the exec belongs to.
+
+    One device: the child plan's batches, kept as they came (claimed from
+    the donation protocol: a retained plane is never donated). A mesh
+    (``shuffle.mode`` other than host, more than one device, a child that
+    can stage itself: ``stage_mesh_planes``): the relation IS the child's
+    ``StagedPlanes``, global arrays under ``row_sharding(mesh)`` with one
+    shard a device, staged once and handed to every mesh stage as they
+    are; partition ``i`` of this exec is then shard ``i``. Resident bytes
+    are booked per device with the ``BufferCatalog``; cached shards are not
+    evicted or spilled, so a fill that does not fit the budget fails by
+    name before it uploads.
+
+    Spans: ``.fill`` (``rows``, ``bytes``, ``shards``; the child scan's own
+    spans nest under it) and ``.serve`` (``hits``: 1 when the relation was
+    resident before the call; ``rows`` and ``bytes`` served from residency,
+    0 on the call that filled; ``source=cached``)."""
+
+    #: what a mesh stage's forecast and actuals call these planes
+    mesh_stage_source = "cached"
+
+    def __init__(self, conf: RapidsConf, child: TpuExec, relation,
+                 files_key: tuple = ()):
+        super().__init__(conf, [child])
+        self.relation = relation
+        self.files_key = files_key
+        self._n_shards = self._mesh_shards()
+
+    def _mesh_shards(self) -> int:
+        """Shards of a mesh-resident relation; 0 = device batches."""
+        rel = self.relation
+        if rel.planes is not None:
+            return len(rel.planes.counts)
+        if rel.batches is not None:
+            return 0
+        from ..parallel.mesh import get_mesh
+        from .mesh import mesh_available
+
+        if not mesh_available(self.conf):
+            return 0
+        items = getattr(self.children[0], "mesh_stage_items", None)
+        if items is None or items() is None:
+            return 0
+        n = int(get_mesh(conf=self.conf).devices.size)
+        return n if n > 1 else 0
+
+    @property
+    def output_schema(self):
+        return self.children[0].output_schema
+
+    @property
+    def num_partitions(self):
+        return self._n_shards or self.children[0].num_partitions
+
+    def describe(self):
+        return f"TpuInMemoryTableScanExec({self.relation.describe()})"
+
+    def host_prefetch(self) -> None:
+        # a resident relation reads no file; a mesh fill stages for itself
+        if not self.relation.filled and not self._n_shards:
+            super().host_prefetch()
+
+    # -- the forecast's view (exec/mesh.forecast_mesh_staging) -------------
+    def mesh_stage_items(self):
+        if not self._n_shards:
+            return None
+        return self.children[0].mesh_stage_items()
+
+    def partition_rows(self):
+        if self._n_shards:
+            planes = self.relation.planes
+            return None if planes is None else [
+                int(c) for c in planes.counts]
+        pr = getattr(self.children[0], "partition_rows", None)
+        return pr() if pr is not None else None
+
+    # -- fill ---------------------------------------------------------------
+    def _fill_planes(self, mesh, n_shards: int, conf, on_shard) -> None:
+        from ..io import mesh_stage as MS
+        from ..memory.catalog import BufferCatalog
+        from ..memory.retry import named_oom
+
+        child = self.children[0]
+        op = f"{self.node_name}.fill"
+        fc = MS.forecast_staging(
+            child.mesh_stage_items(), n_shards,
+            self.conf.shape_bucket_min, self.output_schema.fields)
+        ids = [int(d.id) for d in mesh.devices.reshape(-1)]
+        BufferCatalog.get().check_resident_fit(
+            dict(zip(ids, fc["staged_bytes"])), op)
+        with self.section("fill") as span, named_oom(op):
+            planes = child.stage_mesh_planes(
+                mesh, n_shards, conf, on_shard=on_shard)
+            if planes is None:
+                raise RuntimeError(
+                    f"{child.node_name} forecast a sharded scan and "
+                    "declined to stage it")
+            rows = int(planes.counts.sum())
+            self.relation.store(
+                planes=planes, rows=rows,
+                per_device=dict(zip(ids, planes.staged_bytes)),
+                files_key=self.files_key)
+            span.set(rows=rows, bytes=int(self.relation.bytes),
+                     shards=n_shards)
+
+    def _fill_batches(self) -> None:
+        from ..memory.catalog import BufferCatalog
+        from ..memory.retry import named_oom
+        from ..plugin import donation as _donation
+        from .base import batch_arrays
+
+        child = self.children[0]
+        op = f"{self.node_name}.fill"
+        with self.section("fill") as span, named_oom(op):
+            parts: List[List[ColumnarBatch]] = []
+            sizes: List[Tuple[int, int]] = []
+            for p in range(child.num_partitions):
+                kept = [_donation.claim(b)
+                        for b in child.execute_partition(p)]
+                parts.append(kept)
+                sizes.append((
+                    sum(int(b.num_rows) for b in kept),
+                    sum(int(a.size) * a.dtype.itemsize
+                        for b in kept for a in batch_arrays(b))))
+            rows = sum(r for r, _ in sizes)
+            nbytes = sum(b for _, b in sizes)
+            per_device = {int(jax.devices()[0].id): nbytes}
+            BufferCatalog.get().check_resident_fit(per_device, op)
+            self.relation.store(
+                batches=parts, part_sizes=sizes, rows=rows,
+                per_device=per_device, files_key=self.files_key)
+            span.set(rows=rows, bytes=int(nbytes), shards=1)
+
+    def _serve(self, hit: bool, rows: int, nbytes: int, shards: int):
+        """The ``.serve`` span of one hand-over, and its always-on twins."""
+        self.relation.hits += int(hit)
+        self.metric("cacheHits").add(int(hit))
+        self.metric("cachedBytes", "bytes").set(self.relation.bytes)
+        return self.section(
+            "serve", hits=int(hit), rows=rows if hit else 0,
+            bytes=nbytes if hit else 0, shards=shards, source="cached")
+
+    # -- serve ----------------------------------------------------------------
+    def _resident_planes(self, mesh, n_shards: int, conf, on_shard=None):
+        """(the relation's planes, were they resident before this call);
+        the first call stages the child scan, under ``.fill``."""
+        rel = self.relation
+        with rel.lock:
+            hit = rel.planes is not None
+            if not hit:
+                self._fill_planes(mesh, n_shards, conf, on_shard)
+            return rel.planes, hit
+
+    def stage_mesh_planes(self, mesh, n_shards: int, conf, on_shard=None):
+        """The mesh stages' hand-over: the resident planes as they are —
+        no host decode, no ``device_put``, no copy, no donation. The first
+        action answers from the planes it has just cached."""
+        if not self._n_shards or n_shards != self._n_shards:
+            return None
+        planes, hit = self._resident_planes(mesh, n_shards, conf, on_shard)
+        with self._serve(hit, self.relation.rows, self.relation.bytes,
+                         n_shards):
+            return planes._replace(
+                source=self.mesh_stage_source,
+                uploaded_bytes=0 if hit else planes.uploaded_bytes)
+
+    def _shard_batch(self, planes, index: int) -> ColumnarBatch:
+        """Shard ``index`` of mesh-resident planes as a batch on the
+        default device, for a consumer that is not a mesh stage."""
+        n, cap = int(planes.counts[index]), planes.cap
+        home = jax.devices()[0]
+        cols = []
+        for j, f in enumerate(self.output_schema.fields):
+            pair = []
+            for plane in planes.cols[2 * j: 2 * j + 2]:
+                shard = next(s for s in plane.addressable_shards
+                             if (s.index[0].start or 0) == index * cap)
+                pair.append(jax.device_put(shard.data, home))
+            cols.append(DeviceColumn(f.dataType, n, pair[0], pair[1]))
+        return ColumnarBatch(cols, self.output_schema, n)
+
+    def execute_partition(self, index: int) -> Iterator[ColumnarBatch]:
+        rel = self.relation
+        if self._n_shards:
+            from ..parallel.mesh import get_mesh
+
+            planes, hit = self._resident_planes(
+                get_mesh(conf=self.conf), self._n_shards, self.conf)
+            n = int(planes.counts[index])
+            with self._serve(hit, n, int(planes.staged_bytes[index]), 1):
+                batch = self._shard_batch(planes, index) if n else None
+            if batch is not None:
+                yield self.record_batch(batch)
+            return
+        with rel.lock:
+            hit = rel.batches is not None
+            if not hit:
+                self._fill_batches()
+            batches = list(rel.batches[index])
+        with self._serve(hit, *rel.part_sizes[index], 1):
+            pass
+        for b in batches:
+            yield self.record_batch(b)
+
+
 _PROJECT_CACHE: dict = {}
 
 

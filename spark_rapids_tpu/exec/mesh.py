@@ -51,6 +51,7 @@ from ..conf import RapidsConf
 from ..expr import aggregates as A
 from ..expr import expressions as E
 from ..expr.eval import ColV, lower
+from ..ops import groupby as groupby_ops
 from ..ops.sort import SortOrder
 from ..parallel import distributed as D
 from ..parallel.mesh import AXIS, get_mesh, row_sharding
@@ -74,10 +75,10 @@ class StagedChild:
     plananalysis cross-check compares against its forecast."""
 
     __slots__ = ("cols", "counts", "cap", "layout", "smls", "steps",
-                 "staged_bytes", "source")
+                 "staged_bytes", "source", "h2d_bytes")
 
     def __init__(self, cols, counts, cap, layout, smls, steps=(),
-                 staged_bytes=(), source="host"):
+                 staged_bytes=(), source="host", h2d_bytes=0):
         self.cols = cols
         self.counts = counts
         self.cap = cap
@@ -86,6 +87,8 @@ class StagedChild:
         self.steps = tuple(steps)
         self.staged_bytes = tuple(staged_bytes)
         self.source = source
+        #: bytes THIS staging sent to the devices (0: resident planes)
+        self.h2d_bytes = h2d_bytes
 
     def steps_sig(self) -> tuple:
         return tuple(s.fusion_key() for s in self.steps)
@@ -174,8 +177,25 @@ class _MeshStage(TpuExec):
 
     def _stage_child(self, child: TpuExec) -> StagedChild:
         """Stage ``child`` onto the mesh: absorb the fixed-width chain,
-        then either the child's own sharded-scan path (no host gather) or
-        the generic host-gather staging."""
+        then either the child's own planes (a sharded scan stages them
+        with no host gather; a cached relation hands over the planes it
+        keeps on the devices) or the generic host-gather staging. The
+        span ``<Exec>.stage`` carries what this hand-over sent to the
+        devices (``h2d_bytes``: 0 from resident planes), where the planes
+        came from (``source``) and how the rows lie over the shards
+        (``shards``, ``shard_rows_max``, ``shard_rows_sum``)."""
+        with self.section("stage") as span:
+            staged = self._stage_child_planes(child)
+            if span.on:
+                rows = [int(c) for c in staged.counts]
+                span.set(h2d_bytes=int(staged.h2d_bytes),
+                         source=staged.source, shards=len(rows),
+                         shard_rows_max=max(rows, default=0),
+                         shard_rows_sum=sum(rows))
+        self.metric("h2dBytes", "bytes").add(int(staged.h2d_bytes))
+        return staged
+
+    def _stage_child_planes(self, child: TpuExec) -> StagedChild:
         base, steps = self._absorb_chain(child)
         fast = getattr(base, "stage_mesh_planes", None)
         if fast is not None:
@@ -185,11 +205,13 @@ class _MeshStage(TpuExec):
                 return StagedChild(
                     list(staged.cols), staged.counts, staged.cap,
                     staged.layout, staged.smls, steps,
-                    staged.staged_bytes, source="sharded_scan")
+                    staged.staged_bytes, source=staged.source,
+                    h2d_bytes=staged.uploaded_bytes)
         cols, counts, cap, layout, smls, staged_bytes = \
             self._stage_host(base)
         return StagedChild(cols, counts, cap, layout, smls, steps,
-                           staged_bytes, source="host")
+                           staged_bytes, source="host",
+                           h2d_bytes=sum(staged_bytes))
 
     def _stage_host(self, child: TpuExec):
         """Materialize every child partition and lay rows onto the mesh:
@@ -383,7 +405,8 @@ class _MeshStage(TpuExec):
         fn = getattr(base, "mesh_stage_items", None)
         if fn is not None:
             items = fn()
-        source = "sharded_scan" if items is not None else "host"
+        source = (getattr(base, "mesh_stage_source", "sharded_scan")
+                  if items is not None else "host")
         if items is None:
             pr = getattr(base, "partition_rows", None)
             if pr is None:
@@ -391,24 +414,18 @@ class _MeshStage(TpuExec):
             items = pr()
             if items is None:
                 return None
-        assign = MS.round_robin(len(items), self.n_shards)
-        per_shard = [sum(items[i] for i in idxs) for idxs in assign]
-        cap = MS.mesh_shard_cap(per_shard, self.conf.shape_bucket_min)
         fields = base.output_schema.fields
-        fixed = all(T.is_fixed_width(f.dataType) for f in fields)
-        return {
+        out = MS.forecast_staging(
+            items, self.n_shards, self.conf.shape_bucket_min, fields)
+        out.update({
             "source": source,
             "n_shards": self.n_shards,
-            "cap": cap,
-            "per_shard_rows": per_shard,
-            "staged_bytes": (
-                [MS.shard_plane_bytes(cap, fields)] * self.n_shards
-                if fixed else None),
             "absorbed_steps": [s.node_name for s in steps],
             "columns": [
                 (f.name, f.dataType.simpleString) for f in fields
             ],
-        }
+        })
+        return out
 
     def _record_staging(self, staged: StagedChild, which: str = "") -> None:
         key = f"staging{('_' + which) if which else ''}"
@@ -487,6 +504,19 @@ class _MeshStage(TpuExec):
 
 
 _PROGRAM_CACHE: dict = {}
+
+#: the largest per-shard capacity (a power of two) that the mesh aggregate
+#: updates in one piece. A shard with more slots is updated chunk by chunk
+#: inside the SPMD program (one loop over slices of the planes, a slice's
+#: partial cut to ``shuffle.mesh.aggExchangeCapacity`` groups, the slices'
+#: partial rows crossing the exchange unmerged), so the update's
+#: temporaries follow the chunk and not the shard: at 2^27 slots a shard
+#: (TPC-DS SF100 store_sales over four chips) the v5e compiler refuses the
+#: one-piece update (21 GB of temporaries beside 3.2 GB of planes) and
+#: takes 16 chunks of 2^23, the capacity the first four-chip runs had
+#: (tests/test_tpu_compile.py asks). A constant, not a conf: one value is
+#: in use, and tests shrink it by patching this name.
+AGG_UPDATE_CHUNK_ROWS = 1 << 23
 
 
 def _cached_program(key, builder, site: Optional[str] = None,
@@ -588,28 +618,92 @@ class TpuMeshAggregateExec(_MeshStage):
             cap)
         if key_smls or any(lay[0] != "f" for lay in layout):
             gcap = 0  # strings cross at full capacity (no slicing)
+        # a shard larger than the update chunk is updated chunk by chunk
+        # (the update's temporaries follow the chunk, not the shard)
+        chunk = AGG_UPDATE_CHUNK_ROWS
+
+        def update_inputs(cols, live, rows):
+            """One piece of a shard through the absorbed chain, to the
+            update's keys and values. The one-chip programs' scope words
+            (exec/base): ``fused_chain``, then ``agg_update``."""
+            with jax.named_scope("fused_chain"):
+                cols, live = self._apply_steps(steps, cols, live, rows)
+            with jax.named_scope("agg_update"):
+                keys = [lower(b, cols, rows) for b in bound_keys]
+                vals = [
+                    None if e is None else lower(e, cols, rows)
+                    for e in update_exprs
+                ]
+            return keys, vals, live
+
+        def chunked_partials(colflat, n, group_cap, pieces):
+            """The PARTIAL aggregate of a large shard, a chunk at a time:
+            one loop over ``pieces`` slices of the planes, each slice's
+            groups compacted and cut to ``group_cap`` rows. Returns the
+            partial rows of all slices (keys, buffers, live mask) and
+            whether every slice's groups fitted."""
+
+            def one(at):
+                # a slice of the resident planes, not a reshaped copy
+                cols = self._cols_of_flat(
+                    [jax.lax.dynamic_slice(p, (at * chunk,), (chunk,))
+                     for p in colflat], layout)
+                live = at * chunk + jnp.arange(
+                    chunk, dtype=jnp.int32) < n
+                keys, vals, live = update_inputs(cols, live, chunk)
+                with jax.named_scope("agg_update"):
+                    pk, pa, pn = groupby_ops.groupby_agg(
+                        keys, key_dtypes, vals, list(update_ops), live,
+                        ())
+                out = []
+                for c in list(pk) + list(pa):
+                    out.extend([c.data[:group_cap],
+                                c.validity[:group_cap]])
+                return (tuple(out), jnp.minimum(pn, group_cap),
+                        pn <= group_cap)
+
+            planes, counts, fits = jax.lax.map(
+                one, jnp.arange(pieces, dtype=jnp.int32))
+            rows = [ColV(planes[2 * i].reshape(-1),
+                         planes[2 * i + 1].reshape(-1))
+                    for i in range(len(planes) // 2)]
+            live = (jnp.arange(group_cap, dtype=jnp.int32)[None, :]
+                    < counts[:, None]).reshape(-1)
+            return rows[:nk], rows[nk:], live, jnp.all(fits)
 
         while True:
             out_layouts: dict = {}
             group_cap = 0 if gcap >= cap else gcap
+            pieces = cap // chunk if 0 < group_cap < chunk < cap else 1
+            self.mesh_actuals["update_chunks"] = pieces
 
-            def build(group_cap=group_cap, out_layouts=out_layouts):
+            def build(group_cap=group_cap, out_layouts=out_layouts,
+                      pieces=pieces):
+                by_chunk = pieces > 1
+
                 @program("mesh_agg")
                 def shard_fn(*flat):
                     *colflat, cnt = flat
-                    cols = self._cols_of_flat(colflat, layout)
                     n = cnt[0]
-                    live = jnp.arange(cap, dtype=jnp.int32) < n
-                    cols, live = self._apply_steps(steps, cols, live, cap)
-                    keys = [lower(b, cols, cap) for b in bound_keys]
-                    vals = [
-                        None if e is None else lower(e, cols, cap)
-                        for e in update_exprs
-                    ]
+                    fitted = None
+                    if by_chunk:
+                        # the chunks' partial rows cross as they are:
+                        # dist_groupby's FINAL half merges them
+                        keys, vals, live, fitted = chunked_partials(
+                            colflat, n, group_cap, pieces)
+                    else:
+                        cols = self._cols_of_flat(colflat, layout)
+                        live = jnp.arange(cap, dtype=jnp.int32) < n
+                        keys, vals, live = update_inputs(cols, live, cap)
                     rkeys, raggs, rn, ok = D.dist_groupby(
                         keys, key_dtypes, vals, list(update_ops),
                         list(merge_ops), live, AXIS, n_shards,
-                        str_max_lens=key_smls, group_cap=group_cap)
+                        str_max_lens=key_smls,
+                        group_cap=0 if by_chunk else group_cap,
+                        partials=by_chunk)
+                    if fitted is not None:
+                        ok = ok & (jax.lax.psum(
+                            fitted.astype(jnp.int32), AXIS) == n_shards)
                     # result projection over [keys..., buffers...] per shard
                     allv = list(rkeys) + list(raggs)
                     rcap = allv[0].validity.shape[0] if allv else 1
@@ -624,7 +718,8 @@ class TpuMeshAggregateExec(_MeshStage):
                             for j in range(s, e)
                         )
                         exprs.append(f.evaluate(refs))
-                    outs = [lower(x, allv, rcap) for x in exprs]
+                    with jax.named_scope("project"):
+                        outs = [lower(x, allv, rcap) for x in exprs]
                     flat_out, out_lay = self._flatten_vals(outs)
                     out_layouts["lay"] = out_lay
                     flat_out.append(rn.reshape(1))
@@ -642,16 +737,27 @@ class TpuMeshAggregateExec(_MeshStage):
             sig = tuple((str(a.dtype), a.shape) for a in global_cols)
             fn, out_layouts = _cached_program(
                 ("agg", self.fusion_sig(), staged.steps_sig(), sig, cap,
-                 n_shards, key_smls, group_cap),
+                 n_shards, key_smls, group_cap, pieces),
                 build, site="mesh_agg", on_miss=self._note_program_miss)
             cnt_in = jax.device_put(
                 np.asarray(counts, np.int32), row_sharding(mesh))
+            # rows of one exchanged block: the sliced partial, every
+            # chunk's sliced partial, or the whole shard
+            xbytes = self.exchange_bytes(pieces * group_cap or cap)
             t0 = _time.perf_counter_ns()
-            res = fn(*global_cols, cnt_in)
+            with self.section("spmd", exchange_bytes=xbytes,
+                              exchange_cap=group_cap or cap,
+                              update_chunks=pieces):
+                res = fn(*global_cols, cnt_in)
+            self.metric("exchangeBytes", "bytes").add(xbytes)
             *out_cols, out_counts, oks = res
-            if group_cap == 0 or bool(np.all(_np_of(oks))):
+            if group_cap:
+                with self.section("overflow_pull"):
+                    fits = bool(np.all(_np_of(oks)))
+            if group_cap == 0 or fits:
                 self._record_run(list(out_cols) + [out_counts], t0)
                 self.mesh_actuals["exchange_cap"] = group_cap or cap
+                self.mesh_actuals["exchange_bytes"] = xbytes
                 break
             # a shard had more groups than the exchange cap: double it
             # (the aggregate analog of the join's output-capacity retry)
@@ -659,9 +765,22 @@ class TpuMeshAggregateExec(_MeshStage):
         out_lay = out_layouts.get("lay") or tuple(
             ("s",) if T.is_string(f.dataType) else ("f",)
             for f in self._schema.fields)
-        self._outputs = self._emit(
-            self._schema, list(out_cols), _np_of(out_counts), 0,
-            layout=out_lay)
+        with self.section("emit"):
+            self._outputs = self._emit(
+                self._schema, list(out_cols), _np_of(out_counts), 0,
+                layout=out_lay)
+
+    def exchange_bytes(self, xrows: int) -> int:
+        """Bytes one run of the program hands to ``all_to_all`` over all
+        shards: every shard sends ``n_shards`` blocks of ``xrows`` rows of
+        the partial's columns (keys and buffers, a data and a validity
+        plane each; a string key's byte plane is not counted) and its
+        block counts. From the shapes, so the same on every backend."""
+        row = sum(np.dtype(f.dataType.to_numpy()).itemsize + 1
+                  for f in list(self._key_fields) + list(self._buf_fields)
+                  if T.is_fixed_width(f.dataType))
+        n = self.n_shards
+        return n * (n * xrows * row + n * 4)
 
     def fusion_sig(self):
         return (

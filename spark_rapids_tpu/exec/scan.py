@@ -289,8 +289,13 @@ class TpuFileSourceScanExec(TpuExec):
         device (io/mesh_stage.stage_sharded) — PR 7's decode→upload
         pipeline extended across devices. Fixed-width file columns only
         (partition-value columns are strings and keep the generic path).
-        Reads bypass the device scan cache: the cache holds default-
-        device batches, which would have to cross devices again."""
+        Every call decodes and uploads the whole table: the device scan
+        cache holds default-device batches and is not consulted.
+        Residency across queries comes from ``DataFrame.cache()``: the
+        cached relation (exec/basic.TpuInMemoryTableScanExec) calls this
+        once and keeps the planes. Spans as on one chip: ``host_decode``
+        a shard (``columns`` = column chunks, ``names``) on the worker's
+        thread, ``upload`` a shard (``bytes``)."""
         from ..io import mesh_stage as MS
 
         if getattr(self.scanner, "partition_cols", None):
@@ -312,7 +317,9 @@ class TpuFileSourceScanExec(TpuExec):
 
             from ..io.arrow_convert import _np_from_arrow_array
 
-            with self.op_timed("mesh_decode", DECODE_TIME):
+            with self.section("host_decode",
+                              columns=len(columns) * len(assign[s]),
+                              names=",".join(columns), shard=s):
                 by_path = {}
                 for i in assign[s]:
                     path, rg, _ = rgs[i]
@@ -337,9 +344,10 @@ class TpuFileSourceScanExec(TpuExec):
                     arrays.append((d, v))
             return MS.ShardPayload(arrays, total)
 
-        return MS.stage_sharded(
-            mesh, n_shards, schema, decode_shard, rows_per_shard,
-            self.conf.shape_bucket_min, on_shard=on_shard)
+        with self.op_timed("decode", DECODE_TIME):
+            return MS.stage_sharded(
+                mesh, n_shards, schema, decode_shard, rows_per_shard,
+                self.conf.shape_bucket_min, on_shard=on_shard)
 
     def partition_rows(self):
         """Static per-split row counts from parquet metadata (None when

@@ -47,7 +47,11 @@ class StagedPlanes(NamedTuple):
     (data+validity per column, each a NamedSharding row-sharded array),
     per-shard live row counts, the common per-shard capacity, the column
     layout/smls tuples of the generic staging path, and per-shard staged
-    byte counts for the transfer events + the plananalysis cross-check."""
+    byte counts for the transfer events + the plananalysis cross-check.
+    ``source`` says where the planes came from: ``"sharded_scan"`` staged
+    for this stage, ``"cached"`` a cached relation's resident planes
+    (exec/basic.TpuInMemoryTableScanExec); ``uploaded_bytes`` what THIS
+    hand-over sent to the devices (0 when the planes were resident)."""
 
     cols: List[object]
     counts: np.ndarray
@@ -55,6 +59,8 @@ class StagedPlanes(NamedTuple):
     layout: tuple
     smls: tuple
     staged_bytes: tuple
+    source: str = "sharded_scan"
+    uploaded_bytes: int = 0
 
 
 def mesh_shard_cap(rows_per_shard: Sequence[int], bucket_min: int) -> int:
@@ -77,6 +83,26 @@ def shard_plane_bytes(cap: int, fields) -> int:
 
 def stageable_schema(schema: StructType) -> bool:
     return all(T.is_fixed_width(f.dataType) for f in schema.fields)
+
+
+def forecast_staging(items: Sequence[int], n_shards: int, bucket_min: int,
+                     fields) -> dict:
+    """What staging ``items`` (row counts, item i -> shard i % n) will
+    place on each shard: rows, the common capacity and — for a
+    fixed-width schema — the plane bytes. The mesh stages' forecast
+    (exec/mesh.forecast_mesh_staging) and a cached relation's check
+    before its fill both read this, so neither can drift from
+    ``stage_sharded``."""
+    per_shard = [sum(items[i] for i in idxs)
+                 for idxs in round_robin(len(items), n_shards)]
+    cap = mesh_shard_cap(per_shard, bucket_min)
+    fixed = all(T.is_fixed_width(f.dataType) for f in fields)
+    return {
+        "cap": cap,
+        "per_shard_rows": per_shard,
+        "staged_bytes": ([shard_plane_bytes(cap, fields)] * n_shards
+                         if fixed else None),
+    }
 
 
 def stage_sharded(
@@ -117,6 +143,7 @@ def stage_sharded(
     staged_bytes = [0] * n_shards
 
     def upload_shard(s: int, payload: ShardPayload) -> None:
+        from ..exec.base import phase
         from ..memory.retry import named_oom
 
         t0 = time.perf_counter()
@@ -126,7 +153,11 @@ def stage_sharded(
         # a device allocation failure placing a shard's planes surfaces
         # as TpuOutOfDeviceMemory naming the shard, never a raw XLA
         # traceback mid-pipeline
-        with named_oom(f"mesh_stage[shard {s}]"):
+        # the h2d boundary of the scan that stages (one span a shard,
+        # like io/arrow_convert.packed_upload's one a batch): padding +
+        # transfer, sized by the bytes that cross the link
+        with phase("upload") as span, \
+                named_oom(f"mesh_stage[shard {s}]"):
             for j, f in enumerate(fields):
                 dt = f.dataType.to_numpy()
                 d = np.zeros(cap, dt)
@@ -140,6 +171,7 @@ def stage_sharded(
                 pieces[2 * j][s] = dd
                 pieces[2 * j + 1][s] = vv
                 nbytes += d.nbytes + v.nbytes
+            span.set(bytes=int(nbytes), shard=s)
         staged_bytes[s] = nbytes
         if on_shard is not None:
             on_shard(s, n, nbytes, time.perf_counter() - t0)
@@ -148,10 +180,14 @@ def stage_sharded(
     # pads + uploads shard k
     with ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="srtpu-meshdec") as pool:
-        nxt = pool.submit(decode_shard, 0) if n_shards else None
+        from ..exec.base import carry
+
+        # the worker's spans belong to the exec and query open here
+        decode = carry(decode_shard)
+        nxt = pool.submit(decode, 0) if n_shards else None
         for s in range(n_shards):
             payload = nxt.result()
-            nxt = (pool.submit(decode_shard, s + 1)
+            nxt = (pool.submit(decode, s + 1)
                    if s + 1 < n_shards else None)
             upload_shard(s, payload)
 
@@ -162,7 +198,8 @@ def stage_sharded(
     layout = tuple(("f",) for _ in fields)
     smls = tuple(0 for _ in fields)
     return StagedPlanes(cols, counts, cap, layout, smls,
-                        tuple(staged_bytes))
+                        tuple(staged_bytes),
+                        uploaded_bytes=sum(staged_bytes))
 
 
 def round_robin(num_items: int, n_shards: int) -> List[List[int]]:

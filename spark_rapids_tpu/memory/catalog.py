@@ -32,7 +32,7 @@ from ..conf import (
     conf,
 )
 from ..utils.locks import ordered_lock
-from .ledger import KIND_RESERVATION, Ledger
+from .ledger import KIND_CACHED_RELATION, KIND_RESERVATION, Ledger
 
 log = logging.getLogger("spark_rapids_tpu.memory")
 
@@ -121,6 +121,13 @@ class BufferCatalog:
         #: observed-peak admission feed) — armed only while events/obs
         #: are on (or force-armed by bench/tests)
         self.ledger = Ledger(self.conf)
+        # cached relations (DataFrame.cache()): rid -> ({device id:
+        # bytes}, label, ledger id). Resident for a session, a share on
+        # each device of a mesh, never spilled: the only bytes the
+        # catalog books per device (every spillable buffer lives on the
+        # default device, id 0).
+        self._resident: Dict[int, tuple] = {}
+        self._resident_bytes: Dict[int, int] = {}
 
     # -- singleton (reference: RapidsBufferCatalog.singleton) --------------
     @classmethod
@@ -289,7 +296,8 @@ class BufferCatalog:
         # handle-then-catalog — never holding one while acquiring the other
         # in the opposite order avoids a lock-order inversion
         with self._lock:
-            need = self._device_bytes + nbytes - self._budget
+            need = (self._device_bytes + self._resident_bytes.get(0, 0)
+                    + nbytes - self._budget)
             victims = sorted(
                 (h for h in self._buffers.values()
                  if h.tier == TIER_DEVICE and not h.pinned
@@ -364,6 +372,76 @@ class BufferCatalog:
     @property
     def device_bytes(self) -> int:
         return self._device_bytes
+
+    # -- cached relations (exec/basic.TpuInMemoryTableScanExec) ------------
+    def resident_bytes(self, device: Optional[int] = None):
+        """Bytes cached relations hold on device ``device``, or the
+        ``{device id: bytes}`` map of every device that holds any."""
+        with self._lock:
+            if device is None:
+                return dict(self._resident_bytes)
+            return self._resident_bytes.get(device, 0)
+
+    def check_resident_fit(self, per_device: Dict[int, int],
+                           op: str) -> None:
+        """Before a cached relation fills: would ``per_device`` more
+        resident bytes fit the budget on every device, beside what is
+        resident already (and, on the default device, the spillable
+        buffers)? Cached shards are never spilled, so a fill that does
+        not fit fails here, by name, before anything is uploaded."""
+        from .retry import TpuOutOfDeviceMemory
+
+        with self._lock:
+            if self._budget is None:
+                return
+            for dev, nbytes in sorted(per_device.items()):
+                held = self._resident_bytes.get(dev, 0) + (
+                    self._device_bytes if dev == 0 else 0)
+                if held + nbytes > self._budget:
+                    raise TpuOutOfDeviceMemory(
+                        f"cached relation does not fit device {dev} in "
+                        f"{op}: {nbytes} B to make resident beside "
+                        f"{held} B held, budget {self._budget} B "
+                        "(cached shards are not spilled: unpersist() a "
+                        "relation or raise memory.hbm.budgetBytes)",
+                        op=op, watermark=held, budget=self._budget)
+
+    def register_resident(self, per_device: Dict[int, int],
+                          label: str = "") -> int:
+        """Book a filled cached relation: ``per_device`` bytes stay on
+        each device until :meth:`unregister_resident`."""
+        with self._lock:
+            rid = self._next_rid
+            self._next_rid += 1
+            total = sum(per_device.values())
+            lid = self.ledger.note_alloc(
+                total, kind=KIND_CACHED_RELATION,
+                site=f"cached:{label}" if label else "cached",
+            ) if self.ledger.armed() else None
+            self._resident[rid] = (dict(per_device), label, lid)
+            for dev, nbytes in per_device.items():
+                self._resident_bytes[dev] = (
+                    self._resident_bytes.get(dev, 0) + nbytes)
+            if self.conf.get(MEMORY_DEBUG):
+                log.info("cached relation %s resident: %s", label,
+                         self._resident_bytes)
+        # what is resident on the default device narrows the room of
+        # the spillable buffers there
+        self.request(0)
+        return rid
+
+    def unregister_resident(self, rid: int) -> None:
+        with self._lock:
+            entry = self._resident.pop(rid, None)
+            if entry is None:
+                return
+            for dev, nbytes in entry[0].items():
+                left = self._resident_bytes.get(dev, 0) - nbytes
+                if left > 0:
+                    self._resident_bytes[dev] = left
+                else:
+                    self._resident_bytes.pop(dev, None)
+            self.ledger.note_free(entry[2], reason="unpersist")
 
     # -- admission reservations (serve/scheduler.py) -----------------------
     def observed_query_peak(self, query_id: Optional[str]

@@ -71,13 +71,17 @@ KIND_RESERVATION = "reservation"
 #: outliving one query is the point — the creating site DECLARES it
 #: (SpillableHandle ledger_kind) instead of the sentinel guessing
 KIND_PLAN_STATE = "plan_state"
+#: a cached relation's resident planes (DataFrame.cache()): held for the
+#: session, freed by unpersist()
+KIND_CACHED_RELATION = "cached_relation"
 
 #: kinds the leak sentinel never flags: scan-cache entries and declared
 #: plan state outlive queries by design, reservations are released by
 #: the scheduler AFTER the query window closes (session finally ->
 #: sched.release ordering)
 SWEEP_EXEMPT_KINDS = frozenset(
-    {KIND_SCAN_CACHE, KIND_RESERVATION, KIND_PLAN_STATE})
+    {KIND_SCAN_CACHE, KIND_RESERVATION, KIND_PLAN_STATE,
+     KIND_CACHED_RELATION})
 
 #: bounded history sizes (per-digest observed peaks / per-query peaks)
 _DIGEST_HISTORY = 256

@@ -44,6 +44,7 @@ def dist_groupby(
     n_shards: int,
     str_max_lens: Sequence[int] = (),
     group_cap: int = 0,
+    partials: bool = False,
 ) -> Tuple[List[ColV], List[ColV], jax.Array, jax.Array]:
     """PARTIAL local aggregate -> key-hash all_to_all -> FINAL merge.
 
@@ -65,12 +66,23 @@ def dist_groupby(
     retry). 0 disables slicing, ``ok`` is then always True. Fixed-width
     columns only (string group keys keep the full-capacity exchange).
 
+    ``partials``: the inputs are partial rows already (keys and buffers
+    of an update the caller ran piece by piece, exec/mesh's chunked
+    update); the PARTIAL half is skipped and they cross as they are.
+
     Returns (keys, aggs, count, ok) — ``ok`` is globally reduced.
     """
-    # PARTIAL: local groupby shrinks rows before they cross the wire
-    pkeys, paggs, pn = groupby_ops.groupby_agg(
-        key_cols, key_dtypes, value_cols, list(update_ops), num_rows,
-        str_max_lens)
+    # PARTIAL: local groupby shrinks rows before they cross the wire.
+    # The three phases carry the scope words of exec/base (the one-chip
+    # programs' ``agg_update`` / ``agg_merge``, and ``mesh_exchange``):
+    # names on the profiler's clock, nothing the program computes
+    if partials:
+        pkeys, paggs, pn = list(key_cols), list(value_cols), num_rows
+    else:
+        with jax.named_scope("agg_update"):
+            pkeys, paggs, pn = groupby_ops.groupby_agg(
+                key_cols, key_dtypes, value_cols, list(update_ops),
+                num_rows, str_max_lens)
 
     all_cols = list(pkeys) + list(paggs)
     cap = all_cols[0].validity.shape[0] if all_cols else 0
@@ -89,19 +101,22 @@ def dist_groupby(
 
     # exchange by key hash (same murmur3+pmod as the single-host exchange);
     # string keys cross via the byte plane of the collective
-    h = hashing.murmur3(list(pkeys), list(key_dtypes),
-                        str_max_lens=str_max_lens)
-    pids = hashing.partition_ids(h, n_shards)
-    recvd, rn, x_ok = all_to_all_exchange(
-        all_cols, pids, pn, axis_name, n_shards)
-    ok = x_ok & (
-        lax.psum(ok_local.astype(jnp.int32), axis_name) == n_shards)
+    with jax.named_scope("mesh_exchange"):
+        h = hashing.murmur3(list(pkeys), list(key_dtypes),
+                            str_max_lens=str_max_lens)
+        pids = hashing.partition_ids(h, n_shards)
+        recvd, rn, x_ok = all_to_all_exchange(
+            all_cols, pids, pn, axis_name, n_shards)
+        ok = x_ok & (
+            lax.psum(ok_local.astype(jnp.int32), axis_name) == n_shards)
     rkeys = recvd[: len(pkeys)]
     raggs = recvd[len(pkeys):]
 
     # FINAL: merge partial buffers locally (keys now shard-disjoint)
-    fkeys, faggs, fn_ = groupby_ops.groupby_agg(
-        rkeys, key_dtypes, list(raggs), list(merge_ops), rn, str_max_lens)
+    with jax.named_scope("agg_merge"):
+        fkeys, faggs, fn_ = groupby_ops.groupby_agg(
+            rkeys, key_dtypes, list(raggs), list(merge_ops), rn,
+            str_max_lens)
     return fkeys, faggs, fn_, ok
 
 

@@ -471,6 +471,14 @@ def _convert_file_scan(cpu: "C.CpuFileScanExec", conf, children):
     return TpuFileSourceScanExec(conf, cpu.scanner, cpu.fmt)
 
 
+def _convert_cached(cpu: "C.CpuInMemoryTableScanExec", conf, children):
+    """A plan marked by ``DataFrame.cache()``: the exec that keeps the
+    relation on the device(s) and serves it (reference: the
+    InMemoryTableScanExec replacement over ParquetCachedBatchSerializer)."""
+    return XB.TpuInMemoryTableScanExec(
+        conf, children[0], cpu.relation, cpu.files_key)
+
+
 def _has_string_hash(e: E.Expression, schema: StructType) -> bool:
     """hash() with a string input (expr may be unbound: bind to type)."""
     if isinstance(e, E.Murmur3Hash):
@@ -986,6 +994,9 @@ _exec_rule(C.CpuCollectLimitExec, "CollectLimitExec", "global row limit",
 _exec_rule(C.CpuExpandExec, "ExpandExec", "expand projections", _tag_expand, _convert_expand)
 _exec_rule(C.CpuGenerateExec, "GenerateExec", "explode generator rows",
            _tag_expand, _convert_expand)
+_exec_rule(C.CpuInMemoryTableScanExec, "InMemoryTableScanExec",
+           "scan of a relation cached by DataFrame.cache()",
+           _tag_scan, _convert_cached)
 _exec_rule(C.CpuHashAggregateExec, "HashAggregateExec", "hash aggregation",
            _tag_aggregate, _convert_aggregate)
 _exec_rule(C.CpuSortExec, "SortExec", "sort", _tag_sort, _convert_sort)
@@ -1052,8 +1063,10 @@ class PlanMeta:
         named without reading code."""
         name = self.rule.name if self.rule else self.wrapped.node_name
         pad = "  " * indent
+        detail = getattr(self.wrapped, "explain_detail", None)
         if self.can_replace:
-            lines = [f"{pad}*Exec <{name}> will run on TPU"]
+            lines = [f"{pad}*Exec <{name}> will run on TPU"
+                     + (f" ({detail()})" if detail else "")]
         else:
             why = "; ".join(self.reasons)
             lines = [f"{pad}!Exec <{name}> cannot run on TPU because {why}"]

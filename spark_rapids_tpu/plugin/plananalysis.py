@@ -567,6 +567,7 @@ class _Analyzer:
         handlers = {
             C.CpuScanExec: self._scan,
             C.CpuFileScanExec: self._file_scan,
+            C.CpuInMemoryTableScanExec: self._cached,
             C.CpuRangeExec: self._range,
             C.CpuProjectExec: self._project,
             C.CpuFilterExec: self._filter,
@@ -761,6 +762,18 @@ class _Analyzer:
             report=OpReport(node.node_name, "", layout, None, {}, False,
                             notes, []),
             exact=False)
+
+    def _cached(self, node: "C.CpuInMemoryTableScanExec") -> _Result:
+        """A plan marked by ``DataFrame.cache()``: the child's shapes,
+        served from the device once the first action has filled the
+        relation (what is resident is said, not forecast)."""
+        kid = self.analyze(node.children[0])
+        self._finalize_chain(kid)
+        report = OpReport(
+            "TpuInMemoryTableScanExec", "", kid.layout,
+            self._total_bytes(kid.parts), {}, kid.exact,
+            ["cached relation: " + node.relation.describe()], [kid.report])
+        return _Result(kid.parts, kid.layout, report, kid.exact)
 
     def _model_parquet_scan(self, node, schema: StructType,
                             notes: List[str]) -> None:

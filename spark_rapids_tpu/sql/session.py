@@ -29,6 +29,7 @@ from ..expr import expressions as E
 from ..plugin.overrides import TpuOverrides
 from ..types import StructType
 from ..utils import locks as _locks
+from .cache import CacheManager
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,10 +155,36 @@ def _resolve_udfs(e: E.Expression, conf: RapidsConf) -> E.Expression:
     return e.transform(rw)
 
 
-def _lower(node: LNode, conf: RapidsConf) -> C.CpuExec:
+def _lower(node: LNode, conf: RapidsConf, cache=None) -> C.CpuExec:
+    """Logical plan -> CPU physical plan. ``cache`` is the session's
+    ``CacheManager``: a subtree that ``DataFrame.cache()`` marked lowers
+    to a ``CpuInMemoryTableScanExec`` over itself (Spark's useCachedData),
+    whichever DataFrame it is reached from."""
+    rel = cache.lookup(node) if cache else None
+    if rel is None:
+        return _lower_node(node, conf, cache)
+    from .cache import file_scan_paths, files_identity
+
+    now = files_identity(node)
+    if rel.filled and rel.files_key != now:
+        # a file under the plan was rewritten: what is resident answers
+        # for another file. Free it, forget the scanners that parsed the
+        # old footers, and let this query's first action refill
+        rel.release()
+        paths = set(file_scan_paths(node))
+        with _SCANNER_CACHE_LOCK:
+            for key in [k for k in _SCANNER_CACHE if k[1] in paths]:
+                del _SCANNER_CACHE[key]
+    return C.CpuInMemoryTableScanExec(
+        conf, _lower_node(node, conf, cache), rel, now)
+
+
+def _lower_node(node: LNode, conf: RapidsConf, cache=None) -> C.CpuExec:
     k = node.kind
     rx = lambda ex: _resolve_udfs(ex, conf)  # noqa: E731
-    if k == "filter" and node.children[0].kind == "file_scan":
+    if k == "filter" and node.children[0].kind == "file_scan" and not (
+            cache and cache.lookup(node.children[0])):
+        # (a cached scan holds every row group: nothing is pushed into it)
         # push col-vs-literal conjuncts into the scan for row-group pruning
         (cond,) = node.args
         cond = rx(cond)
@@ -166,7 +193,7 @@ def _lower(node: LNode, conf: RapidsConf) -> C.CpuExec:
             _extract_pushed_filters(cond) if fmt in ("parquet", "orc") else ())
         sc = _make_scanner(fmt, path, opts, conf, pushed)
         return C.CpuFilterExec(conf, cond, C.CpuFileScanExec(conf, sc, fmt))
-    kids = [_lower(c, conf) for c in node.children]
+    kids = [_lower(c, conf, cache) for c in node.children]
     if k == "file_scan":
         fmt, path, opts = node.args
         return C.CpuFileScanExec(
@@ -259,6 +286,8 @@ class TpuSession:
         self.last_executed_plan = None
         self.last_cpu_plan = None
         self.last_analysis = None
+        #: plans marked by DataFrame.cache() and what they keep resident
+        self.cache_manager = CacheManager()
         #: stable name in serving queues / event lanes ("session-N")
         self.serve_id = _next_session_id()
         # planning is session-state-mutating (last_* fields, the pending
@@ -311,10 +340,12 @@ class TpuSession:
             _donation.install_witness()
 
     def close(self) -> None:
-        """Flush/close the session's event sink (atexit also covers a
-        forgotten close) and detach it from the process-global emit
-        path. The obs plane is process-wide and stays up for other
-        sessions; stop it explicitly with obs.shutdown()."""
+        """Free every cached relation, flush/close the session's event
+        sink (atexit also covers a forgotten close) and detach it from
+        the process-global emit path. The obs plane is process-wide and
+        stays up for other sessions; stop it explicitly with
+        obs.shutdown()."""
+        self.cache_manager.clear()
         if _events._ACTIVE is self.events:
             _events.uninstall()
         self.events.close()
@@ -376,7 +407,7 @@ class TpuSession:
     def _plan(self, node: LNode, qid: int) -> C.CpuExec:
         from ..exec.base import compile_snapshot
 
-        cpu = _lower(node, self.conf)
+        cpu = _lower(node, self.conf, self.cache_manager)
         self.last_cpu_plan = cpu
         from ..conf import ANALYSIS_CROSS_CHECK, ANALYSIS_ENABLED, SQL_ENABLED
 
@@ -989,6 +1020,30 @@ class DataFrame:
             self.session, LNode("aggregate", (keys, ()), (self.node,))
         )
 
+    # -- caching -------------------------------------------------------------
+    def cache(self) -> "DataFrame":
+        """Mark this plan as cached (Spark's ``Dataset.cache()``) and
+        return the frame. Nothing runs now: the first action over the
+        plan — from this frame or any other of the session built over
+        the same plan and the same files — fills the relation on the
+        device (one shard on every device of a mesh) and every later one
+        is served from it until ``unpersist()``. A file rewritten in
+        between refills. ``explain()`` names the cached scan."""
+        self.session.cache_manager.mark(self.node)
+        return self
+
+    persist = cache
+
+    def unpersist(self) -> "DataFrame":
+        """Forget the mark and free what the relation held on the
+        device(s)."""
+        self.session.cache_manager.drop(self.node)
+        return self
+
+    @property
+    def is_cached(self) -> bool:
+        return self.session.cache_manager.lookup(self.node) is not None
+
     # -- actions -----------------------------------------------------------
     @property
     def schema(self) -> StructType:
@@ -1017,7 +1072,7 @@ class DataFrame:
         checked against the memory budget. Nothing is lowered or executed
         and no device allocation happens (see docs/tuning.md)."""
         conf = self.session.conf
-        cpu = _lower(self.node, conf)
+        cpu = _lower(self.node, conf, self.session.cache_manager)
         from ..plugin.overrides import PlanMeta
 
         meta = PlanMeta(cpu, conf)

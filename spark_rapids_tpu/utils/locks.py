@@ -56,6 +56,11 @@ LOCK_ORDER = (
     "serve.plan_cache",
     # obs plane install/teardown (registry gauge writes happen under it)
     "obs.plane",
+    # a cached relation's fill latch (DataFrame.cache(), sql/cache.py):
+    # held across the whole fill — the child plan's drain or the sharded
+    # scan's staging — and by every reader that waits for it; the fill
+    # compiles, stages and books bytes with the catalog, all below
+    "sql.cache",
     # per-exchange map-side one-shot latch, held across the whole map
     # run (compiles, retry plane, transport writes); stacked exchanges
     # nest child latches under the parent's — same-name nesting is the

@@ -171,22 +171,17 @@ def test_smoke_query_aggregate_compiles_for_v5e(
     assert mem.temp_size_in_bytes < HBM_BYTES // 4, mem.temp_size_in_bytes
 
 
-def test_mesh_aggregate_compiles_for_four_v5e_chips(
-        topo, no_persistent_cache, tmp_path):
-    """``chip_smoke.py --mesh 4``'s one program across chips:
-    ``TpuMeshAggregateExec``'s shard_map groupby with its all_to_all
-    exchange, compiled for four DESCRIBED chips. Without
-    ``parallel/mesh.mesh_jit_kwargs`` the compiler aborts the whole
-    process here (conditional-code-motion, see that docstring). The
-    program is captured from a run staged on the virtual CPU devices and
-    re-targeted at the described mesh; size does not matter to the
-    fault (65,536 rows abort like 28.8M do)."""
-    import chip_smoke
+def _compile_mesh_program_for_four_chips(topo, collect, patches=()):
+    """Run ``collect()`` on the virtual CPU devices with the engine's
+    ``shard_map`` spied: the SPMD program is captured at its dispatch (it
+    never runs), re-targeted at four DESCRIBED chips and compiled there.
+    Returns (the dispatch's argument shapes, the compiled program)."""
+    import contextlib
+
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from spark_rapids_tpu.exec import mesh as XM
     from spark_rapids_tpu.parallel.mesh import (
         AXIS, mesh_jit_kwargs, shard_map)
-    from spark_rapids_tpu.sql import TpuSession
 
     class Captured(Exception):
         pass
@@ -201,17 +196,14 @@ def test_mesh_aggregate_compiles_for_four_v5e_chips(
 
         return stop_at_dispatch
 
-    rows = chip_smoke.REHEARSE_ROWS
-    chip_smoke.make_data(str(tmp_path), rows, seed=19, row_group=rows // 4)
-    sess = TpuSession({
-        **chip_smoke.CONF,
-        "spark.rapids.tpu.shuffle.mode": "ici",
-        "spark.rapids.tpu.sql.reader.batchSizeBytes": 1,
-        "spark.rapids.tpu.mesh.devices": 4})
-    with mock.patch.object(XM, "shard_map", spy_shard_map), \
-            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+    with contextlib.ExitStack() as stack:
+        stack.enter_context(mock.patch.object(XM, "shard_map", spy_shard_map))
+        stack.enter_context(
+            mock.patch.object(jax, "default_backend", lambda: "tpu"))
+        for patch in patches:
+            stack.enter_context(patch)
         with pytest.raises(Captured):
-            chip_smoke.frame(sess, str(tmp_path)).collect()
+            collect()
         chips = Mesh(np.array(topo.devices[:4]), (AXIS,))
         rows_on_chips = NamedSharding(chips, P(AXIS))
         fn = jax.jit(
@@ -220,7 +212,105 @@ def test_mesh_aggregate_compiles_for_four_v5e_chips(
         compiled = fn.lower(*[
             jax.ShapeDtypeStruct(s, dt, sharding=rows_on_chips)
             for s, dt in cap["shapes"]]).compile()
+    return cap["shapes"], compiled
+
+
+def test_mesh_aggregate_compiles_for_four_v5e_chips(
+        topo, no_persistent_cache, tmp_path):
+    """``chip_smoke.py --mesh 4``'s one program across chips:
+    ``TpuMeshAggregateExec``'s shard_map groupby with its all_to_all
+    exchange, compiled for four DESCRIBED chips. Without
+    ``parallel/mesh.mesh_jit_kwargs`` the compiler aborts the whole
+    process here (conditional-code-motion, see that docstring). The
+    program is captured from a run staged on the virtual CPU devices and
+    re-targeted at the described mesh; size does not matter to the
+    fault (65,536 rows abort like 28.8M do)."""
+    import chip_smoke
+    from spark_rapids_tpu.sql import TpuSession
+
+    rows = chip_smoke.REHEARSE_ROWS
+    chip_smoke.make_data(str(tmp_path), rows, seed=19, row_group=rows // 4)
+    sess = TpuSession({
+        **chip_smoke.CONF,
+        "spark.rapids.tpu.shuffle.mode": "ici",
+        "spark.rapids.tpu.sql.reader.batchSizeBytes": 1,
+        "spark.rapids.tpu.mesh.devices": 4})
+    _, compiled = _compile_mesh_program_for_four_chips(
+        topo, lambda: chip_smoke.frame(sess, str(tmp_path)).collect())
     assert "all-to-all" in compiled.as_text()
+
+
+#: slots a shard of ``tpcds_sf100_store_sales_mesh4``: up to 73.4 M rows padded
+#: to a power of two
+SF100_SHARD_CAP = 1 << 27
+
+
+def test_mesh_aggregate_compiles_at_sf100_shard_capacity(
+        topo, no_persistent_cache, tmp_path):
+    """The same SPMD aggregate at the capacity the benchmark's four-chip
+    cell runs it: 2^27 slots a shard, the planes of a cached relation
+    (``store_sales_sf100.cached_report.mesh4``). 12.9 GB of planes are not
+    staged here: the stage is handed shapes where the cell hands resident
+    planes, the program is captured at its dispatch and re-targeted at four
+    described chips. What the compiler says of memory is what one program
+    needs beside its 3.2 GB of arguments a chip: it has to fit the chip,
+    which the one-piece update (``exec/mesh.AGG_UPDATE_CHUNK_ROWS`` at or
+    above the shard's slots) does not."""
+    import importlib.util
+    import json
+
+    from spark_rapids_tpu.exec import mesh as XM
+    from spark_rapids_tpu.sql import TpuSession
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    spec = importlib.util.spec_from_file_location(
+        "cached_report_query", os.path.join(
+            bench, "queries", "store_sales_cached_quantity_report.py"))
+    query = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(query)
+    with open(os.path.join(
+            bench, "configs", "tpcds_sf100_store_sales_mesh4.json")) as f:
+        config = json.load(f)
+
+    def shapes_for_planes(self, child):
+        """``_stage_child`` with nothing staged: the absorbed chain and
+        the planes' shapes at the cell's capacity."""
+        base, steps = self._absorb_chain(child)
+        n = self.n_shards
+        cols = []
+        for f in base.output_schema.fields:
+            cols.append(jax.ShapeDtypeStruct(
+                (n * SF100_SHARD_CAP,), f.dataType.to_numpy()))
+            cols.append(jax.ShapeDtypeStruct((n * SF100_SHARD_CAP,), bool))
+        fields = base.output_schema.fields
+        return XM.StagedChild(
+            cols, np.full(n, 72_000_000, np.int32), SF100_SHARD_CAP,
+            tuple(("f",) for _ in fields), tuple(0 for _ in fields), steps,
+            source="cached")
+
+    # a file of the deployment's schema, so that the plan is the cell's
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    pq.write_table(pa.table({
+        c["name"]: pa.array(np.ones(8, c["type"])) for c in config["columns"]
+    }), str(tmp_path / query.TABLE))
+    sess = TpuSession(config["conf"])
+    shapes, compiled = _compile_mesh_program_for_four_chips(
+        topo, lambda: query.frame(sess, str(tmp_path)).collect(),
+        patches=[mock.patch.object(XM._MeshStage, "_stage_child",
+                                   shapes_for_planes)])
+    sess.close()
+    assert shapes[0][0] == (4 * SF100_SHARD_CAP,)
+    assert "all-to-all" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the planes are the cached relation's, resident beside the program:
+    # 20 bytes of values a slot (and 4 validity bytes the compiler packs)
+    assert mem.argument_size_in_bytes >= SF100_SHARD_CAP * 20, mem
+    # updated in one piece the program asks 21 GB of temporaries a chip
+    # and is refused; in chunks of reshaped planes 6.8 GB; in chunks
+    # sliced from the resident planes 2.5 GB (PR 30's asks)
+    assert mem.temp_size_in_bytes < HBM_BYTES // 4, mem
 
 
 # ---------------------------------------------------------------------------

@@ -41,9 +41,11 @@ DIFF_MIN_BYTES = 1 << 20
 
 #: ledger record kinds that never count as device residency or leaks
 #: (must mirror memory/ledger.py: reservations are bookkeeping, not
-#: buffers; scan-cache entries outlive queries by design)
+#: buffers; scan-cache entries and cached relations outlive queries by
+#: design)
 NON_DEVICE_KINDS = ("reservation",)
-LEAK_EXEMPT_KINDS = ("reservation", "scan_cache", "plan_state")
+LEAK_EXEMPT_KINDS = ("reservation", "scan_cache", "plan_state",
+                     "cached_relation")
 
 
 # ---------------------------------------------------------------------------
