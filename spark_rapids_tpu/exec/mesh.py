@@ -636,12 +636,14 @@ class TpuMeshAggregateExec(_MeshStage):
                 ]
             return keys, vals, live
 
-        def chunked_partials(colflat, n, group_cap, pieces):
+        def chunked_partials(colflat, n, group_cap, pieces, reports):
             """The PARTIAL aggregate of a large shard, a chunk at a time:
             one loop over ``pieces`` slices of the planes, each slice's
             groups compacted and cut to ``group_cap`` rows. Returns the
             partial rows of all slices (keys, buffers, live mask) and
-            whether every slice's groups fitted."""
+            whether every slice's groups fitted; ``reports["update"]``
+            says how a slice's aggregate lowers."""
+            report: dict = {}
 
             def one(at):
                 # a slice of the resident planes, not a reshaped copy
@@ -654,16 +656,21 @@ class TpuMeshAggregateExec(_MeshStage):
                 with jax.named_scope("agg_update"):
                     pk, pa, pn = groupby_ops.groupby_agg(
                         keys, key_dtypes, vals, list(update_ops), live,
-                        ())
+                        (), report=report)
                 out = []
                 for c in list(pk) + list(pa):
                     out.extend([c.data[:group_cap],
                                 c.validity[:group_cap]])
+                # the slice's flag leaves the loop as a result of its own
                 return (tuple(out), jnp.minimum(pn, group_cap),
-                        pn <= group_cap)
+                        pn <= group_cap,
+                        report.pop("float_detour", jnp.bool_(False)))
 
-            planes, counts, fits = jax.lax.map(
+            planes, counts, fits, detours = jax.lax.map(
                 one, jnp.arange(pieces, dtype=jnp.int32))
+            if report:
+                reports["update"] = dict(
+                    report, float_detour=jnp.any(detours))
             rows = [ColV(planes[2 * i].reshape(-1),
                          planes[2 * i + 1].reshape(-1))
                     for i in range(len(planes) // 2)]
@@ -686,11 +693,12 @@ class TpuMeshAggregateExec(_MeshStage):
                     *colflat, cnt = flat
                     n = cnt[0]
                     fitted = None
+                    reports: dict = {}
                     if by_chunk:
                         # the chunks' partial rows cross as they are:
                         # dist_groupby's FINAL half merges them
                         keys, vals, live, fitted = chunked_partials(
-                            colflat, n, group_cap, pieces)
+                            colflat, n, group_cap, pieces, reports)
                     else:
                         cols = self._cols_of_flat(colflat, layout)
                         live = jnp.arange(cap, dtype=jnp.int32) < n
@@ -700,10 +708,22 @@ class TpuMeshAggregateExec(_MeshStage):
                         list(merge_ops), live, AXIS, n_shards,
                         str_max_lens=key_smls,
                         group_cap=0 if by_chunk else group_cap,
-                        partials=by_chunk)
+                        partials=by_chunk, reports=reports)
                     if fitted is not None:
                         ok = ok & (jax.lax.psum(
                             fitted.astype(jnp.int32), AXIS) == n_shards)
+                    # how the two halves' first hash tier lowers (a half
+                    # that bypasses the hash tiers reports nothing)
+                    halves = [r for r in reports.values() if r]
+                    detour = jnp.bool_(False)
+                    for r in halves:
+                        detour = detour | r["float_detour"]
+                    if halves:
+                        out_layouts["lowering"] = dict(
+                            float_sums_fixed=min(
+                                r["float_sums_fixed"] for r in halves),
+                            row_scatters=sum(
+                                r["row_scatters"] for r in halves))
                     # result projection over [keys..., buffers...] per shard
                     allv = list(rkeys) + list(raggs)
                     rcap = allv[0].validity.shape[0] if allv else 1
@@ -723,7 +743,9 @@ class TpuMeshAggregateExec(_MeshStage):
                     flat_out, out_lay = self._flatten_vals(outs)
                     out_layouts["lay"] = out_lay
                     flat_out.append(rn.reshape(1))
-                    flat_out.append(ok.reshape(1))
+                    # the shard's flags: its exchange fitted; a float
+                    # sum's detour ran (ops/bucket_reduce)
+                    flat_out.append(jnp.stack([ok, detour]))
                     return tuple(flat_out)
 
                 nin = len(global_cols)
@@ -747,13 +769,22 @@ class TpuMeshAggregateExec(_MeshStage):
             t0 = _time.perf_counter_ns()
             with self.section("spmd", exchange_bytes=xbytes,
                               exchange_cap=group_cap or cap,
-                              update_chunks=pieces):
+                              update_chunks=pieces) as span:
                 res = fn(*global_cols, cnt_in)
+                # known once the program is traced: how its aggregates
+                # lower (float_sums_fixed, row_scatters)
+                lowering = out_layouts.get("lowering", {})
+                span.set(**lowering)
+            self.mesh_actuals.update(lowering)
             self.metric("exchangeBytes", "bytes").add(xbytes)
-            *out_cols, out_counts, oks = res
+            *out_cols, out_counts, flags = res
             if group_cap:
-                with self.section("overflow_pull"):
-                    fits = bool(np.all(_np_of(oks)))
+                with self.section("overflow_pull") as span:
+                    oks, detours = _np_of(flags).reshape(n_shards, 2).T
+                    fits = bool(np.all(oks))
+                    detoured = int(np.sum(detours))
+                    span.set(float_detour=detoured)
+                self.mesh_actuals["float_detour"] = detoured
             if group_cap == 0 or fits:
                 self._record_run(list(out_cols) + [out_counts], t0)
                 self.mesh_actuals["exchange_cap"] = group_cap or cap

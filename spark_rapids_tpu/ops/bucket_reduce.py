@@ -28,7 +28,10 @@ bit-exact integer sums at matmul speed, including Java wraparound. The
 tiny; 16-bit limbs would force 256-row blocks and a gigabyte-scale
 transient. Counts are a ones-limb. Doubles use a hi/lo float split (not
 bit-exact, order-insensitive — the reference gates float aggregation the
-same way: spark.rapids.sql.variableFloatAgg.enabled).
+same way: spark.rapids.sql.variableFloatAgg.enabled). A float sum that
+must NOT be approximate rides the same matmul as signed fixed-point limbs
+(:func:`_fixed_point_limbs`): integer limb totals, so no scatter and no
+order dependence.
 
 Out-of-range segment ids (padding/dead rows) one-hot to a zero row and
 drop out of every reduction for free.
@@ -42,6 +45,20 @@ import jax.numpy as jnp
 
 BLOCK_R = 1 << 16  # rows per block: 65536 * 255 < 2^24 keeps f32 exact
 N_LIMBS = 8  # 8-bit limbs per int64
+
+#: the fixed-point float sum's window: bits of the grid below the top of
+#: the call's largest addend (``N_LIMBS`` sign x magnitude limbs)
+FIXED_WINDOW_BITS = 8 * N_LIMBS
+#: binary orders of magnitude below the call's largest addend that the
+#: window still holds to within 2^-40 of the addend's own size: an addend
+#: of f32 exponent ``e`` under the largest ``E`` is cut at 2^(E-e+1-64)
+#: of its size (times 1 + 2^-23), so ``E - e <= 22`` keeps the cut under
+#: 2^-40. Smaller addends (and every non-finite one) take the detour
+FIXED_SPAN = FIXED_WINDOW_BITS - 42
+#: the smallest f32 exponent (biased) whose addend the two f32 words hold
+#: to 2^-40 even where a backend flushes denormals: under 2^-86 the ``lo``
+#: word may be one, and the addend detours
+_LO_WORD_SAFE_EXP = 41
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -229,7 +246,9 @@ def bucket_reduce(
     count_cols: Sequence[jax.Array] = (),
     float_cols: Sequence[Tuple[jax.Array, jax.Array]] = (),
     strategy: str = None,
-) -> Tuple[List[jax.Array], List[jax.Array], List[jax.Array]]:
+    fixed_cols: Sequence[Tuple[jax.Array, jax.Array]] = (),
+) -> Tuple[List[jax.Array], List[jax.Array], List[jax.Array],
+           Tuple[List[jax.Array], jax.Array]]:
     """ALL requested reductions across ALL columns in one fused pass.
 
     Multi-column fusion is the point: every column's limbs stack into one
@@ -247,11 +266,18 @@ def bucket_reduce(
     float_cols: [(data f64/f32, valid bool)] -> f64 sums (B,) (hi/lo split)
     strategy:   MATMUL / SCATTER / SORT, or None for the backend default
                 (see :func:`_resolve_strategy`).
+    fixed_cols: [(data f64/f32, valid bool)] -> (f64 sums (B,), whether a
+                row took the detour): float sums that may not be
+                approximate, as fixed-point limbs of the same matmul
+                (:func:`_fixed_point_limbs`). The MATMUL lowering only:
+                the others have a native f64 sum and no use for it.
     """
     if FORCE_PER_COLUMN:
         out_int: List[jax.Array] = []
         out_cnt: List[jax.Array] = []
         out_flt: List[jax.Array] = []
+        out_fix: List[jax.Array] = []
+        detoured = jnp.bool_(False)
         for spec in int_cols:
             out_int += _bucket_reduce_pass(seg, B, [spec], (), (),
                                            strategy)[0]
@@ -261,9 +287,14 @@ def bucket_reduce(
         for spec in float_cols:
             out_flt += _bucket_reduce_pass(seg, B, (), (), [spec],
                                            strategy)[2]
-        return out_int, out_cnt, out_flt
+        for spec in fixed_cols:
+            sums, flag = _bucket_reduce_pass(seg, B, (), (), (), strategy,
+                                             [spec])[3]
+            out_fix += sums
+            detoured = detoured | flag
+        return out_int, out_cnt, out_flt, (out_fix, detoured)
     return _bucket_reduce_pass(seg, B, int_cols, count_cols, float_cols,
-                               strategy)
+                               strategy, fixed_cols)
 
 
 def _bucket_reduce_pass(
@@ -273,17 +304,25 @@ def _bucket_reduce_pass(
     count_cols: Sequence[jax.Array] = (),
     float_cols: Sequence[Tuple[jax.Array, jax.Array]] = (),
     strategy: str = None,
-) -> Tuple[List[jax.Array], List[jax.Array], List[jax.Array]]:
+    fixed_cols: Sequence[Tuple[jax.Array, jax.Array]] = (),
+) -> Tuple[List[jax.Array], List[jax.Array], List[jax.Array],
+           Tuple[List[jax.Array], jax.Array]]:
     resolved = _resolve_strategy(strategy)
-    if resolved == "SCATTER":
-        return _bucket_reduce_scatter(seg, B, int_cols, count_cols, float_cols)
-    if resolved == "SORT":
-        return _bucket_reduce_sort(seg, B, int_cols, count_cols, float_cols)
-    if resolved == "PALLAS":
-        from .pallas_groupby import pallas_bucket_reduce
+    if resolved != "MATMUL":
+        assert not fixed_cols, (
+            "fixed-point float sums are the MATMUL lowering's", resolved)
+        if resolved == "SCATTER":
+            out = _bucket_reduce_scatter(
+                seg, B, int_cols, count_cols, float_cols)
+        elif resolved == "SORT":
+            out = _bucket_reduce_sort(
+                seg, B, int_cols, count_cols, float_cols)
+        else:
+            from .pallas_groupby import pallas_bucket_reduce
 
-        return pallas_bucket_reduce(seg, B, int_cols, count_cols,
-                                    float_cols)
+            out = pallas_bucket_reduce(seg, B, int_cols, count_cols,
+                                       float_cols)
+        return (*out, ([], jnp.bool_(False)))
     n = seg.shape[0]
     limbs: List[jax.Array] = []
     for data, valid in int_cols:
@@ -314,8 +353,12 @@ def _bucket_reduce_pass(
         limbs.append(hi)
         limbs.append(lo)
         flt_corrections.append((jnp.any(ovf), jnp.where(ovf, d, 0.0)))
+    nx_start = len(limbs)
+    fixed = [_fixed_point_limbs(data, valid) for data, valid in fixed_cols]
+    for cut in fixed:
+        limbs.extend(cut[0])
     if not limbs:
-        return [], [], []
+        return [], [], [], ([], jnp.bool_(False))
     cols = jnp.stack(limbs, axis=-1)  # (n, L)
     L = cols.shape[1]
 
@@ -334,7 +377,8 @@ def _bucket_reduce_pass(
         S_parts.append(St[None])
     S = jnp.concatenate(S_parts, axis=0) if len(S_parts) > 1 else S_parts[0]
     acc_i = S[:, :nf_start, :].astype(jnp.int64).sum(axis=0)  # exact
-    acc_f = S[:, nf_start:, :].astype(jnp.float64).sum(axis=0)
+    acc_f = S[:, nf_start:nx_start, :].astype(jnp.float64).sum(axis=0)
+    acc_x = S[:, nx_start:, :].astype(jnp.int64).sum(axis=0)  # exact
 
     out_int: List[jax.Array] = []
     k = 0
@@ -358,7 +402,121 @@ def _bucket_reduce_pass(
         )
         out_flt.append(acc_f[k] + acc_f[k + 1] + corr)
         k += 2
-    return out_int, out_cnt, out_flt
+    out_fix: List[jax.Array] = []
+    detoured = jnp.bool_(False)
+    for j, (_, detour, top, d) in enumerate(fixed):
+        # highest limb first, each term exact: limb i of the grid weighs
+        # 2^(8i - 63) in units of 2^(top - 127), the largest addend's own
+        # power of two (a normal f32, so inside what the chip's f64 holds)
+        total = jnp.zeros(B, jnp.float64)
+        for i in reversed(range(N_LIMBS)):
+            total = total + (acc_x[N_LIMBS * j + i].astype(jnp.float64)
+                             * (2.0 ** (8 * i - (FIXED_WINDOW_BITS - 1))))
+        unit = jax.lax.bitcast_convert_type(
+            top.astype(jnp.uint32) << 23, jnp.float32).astype(jnp.float64)
+        any_detour = jnp.any(detour)
+        corr = jax.lax.cond(
+            any_detour,
+            lambda d=d, detour=detour: jax.ops.segment_sum(
+                jnp.where(detour, d, 0.0), seg, num_segments=B),
+            lambda: jnp.zeros(B, jnp.float64),
+        )
+        out_fix.append(total * unit + corr)
+        detoured = detoured | any_detour
+    return out_int, out_cnt, out_flt, (out_fix, detoured)
+
+
+def _place(m: jax.Array, sh: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The (high, low) u32 words of ``m * 2**sh`` cut to 64 bits, for a
+    mantissa ``m < 2**24`` and a shift ``sh <= 40`` (int32, any sign):
+    bits that fall below bit 0 are dropped — the cut to the grid."""
+    zero = jnp.uint32(0)
+
+    def by(x):  # a shift count in range; the cases out of it select zero
+        return jnp.clip(x, 0, 31).astype(jnp.uint32)
+
+    low = jnp.where(sh >= 32, zero,
+                    jnp.where(sh >= 0, m << by(sh), m >> by(-sh)))
+    high = jnp.where(sh >= 32, m << by(sh - 32),
+                     jnp.where(sh > 8, m >> by(32 - sh), zero))
+    return high, low
+
+
+def _fixed_point_limbs(
+    data: jax.Array, valid: jax.Array
+) -> Tuple[List[jax.Array], jax.Array, jax.Array, jax.Array]:
+    """One float column as ``N_LIMBS`` signed 8-bit limbs of a fixed-point
+    grid, for the limb matmul: a float sum from exact integer totals.
+
+    One pass finds ``top``, the largest f32 exponent among the finite
+    addends of the call. The grid is ``FIXED_WINDOW_BITS`` wide under the
+    top of that binade; every addend is cut to it (toward zero) and split
+    sign x magnitude into 8-bit limbs, so |limb| <= 255, a block of
+    ``BLOCK_R`` rows sums below 2^24 and the f32 one-hot matmul is exact,
+    as for the int limbs. Block totals accumulate in int64; the caller
+    rebuilds the float from the limb totals, highest first. The only
+    roundings are each addend's own cut and that last recombination:
+    an addend within ``FIXED_SPAN`` binades of the largest is cut by less
+    than 2^-40 of its own size, and nothing at all when the call's range
+    of magnitudes times an addend's precision fits the window (two-decimal
+    prices of 1 to 100 need 55 bits of the 64). Hence **the sum does not
+    depend on the order of the rows** (integer totals), nor — where no
+    addend is cut — on how the rows are split into calls, chunks or
+    shards, up to the recombination's last-place rounding. A scatter-add
+    has neither property.
+
+    The addend is taken as the two f32 words the chip holds an f64 in,
+    ``hi = f32(x)`` and ``lo = f32(x - hi)`` (XLA folds the round trip
+    there; on a backend with a native f64 this rounds x to 48 bits), and
+    everything from the bitcast on is 32-bit integer work: exponent,
+    mantissa, shift, borrow. Rows the window cannot serve **detour**, as
+    |x| > F32_MAX does in the hi/lo lowering: a non-finite value (NaN,
+    an infinity, or beyond f32's range), a nonzero addend more than
+    ``FIXED_SPAN`` binades under the largest or under 2^-86 (where the
+    ``lo`` word may be a denormal), and a (hi, lo) pair that is not
+    normalised (|lo| >= ulp(hi): the limbs' bits would overlap).
+    Their limbs are zero and the caller adds them by a ``segment_sum``
+    under a ``lax.cond`` on "any such row".
+
+    Returns (limbs, detour mask, ``top``, the masked f64 addends).
+    """
+    u = jnp.uint32
+    d = jnp.where(valid, data, 0.0).astype(jnp.float64)
+    hi = d.astype(jnp.float32)
+    lo = (d - hi.astype(jnp.float64)).astype(jnp.float32)
+    bh = jax.lax.bitcast_convert_type(hi, u)
+    bl = jax.lax.bitcast_convert_type(lo, u)
+    eh = ((bh >> 23) & u(0xFF)).astype(jnp.int32)
+    el = ((bl >> 23) & u(0xFF)).astype(jnp.int32)
+    nonfinite = eh == 0xFF
+    top = jnp.maximum(jnp.max(jnp.where(nonfinite, 0, eh)), 1)
+    # a subnormal has exponent 1 and no implicit bit
+    ehe, ele = jnp.maximum(eh, 1), jnp.maximum(el, 1)
+    lo_set = (bl & u(0x7FFFFFFF)) != 0
+    detour = (nonfinite
+              | ((eh < jnp.maximum(top - FIXED_SPAN, _LO_WORD_SAFE_EXP))
+                 & (d != 0.0))
+              | (lo_set & (ele > ehe - 24)))
+
+    def mantissa(bits, e):
+        m = (bits & u(0x7FFFFF)) | jnp.where(e > 0, u(1 << 23), u(0))
+        return jnp.where(detour, u(0), m)
+
+    at_top = FIXED_WINDOW_BITS - 24  # the shift of an addend of exponent top
+    h1, h0 = _place(mantissa(bh, eh), ehe - top + at_top)
+    l1, l0 = _place(mantissa(bl, el), ele - top + at_top)
+    # |lo| < ulp(hi) <= |hi|: on the grid the two share no bit, so their
+    # sum is an OR and their difference borrows at most across the words
+    same = (bh >> 31) == (bl >> 31)
+    w0 = jnp.where(same, h0 | l0, h0 - l0)
+    w1 = jnp.where(same, h1 | l1, h1 - l1 - (h0 < l0).astype(u))
+    negative = (bh >> 31) == 1
+    limbs: List[jax.Array] = []
+    for w in (w0, w1):
+        for i in range(4):
+            mag = ((w >> (8 * i)) & u(0xFF)).astype(jnp.float32)
+            limbs.append(jnp.where(negative, -mag, mag))
+    return limbs, detour, top, d
 
 
 def bucket_min_max(

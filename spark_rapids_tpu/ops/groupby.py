@@ -641,6 +641,7 @@ def hash_groupby(
     num_buckets: int,
     approx_float_sum: bool = False,
     reduce_strategy: Optional[str] = None,
+    report: Optional[dict] = None,
 ) -> Tuple[List[ColV], List[ColV], jax.Array, jax.Array]:
     """O(n) groupby: bucket keys, reduce on the MXU.
 
@@ -655,14 +656,26 @@ def hash_groupby(
          :func:`groupby_agg` fall back to the sort path.
 
     Sums/counts run as one-hot limb matmuls (ops/bucket_reduce.py — exact
-    for integers); min/max/first/last use scatter segment ops; float sums
-    use one scatter op unless ``approx_float_sum`` (order-insensitive
-    matmul, the reference's variableFloatAgg tradeoff).
+    for integers); min/max/first/last use scatter segment ops. A float
+    sum under ``approx_float_sum`` rides the matmul as hi/lo f32 limbs
+    (order-insensitive, the reference's variableFloatAgg tradeoff); one
+    that may not be approximate rides it as exact fixed-point limbs where
+    the reduction's resolved lowering is MATMUL
+    (``bucket_reduce._fixed_point_limbs``: no row-sized scatter, and no
+    dependence on row order or on the split into chunks and shards), and
+    is one scatter op under the other lowerings.
+
+    ``report``, when given, is filled at trace time with how this call's
+    plan lowers (exec/mesh reads it into its span counts): ``lowering``,
+    a word an aggregate; ``float_sums_fixed``; ``row_scatters``, the
+    row-sized ``segment_*`` operations outside any ``lax.cond``; and
+    ``float_detour``, the traced flag "a fixed-point sum's detour ran".
 
     Returns (out_keys, out_aggs, num_groups, collision_free); outputs are
     bucket-compacted to the front at the input capacity.
     """
-    from .bucket_reduce import bucket_equal_check, bucket_reduce
+    from .bucket_reduce import (
+        _resolve_strategy, bucket_equal_check, bucket_reduce)
     from .filter_gather import live_of
     from .hashing import murmur3
     from .sort import SortOrder, fixed_radix_keys
@@ -721,7 +734,8 @@ def hash_groupby(
     # --- reductions (all sums/counts of EVERY column in ONE matmul pass;
     # min/max batched into one scatter family per (op, dtype), their
     # nullability counts riding the same matmul) -------------------------
-    int_specs, cnt_specs, flt_specs = [], [], []
+    int_specs, cnt_specs, flt_specs, fix_specs = [], [], [], []
+    resolved = _resolve_strategy(reduce_strategy)
     plan = []  # per agg: (path, payload)
     cnt_index: dict = {}
     mm_fam: dict = {}  # (op, dtype) -> [filled (n,) columns]
@@ -751,6 +765,12 @@ def hash_groupby(
             ci = _want_count(v.validity & live, ("c", ai))
             flt_specs.append((v.data, v.validity & live))
             plan.append(("fsum", (len(flt_specs) - 1, ci, v.data.dtype)))
+        elif op == "sum" and resolved == "MATMUL":
+            # exact float sum beside the int limbs: fixed-point limbs
+            ci = _want_count(v.validity & live, ("c", ai))
+            fix_specs.append((v.data, v.validity & live))
+            plan.append(("fsum_fixed", (len(fix_specs) - 1, ci,
+                                        v.data.dtype)))
         elif op == "sum":
             # exact float sum: one scatter op; nullability via matmul count
             ci = _want_count(v.validity & live, ("c", ai))
@@ -792,9 +812,21 @@ def hash_groupby(
 
     from .bucket_reduce import bucket_min_max
 
-    isums, counts, fsums = bucket_reduce(
+    isums, counts, fsums, (xsums, float_detour) = bucket_reduce(
         seg, B, int_specs, cnt_specs, flt_specs,
-        strategy=reduce_strategy)
+        strategy=reduce_strategy, fixed_cols=fix_specs)
+    if report is not None:
+        kinds = [kind for kind, _ in plan]
+        report["lowering"] = tuple(kinds)
+        report["float_sums_fixed"] = len(fix_specs)
+        report["float_detour"] = float_detour
+        # what walks every row outside a cond: the SCATTER lowering's two
+        # families, a scatter float sum, a min/max family, a first/last
+        report["row_scatters"] = (
+            (bool(int_specs) + bool(cnt_specs or flt_specs))
+            * (resolved == "SCATTER")
+            + kinds.count("fsum_exact") + kinds.count("scatter")
+            + len(mm_fam) * (resolved != "PALLAS"))
     mm_results = {
         k: bucket_min_max(seg, B, k[0], cols_, strategy=reduce_strategy)
         for k, cols_ in mm_fam.items()
@@ -904,6 +936,9 @@ def hash_groupby(
         elif kind == "fsum":
             si, ci, dt = payload
             out_aggs.append(to_slots(fsums[si].astype(dt), counts[ci] > 0))
+        elif kind == "fsum_fixed":
+            si, ci, dt = payload
+            out_aggs.append(to_slots(xsums[si].astype(dt), counts[ci] > 0))
         elif kind == "fsum_exact":
             sv, ci = payload
             z = jnp.zeros((), sv.data.dtype)
@@ -950,6 +985,7 @@ def groupby_agg(
     num_buckets: int = 8192,
     str_val_max_lens: Sequence[int] = (),
     strategy: Optional[str] = None,
+    report: Optional[dict] = None,
 ) -> Tuple[List[Val], List[Val], jax.Array]:
     """Adaptive groupby: MXU hash-bucket fast path with a traced sort
     fallback.
@@ -972,6 +1008,11 @@ def groupby_agg(
     trick) and rewrap the output codes, so the group keys stay encoded.
     Non-unique dictionaries (post-transform, where distinct codes may
     hold equal strings) materialize and sort like plain strings.
+
+    ``report``: filled by the FIRST hash tier (the one outside every
+    ``lax.cond``, which a low-cardinality aggregate takes) with how its
+    plan lowers, see :func:`hash_groupby`; left empty where the keys
+    bypass the hash tiers.
     """
     key_cols = list(key_cols)
     key_dtypes = list(key_dtypes)
@@ -1059,12 +1100,12 @@ def groupby_agg(
             key_cols, key_dtypes, value_cols, agg_ops, num_rows,
             str_max_lens, radix_reduce=radix))
 
-    def tier(B, below):
+    def tier(B, below, report=None):
         def run(_):
             hk, ha, hn, ok = hash_groupby(
                 list(key_cols), key_dtypes, value_cols, agg_ops, num_rows,
                 B, approx_float_sum=approx_float_sum,
-                reduce_strategy=strategy)
+                reduce_strategy=strategy, report=report)
 
             def use_hash(_):
                 return pack(hk, ha, hn)
@@ -1078,7 +1119,7 @@ def groupby_agg(
         chain = tier(B2, chain)
     if B1 > B0:
         chain = tier(B1, chain)
-    keys_t, aggs_t, n = tier(B0, chain)(None)
+    keys_t, aggs_t, n = tier(B0, chain, report)(None)
     out_keys = [ColV(d, v) for d, v in keys_t]
     out_aggs = [ColV(d, v) for d, v in aggs_t]
     return _rewrap(out_keys, out_aggs, n)

@@ -45,6 +45,7 @@ def dist_groupby(
     str_max_lens: Sequence[int] = (),
     group_cap: int = 0,
     partials: bool = False,
+    reports: Optional[dict] = None,
 ) -> Tuple[List[ColV], List[ColV], jax.Array, jax.Array]:
     """PARTIAL local aggregate -> key-hash all_to_all -> FINAL merge.
 
@@ -70,6 +71,10 @@ def dist_groupby(
     of an update the caller ran piece by piece, exec/mesh's chunked
     update); the PARTIAL half is skipped and they cross as they are.
 
+    ``reports``: filled with how each half's aggregate lowers,
+    ``reports["update"]`` (unless ``partials``) and ``reports["merge"]``,
+    each as ``ops/groupby.hash_groupby`` describes its ``report``.
+
     Returns (keys, aggs, count, ok) — ``ok`` is globally reduced.
     """
     # PARTIAL: local groupby shrinks rows before they cross the wire.
@@ -82,7 +87,7 @@ def dist_groupby(
         with jax.named_scope("agg_update"):
             pkeys, paggs, pn = groupby_ops.groupby_agg(
                 key_cols, key_dtypes, value_cols, list(update_ops),
-                num_rows, str_max_lens)
+                num_rows, str_max_lens, report=_half(reports, "update"))
 
     all_cols = list(pkeys) + list(paggs)
     cap = all_cols[0].validity.shape[0] if all_cols else 0
@@ -116,8 +121,12 @@ def dist_groupby(
     with jax.named_scope("agg_merge"):
         fkeys, faggs, fn_ = groupby_ops.groupby_agg(
             rkeys, key_dtypes, list(raggs), list(merge_ops), rn,
-            str_max_lens)
+            str_max_lens, report=_half(reports, "merge"))
     return fkeys, faggs, fn_, ok
+
+
+def _half(reports: Optional[dict], name: str) -> Optional[dict]:
+    return None if reports is None else reports.setdefault(name, {})
 
 
 def _sample_bounds(

@@ -574,3 +574,111 @@ def test_a_chunk_with_more_groups_than_the_cap_retries(
     agg = sess.last_executed_plan.tpu_child
     assert agg.mesh_actuals["programs"] > 1
     _close(sess)
+
+
+# ---------------------------------------------------------------------------
+# the report's float sum as fixed-point limbs (PR 31): the chip's lowering,
+# forced here, and the counts that say which lowering a program took
+# ---------------------------------------------------------------------------
+def _one_traced_query(query, directory, out):
+    """The cell's query once, cached, on the 4-device mesh under the CPU
+    profiler: (rows, the mesh aggregate, its spans by name)."""
+    from jax.profiler import ProfileData
+
+    sess = TpuSession(dict(MESH4, **SMALL_CAP,
+                           **{P + "sql.trace.enabled": True}))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(out, profiler_options=options)
+    try:
+        rows = query.frame(sess, directory).collect()
+    finally:
+        jax.profiler.stop_trace()
+    agg = sess.last_executed_plan.tpu_child
+    _close(sess)
+    path = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("TpuMeshAggregateExec."):
+                        spans.setdefault(e.name, []).append(dict(e.stats))
+    return rows, agg, spans
+
+
+@pytest.mark.parametrize("lowering", ["matmul", "scatter"])
+def test_the_mesh_report_says_how_its_float_sum_lowers(
+        table, small_chunks, lowering, tmp_path, monkeypatch):
+    """Eight chunks a shard and the merge of their partials, under the
+    chip's MATMUL lowering (forced) and the CPU backend's own: the same
+    report, and on the ``.spmd`` / ``.overflow_pull`` spans the counts."""
+    from spark_rapids_tpu.ops import bucket_reduce as BR
+
+    directory, _, query, want = table
+    monkeypatch.setattr(BR, "FORCE_MATMUL", lowering == "matmul")
+    XB.clear_pipeline_caches()  # a program is cached by plan, not lowering
+    try:
+        rows, agg, spans = _one_traced_query(query, directory, str(tmp_path))
+    finally:
+        XB.clear_pipeline_caches()
+    # the forced lowering sums the float column to the last places too
+    _held(rows, want, query, limit=1e-12)
+    assert agg.mesh_actuals["update_chunks"] == 8
+    (spmd,) = spans["TpuMeshAggregateExec.spmd"]
+    (pull,) = spans["TpuMeshAggregateExec.overflow_pull"]
+    if lowering == "matmul":
+        assert spmd["float_sums_fixed"] == 1 and spmd["row_scatters"] == 0
+    else:
+        # each half: the ints', the counts' and the float sum's scatter
+        assert spmd["float_sums_fixed"] == 0 and spmd["row_scatters"] == 6
+    assert pull["float_detour"] == 0
+    assert agg.mesh_actuals["float_sums_fixed"] == spmd["float_sums_fixed"]
+    assert agg.mesh_actuals["row_scatters"] == spmd["row_scatters"]
+    assert agg.mesh_actuals["float_detour"] == 0
+
+
+def test_a_detour_on_a_shard_is_counted(table, small_chunks, monkeypatch):
+    """An infinite price in one row group: its shard's detour runs, its
+    group's sum is infinite and every other group's is the oracle's."""
+    import shutil
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from spark_rapids_tpu.ops import bucket_reduce as BR
+
+    directory, path, query, want = table
+    t = pq.read_table(path)
+    prices = t.column("ss_wholesale_cost").to_numpy().copy()
+    dates = t.column("ss_sold_date_sk").to_numpy()
+    row = int(np.flatnonzero(dates >= query.DATE_CUT)[0])
+    prices[row] = np.inf
+    quantity = int(t.column("ss_quantity")[row].as_py())
+    spoiled = os.path.join(os.path.dirname(directory), "spoiled")
+    os.makedirs(spoiled, exist_ok=True)
+    pq.write_table(
+        t.set_column(t.schema.get_field_index("ss_wholesale_cost"),
+                     "ss_wholesale_cost", pa.array(prices)),
+        os.path.join(spoiled, os.path.basename(path)),
+        row_group_size=pq.ParquetFile(path).metadata.row_group(0).num_rows)
+    monkeypatch.setattr(BR, "FORCE_MATMUL", True)
+    XB.clear_pipeline_caches()
+    try:
+        sess = TpuSession(dict(MESH4, **SMALL_CAP))
+        got = sorted(query.frame(sess, spoiled).collect())
+        agg = sess.last_executed_plan.tpu_child
+        assert agg.mesh_actuals["float_detour"] >= 1
+        assert agg.mesh_actuals["row_scatters"] == 0
+        _close(sess)
+    finally:
+        XB.clear_pipeline_caches()
+        shutil.rmtree(spoiled)
+    for g, w in zip(got, sorted(want)):
+        assert (g[0], g[2], g[3]) == (w[0], w[2], w[3])
+        if g[0] == quantity:
+            assert g[1] == np.inf
+        else:
+            assert abs(g[1] - w[1]) <= 1e-12 * abs(w[1])

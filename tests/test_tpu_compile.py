@@ -311,6 +311,64 @@ def test_mesh_aggregate_compiles_at_sf100_shard_capacity(
     # and is refused; in chunks of reshaped planes 6.8 GB; in chunks
     # sliced from the resident planes 2.5 GB (PR 30's asks)
     assert mem.temp_size_in_bytes < HBM_BYTES // 4, mem
+    # the float sum rides the limb matmul as fixed-point limbs (PR 31): of
+    # the aggregate's two halves no scatter that walks a chunk's slots, or
+    # the merge's 262,144 received partial rows, is left on the taken
+    # path; those that remain sit in a branch of a conditional (the float
+    # detour, the hash and sort tiers). The exchange places its rows by
+    # scatter under its own scope word: not the aggregate's
+    walks = [w for w in _row_sized_scatters(compiled.as_text(), 1 << 16)
+             if "/agg_update/" in w[1] or "/agg_merge/" in w[1]]
+    assert {n for n, _ in walks} == {XM.AGG_UPDATE_CHUNK_ROWS, 4 << 16}, walks
+    assert [w for w in walks if "/cond/branch_" not in w[1]] == [], walks
+
+
+def _row_sized_scatters(text, rows):
+    """(indices' elements, op_name) of every ``scatter`` instruction of a
+    compiled program's text whose indices operand has ``rows`` elements or
+    more. A scatter of N arrays has 2N+1 operands: the indices are the
+    middle one."""
+    import math
+    import re
+
+    shape_of = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.-]+) = \(?\w+\[([\d,]*)\]", text, re.M))
+    found = []
+    for line in text.splitlines():
+        m = re.search(r" scatter\(([^)]*)\)", line)
+        if not m:
+            continue
+        operands = [o.strip().split(" ")[-1] for o in m.group(1).split(",")]
+        dims = shape_of[operands[len(operands) // 2]]
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if n >= rows:
+            found.append((n, name.group(1) if name else ""))
+    return found
+
+
+def test_row_sized_scatters_reads_a_programs_text():
+    text = """
+%fused.1 (param_0.1: f32[128], param_1.1: s32[4096,1], p2: f32[4096]) -> f32[128] {
+  %param_0.1 = f32[128]{0:T(128)} parameter(0)
+  %param_1.1 = s32[4096,1]{1,0} parameter(1)
+  %p2 = f32[4096]{0} parameter(2)
+  ROOT %scatter.1 = f32[128]{0} scatter(%param_0.1, %param_1.1, %p2), to_apply=%add, metadata={op_name="jit(f)/agg_update/cond/branch_1_fun/scatter-add"}
+}
+%fused.2 {
+  %a = f32[8]{0} parameter(0)
+  %b = f32[8]{0} parameter(1)
+  %i = s32[64]{0} parameter(2)
+  %u = f32[64]{0} parameter(3)
+  %v = f32[64]{0} parameter(4)
+  ROOT %scatter.2 = (f32[8]{0}, f32[8]{0}) scatter(%a, %b, %i, %u, %v), to_apply=%add2, metadata={op_name="jit(f)/agg_merge/scatter-add"}
+}
+"""
+    assert _row_sized_scatters(text, 1) == [
+        (4096, "jit(f)/agg_update/cond/branch_1_fun/scatter-add"),
+        (64, "jit(f)/agg_merge/scatter-add")]
+    assert _row_sized_scatters(text, 100) == [
+        (4096, "jit(f)/agg_update/cond/branch_1_fun/scatter-add")]
 
 
 # ---------------------------------------------------------------------------
