@@ -1,11 +1,10 @@
 """Environment provenance: which hardware produced these numbers.
 
-Every BENCH round since PR 1 has carried a prose caveat ("CPU fallback,
-not comparable to r05") because nothing machine-readable recorded WHAT
-backend a run measured. This helper is the one home for
-that record: bench.py stamps it into every ``BENCH_*.json`` /
-``MULTICHIP_*.json`` top level, the session rides it on ``query_start``
-events, ``/status`` serves it live, and ``tpu_profile --diff`` warns
+Recorded results used to carry a prose caveat ("CPU fallback, not
+comparable") because nothing machine-readable recorded WHAT backend a
+run measured. This helper is the one home for that record: the session
+rides it on ``query_start`` events, ``/status`` serves it live, and
+``tpu_profile --diff`` warns
 loudly when two runs' backends or device kinds differ — numbers from
 different hardware compare structure, not speed.
 

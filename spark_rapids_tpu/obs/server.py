@@ -57,8 +57,8 @@ def build_status(registry: MetricsRegistry, progress: ProgressTracker,
     # stats() is None in a process that never installed one
     from ..serve import program_cache
 
-    # environment provenance (envinfo — the same helper bench.py stamps
-    # into BENCH_*.json): a live operator must be able to tell at a
+    # environment provenance (envinfo — the block every query_start
+    # event carries): a live operator must be able to tell at a
     # glance whether the numbers on screen are device-backed or the CPU
     # fallback's
     from .. import envinfo

@@ -798,8 +798,8 @@ class _Analyzer:
         notes.append(
             "unpack layout bound: uploaded payloads "
             f"<= {_pretty_bytes(fp['upload_total'])} + decoded planes "
-            f"{_pretty_bytes(decoded)} — the denominator of the parquet "
-            "shape's byte_amplification (bench.py)")
+            f"{_pretty_bytes(decoded)} — the denominator of a parquet "
+            "scan's byte amplification")
 
     def _range(self, node: C.CpuRangeExec) -> _Result:
         schema = node.output_schema
@@ -1532,14 +1532,12 @@ def parquet_scan_footprint(scanner, schema: StructType) -> Optional[dict]:
 
 def predict_exec_hbm(exec_) -> Optional[int]:
     """Forecast the HBM bytes a LIVE TpuExec tree will touch: resident
-    source batches plus each operator's output-layout bound. Used by
-    bench.py to emit predicted_hbm_bytes next to the measured roofline
-    (BENCH tracks forecast accuracy across rounds).
+    source batches plus each operator's output-layout bound, the
+    figure a measured roofline is held against.
 
     Parquet file scans bound through :func:`parquet_scan_footprint`
     (uploaded payloads + decoded planes — the unpack site's layout
-    bound), so the parquet shape's byte_amplification is no longer null
-    and the --diff amplification-growth gate actually binds there."""
+    bound), so a parquet scan's byte amplification has a denominator."""
     from ..exec.base import TpuExec, batch_bytes
     from ..exec.scan import TpuFileSourceScanExec
 

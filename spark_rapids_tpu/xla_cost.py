@@ -24,9 +24,9 @@ Zero-overhead contract (the events.py/obs pattern): with event logging
 AND the live obs plane off — and :data:`FORCE_HARVEST` unset — wrapping
 is skipped entirely at miss time and ``cost_analysis`` is never called
 (tests/test_program_cost.py pins this with a spy). ``FORCE_HARVEST`` is
-the bench/harness opt-in: harvesting without any event sink still
-records into the in-process table below, which bench.py reads to emit
-``hbm_frac_xla`` per shape.
+the test harness's opt-in: harvesting without any event sink still
+records into the in-process table below (``tests/harness.py`` reads it
+for the analysis cross-check).
 
 Graceful degradation: the CPU fallback backend reports different (or
 missing) cost keys than a real TPU — every harvested field is therefore
@@ -68,8 +68,8 @@ ROOFLINE_PEAK_TFLOPS = conf(
     check=lambda v: None if v >= 0 else "must be >= 0")
 
 #: per-backend (peak HBM GB/s, peak TFLOP/s) defaults when the roofline
-#: confs are 0.0 — the TPU row is the v5e public spec (bench.py's
-#: HBM_GBPS constant is the same 819), the CPU row a nominal DDR-class
+#: confs are 0.0 — the TPU row is the v5e public spec (the 819 of
+#: ``benchmarks/peaks.json``), the CPU row a nominal DDR-class
 #: figure so the fallback backend still classifies limiters
 BACKEND_PEAKS: Dict[str, Tuple[float, float]] = {
     "tpu": (819.0, 197.0),

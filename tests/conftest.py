@@ -10,6 +10,7 @@ backend so the differential suites validate Spark-exactness ON the chip
 against the CPU backend. Usage:
     SRTPU_TEST_TPU=1 python -m pytest tests/ -q -m "not cpu_only"
 """
+import collections
 import os
 import sys
 
@@ -38,8 +39,8 @@ if not ON_TPU:
 # wall time (SURVEY §4 test-strategy analog of the reference's reuse of
 # warmed Spark sessions across its pytest modules). The directory is
 # JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_compile_cache —
-# envinfo.use_compile_cache is the one helper that decides (chip_smoke.py
-# uses it too).
+# envinfo.use_compile_cache is the one helper that decides
+# (benchmarks/run.py uses it too).
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from spark_rapids_tpu.envinfo import use_compile_cache  # noqa: E402
@@ -49,7 +50,21 @@ jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 
 
+#: Under ``--dist loadfile`` a file is one worker's, so the run cannot end
+#: before its longest file does. The compile asks are the longest by far
+#: (minutes each, one compile that no cache can hold) and have the fewest
+#: tests, so they are collected, and so handed out, first.
+_COLLECT_FIRST = (
+    "test_tpu_compile_sf100.py", "test_tpu_compile.py",
+    "test_tpu_compile_mesh4.py",
+)
+
+
 def pytest_configure(config):
+    # xdist hands files out by their number of tests, most first, unless
+    # told not to: a compile ask is one test and would start last. The
+    # order is made in pytest_collection_modifyitems instead.
+    config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers", "cpu_only: needs the multi-device virtual CPU mesh; "
         "skipped when SRTPU_TEST_TPU=1 runs the suite on the real chip")
@@ -84,6 +99,12 @@ def _hbm_leak_guard():
 
 
 def pytest_collection_modifyitems(config, items):
+    # _COLLECT_FIRST, then xdist's own order: the files with most tests
+    # first. Deterministic: every xdist worker must collect the same order.
+    rank = {name: i for i, name in enumerate(_COLLECT_FIRST)}
+    tests_in = collections.Counter(item.path for item in items)
+    items.sort(key=lambda item: (rank.get(item.path.name, len(rank)),
+                                 -tests_in[item.path]))
     if not ON_TPU:
         return
     skip = pytest.mark.skip(reason="needs 8-device CPU mesh (on-TPU run)")

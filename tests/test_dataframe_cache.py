@@ -558,12 +558,16 @@ def test_a_shard_larger_than_the_chunk_is_updated_in_chunks(
 def test_a_chunk_with_more_groups_than_the_cap_retries(
         table, small_chunks):
     """``group by ss_item_sk``: some 4,000 groups a chunk of 4096 rows
-    against a cap of 128; the stage doubles the cap until every chunk
-    fits (at the chunk's size the update is in one piece again)."""
+    against a cap of 2048; the stage doubles the cap until every chunk
+    fits (at the chunk's size the update is in one piece again) and every
+    shard's some 19,500 groups do. Every doubling compiles the SPMD
+    program anew, so the cap starts one doubling under the chunk, not the
+    five of ``SMALL_CAP``: one chunked program, then four in one piece."""
     import pyarrow.parquet as pq
 
     directory, path, _, _ = table
-    sess = TpuSession(dict(MESH4, **SMALL_CAP))
+    sess = TpuSession(dict(
+        MESH4, **{P + "shuffle.mesh.aggExchangeCapacity": 2048}))
     got = (sess.read.parquet(directory).cache().group_by("ss_item_sk")
            .agg(A.agg(A.Count(col("ss_quantity")), "c"),
                 A.agg(A.Sum(col("ss_quantity")), "q")).collect())
@@ -572,7 +576,7 @@ def test_a_chunk_with_more_groups_than_the_cap_retries(
     assert sorted(got) == sorted(
         (int(k), int(r["count"]), int(r["sum"])) for k, r in g.iterrows())
     agg = sess.last_executed_plan.tpu_child
-    assert agg.mesh_actuals["programs"] > 1
+    assert agg.mesh_actuals["programs"] == 5  # 2048 ... 32768, the shard
     _close(sess)
 
 

@@ -291,24 +291,6 @@ def test_diff_flags_event_log_regression(tmp_path):
     assert n >= 1 and "REGRESSION" in text and "TpuProjectExec" in text
 
 
-def test_diff_bench_jsons(tmp_path):
-    old = {"per_shape": {"agg": {"tpu_ms": 100.0, "device_ms": 50.0},
-                         "sort": {"tpu_ms": 10.0, "device_ms": None}}}
-    new = {"per_shape": {"agg": {"tpu_ms": 250.0, "device_ms": 51.0},
-                         "sort": {"tpu_ms": 10.5, "device_ms": None}}}
-    pa = str(tmp_path / "BENCH_a.json")
-    pb = str(tmp_path / "BENCH_b.json")
-    for p, d in ((pa, old), (pb, new)):
-        with open(p, "w") as f:
-            json.dump(d, f)
-    text, n = tpu_profile.run_diff(pa, pb, threshold=0.2)
-    assert n == 1  # only agg.tpu_ms regressed beyond 20%
-    assert "agg.tpu_ms: REGRESSION" in text
-    # self-diff is clean
-    _, n2 = tpu_profile.run_diff(pa, pa, threshold=0.2)
-    assert n2 == 0
-
-
 # ---------------------------------------------------------------------------
 # 5. instrumented subsystems through real runs
 # ---------------------------------------------------------------------------

@@ -238,6 +238,8 @@ def _groupby(n, approx, report, strategy=None, rows=None, seed=23):
 
 
 def test_an_exact_float_sum_is_planned_fixed_under_matmul(matmul):
+    # the first forced-MATMUL groupby of the file: its seconds are the limb
+    # program's one compile, which the size (3000 rows) does not move
     report = {}
     got, want = _groupby(3000, False, report)
     assert report["lowering"] == ("fsum_fixed", "isum", "count")

@@ -608,93 +608,6 @@ def test_diff_logs_gates_fusion_bytes_and_scatter_appearance():
     assert n == 0, text
 
 
-def test_diff_bench_gates_hlo_fields():
-    def shape(top, scat, strategy="SCATTER"):
-        return {"per_shape": {"agg": {
-            "tpu_ms": 100.0, "agg_strategy": strategy,
-            "hlo_top_fusion_bytes": top, "hlo_scatter_count": scat}}}
-
-    text, n = tpu_profile.diff_bench(shape(1 << 20, 2),
-                                     shape(10 << 20, 2), threshold=0.2)
-    assert n == 1 and "agg.hlo_top_fusion_bytes: REGRESSION" in text
-    # same strategy, scatter count rises: REGRESSION
-    text, n = tpu_profile.diff_bench(shape(1 << 20, 2),
-                                     shape(1 << 20, 3), threshold=0.2)
-    assert n == 1 and "agg.hlo_scatter_count: REGRESSION" in text
-    # a deliberate strategy flip owns its scatter delta: no gate
-    text, n = tpu_profile.diff_bench(
-        shape(1 << 20, 0, strategy="SORT"),
-        shape(1 << 20, 3, strategy="SCATTER"), threshold=0.2)
-    assert n == 0, text
-    # ... and its fusion-map delta: the radix loop compiles as ONE big
-    # fusion, so a flip's top-fusion growth is owned too (the committed
-    # rounds' absolute amplification levels are pinned in CI instead)
-    text, n = tpu_profile.diff_bench(
-        shape(1 << 20, 2, strategy="SCATTER"),
-        shape(10 << 20, 0, strategy="RADIX"), threshold=0.2)
-    assert n == 0, text
-    # absent fields (old rounds): no gate
-    text, n = tpu_profile.diff_bench(
-        {"per_shape": {"agg": {"tpu_ms": 100.0}}},
-        shape(1 << 20, 2), threshold=0.2)
-    assert n == 0, text
-
-
-def test_diff_bench_gates_byte_amplification():
-    def shape(**kw):
-        return {"per_shape": {"agg": {"tpu_ms": 100.0, **kw}}}
-
-    # first-class field, beyond-threshold growth: REGRESSION
-    text, n = tpu_profile.diff_bench(
-        shape(byte_amplification=2.5),
-        shape(byte_amplification=25.0), threshold=0.2)
-    assert n == 1 and "agg.byte_amplification: REGRESSION" in text
-    # shrink (the round-12 fix direction): ok
-    text, n = tpu_profile.diff_bench(
-        shape(byte_amplification=25.0),
-        shape(byte_amplification=2.5), threshold=0.2)
-    assert n == 0 and "agg.byte_amplification: ok" in text
-    # BACKFILL: an r09-era json carries only the two inputs — the ratio
-    # is derived (19.4 GB / 772 MB ~ 25x) and still gates the new run
-    old = shape(xla_bytes_accessed=int(19.4e9),
-                predicted_hbm_bytes=int(772e6))
-    text, n = tpu_profile.diff_bench(
-        old, shape(byte_amplification=4.0), threshold=0.2)
-    assert n == 0 and "25.13x -> 4.00x" in text, text
-    text, n = tpu_profile.diff_bench(
-        shape(byte_amplification=4.0), old, threshold=0.2)
-    assert n == 1 and "REGRESSION" in text
-    # one side missing both inputs: no gate
-    text, n = tpu_profile.diff_bench(
-        shape(), shape(byte_amplification=9.9), threshold=0.2)
-    assert n == 0, text
-    # a deliberate lowering flip (agg OR join strategy) owns its
-    # amplification — AUTO resolves different tiers at different
-    # scales, so a scale-mismatched smoke must not false-fire; the
-    # committed absolute levels are pinned by the events CI job
-    text, n = tpu_profile.diff_bench(
-        shape(byte_amplification=9.8, agg_strategy="RADIX"),
-        shape(byte_amplification=31.0, agg_strategy="SCATTER"),
-        threshold=0.2)
-    assert n == 0 and "agg.agg_strategy: RADIX -> SCATTER" in text, text
-    text, n = tpu_profile.diff_bench(
-        shape(byte_amplification=9.8, join_strategy="RADIX"),
-        shape(byte_amplification=31.0, join_strategy="DIRECT"),
-        threshold=0.2)
-    assert n == 0 and "agg.join_strategy: RADIX -> DIRECT" in text, text
-    # and bench.py's own helper is the same ratio (shared definition)
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(os.path.dirname(__file__), os.pardir,
-                                  "bench.py"))
-    bench_mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_mod)
-    assert bench_mod.byte_amplification(int(19.4e9), int(772e6)) == 25.13
-    assert bench_mod.byte_amplification(None, 100) is None
-    assert bench_mod.byte_amplification(100, 0) is None
-
-
 # ---------------------------------------------------------------------------
 # 7. environment provenance
 # ---------------------------------------------------------------------------
@@ -748,15 +661,9 @@ def test_diff_warns_loudly_on_environment_mismatch():
                                     threshold=0.2)
     assert "ENVIRONMENTS DIFFER" in text
     assert n == 0, "env mismatch is a warning, not a regression"
-    # bench-JSON form: top-level env blocks
-    text, n = tpu_profile.diff_bench(
-        {"per_shape": {}, "env": cpu_env},
-        {"per_shape": {}, "env": tpu_env}, threshold=0.2)
-    assert "ENVIRONMENTS DIFFER" in text and n == 0
     # same env: silent
-    text, _ = tpu_profile.diff_bench(
-        {"per_shape": {}, "env": cpu_env},
-        {"per_shape": {}, "env": dict(cpu_env)}, threshold=0.2)
+    text, _ = tpu_profile.diff_logs([qstart(cpu_env)],
+                                    [qstart(dict(cpu_env))], threshold=0.2)
     assert "ENVIRONMENTS DIFFER" not in text
 
 

@@ -113,6 +113,9 @@ def test_parquet_hive_partition_values(tmpd):
 
 @pytest.mark.parametrize("rt", ["PERFILE", "COALESCING", "MULTITHREADED"])
 def test_parquet_reader_strategies_agree(tmpd, rt):
+    # three files of five row groups: every strategy has files to coalesce
+    # and row groups to split; the first case pays the compiles all three
+    # share (two row groups a file measured no cheaper)
     t = _mixed_table(1500, seed=3)
     for i in range(3):
         pq.write_table(t.slice(i * 500, 500), f"{tmpd}/p{i}.parquet",

@@ -33,6 +33,7 @@ import spark_rapids_tpu  # noqa: F401  (x64 enable)
 import jax
 import jax.numpy as jnp
 
+from spark_rapids_tpu import events as EV
 from spark_rapids_tpu import faults
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import schema_of
@@ -70,37 +71,39 @@ def _sorted_build(rng, nb, bcount, nwords, lo_card=50):
     return ws
 
 
-def test_ops_radix_ranges_bitidentical_vs_search():
-    rng = np.random.default_rng(3)
-    for trial in range(8):
-        nb = int(rng.integers(1, 400))
-        m = int(rng.integers(1, 600))
-        bcount = int(rng.integers(0, nb + 1))
-        nwords = 1 + trial % 3
-        bws = _sorted_build(rng, nb, bcount, nwords)
-        pws = [rng.integers(0, 70, m).astype(np.uint32)] + [
-            rng.integers(0, 3, m).astype(np.uint32)
-            for _ in range(nwords - 1)
-        ]
-        live = rng.random(m) < 0.8
-        args = ([jnp.asarray(w) for w in bws], jnp.int32(bcount),
-                [jnp.asarray(w) for w in pws], jnp.asarray(live))
-        lo0, hi0 = J._probe_binary_search(*args)
-        lo1, hi1, matched = J.radix_probe_ranges(*args, want_matched=True)
-        np.testing.assert_array_equal(np.asarray(lo0), np.asarray(lo1),
-                                      err_msg=f"trial {trial} lo")
-        np.testing.assert_array_equal(np.asarray(hi0), np.asarray(hi1),
-                                      err_msg=f"trial {trial} hi")
-        want_m = np.asarray(J.matched_build_mask(
-            lo0, hi0, jnp.asarray(live), nb))
-        np.testing.assert_array_equal(want_m, np.asarray(matched),
-                                      err_msg=f"trial {trial} matched")
-        # the fused lo/matched variant: same lo, matched == (hi > lo)
-        lo2, hi2, _ = J.radix_probe_ranges(*args, lo_matched_only=True)
-        has = np.asarray(hi0 > lo0)
-        np.testing.assert_array_equal(np.asarray(lo2)[has],
-                                      np.asarray(lo0)[has])
-        np.testing.assert_array_equal(np.asarray(hi2 > lo2), has)
+#: (build rows, probe rows) by key words: a shape compiles three programs,
+#: so the trials vary the data, the joinable prefix and the dead rows over
+#: three fixed shapes and not the shapes themselves
+_RANGE_SHAPES = {1: (399, 130), 2: (64, 599), 3: (7, 257)}
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_ops_radix_ranges_bitidentical_vs_search(seed):
+    rng = np.random.default_rng(seed)
+    nwords = 1 + seed % 3
+    nb, m = _RANGE_SHAPES[nwords]
+    bcount = int(rng.integers(0, nb + 1))
+    bws = _sorted_build(rng, nb, bcount, nwords)
+    pws = [rng.integers(0, 70, m).astype(np.uint32)] + [
+        rng.integers(0, 3, m).astype(np.uint32)
+        for _ in range(nwords - 1)
+    ]
+    live = rng.random(m) < 0.8
+    args = ([jnp.asarray(w) for w in bws], jnp.int32(bcount),
+            [jnp.asarray(w) for w in pws], jnp.asarray(live))
+    lo0, hi0 = J._probe_binary_search(*args)
+    lo1, hi1, matched = J.radix_probe_ranges(*args, want_matched=True)
+    np.testing.assert_array_equal(np.asarray(lo0), np.asarray(lo1))
+    np.testing.assert_array_equal(np.asarray(hi0), np.asarray(hi1))
+    want_m = np.asarray(J.matched_build_mask(
+        lo0, hi0, jnp.asarray(live), nb))
+    np.testing.assert_array_equal(want_m, np.asarray(matched))
+    # the fused lo/matched variant: same lo, matched == (hi > lo)
+    lo2, hi2, _ = J.radix_probe_ranges(*args, lo_matched_only=True)
+    has = np.asarray(hi0 > lo0)
+    np.testing.assert_array_equal(np.asarray(lo2)[has],
+                                  np.asarray(lo0)[has])
+    np.testing.assert_array_equal(np.asarray(hi2 > lo2), has)
 
 
 def test_ops_radix_ranges_dead_probe_and_empty_sides():
@@ -428,7 +431,12 @@ def test_strategy_visible_in_events_and_explain():
                        "spark.rapids.tpu.sql.join.strategy": "RADIX"})
     ldf = sess.create_dataframe(_L, _LS)
     rdf = sess.create_dataframe(_RU, _RS)
-    rows = ldf.join(rdf, on=[("k", "k2")], how="inner").collect()
+    try:
+        rows = ldf.join(rdf, on=[("k", "k2")], how="inner").collect()
+    finally:
+        # the sink is process-wide: left installed, every later file of
+        # this worker runs with a cost consumer on
+        EV.uninstall()
     assert len(rows) == 120
     evs = [r for r in sess.events.records()
            if r.get("event") == "join_strategy"]

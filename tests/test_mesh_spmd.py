@@ -1,12 +1,11 @@
 """Round-6 mesh SPMD tests: whole-plan absorption, the sharded scan, the
-mesh window stage, per-shard plananalysis forecasts + cross-check, the
-conf-validated mesh builder, and the MULTICHIP diff gate.
+mesh window stage, per-shard plananalysis forecasts + cross-check, and the
+conf-validated mesh builder.
 
 Everything differential: mesh outputs compare against the single-device /
 python oracle, and the forecast cross-check must report ZERO violations on
-every materialized stage (the same bar MULTICHIP_r06.json commits to).
+every materialized stage.
 """
-import json
 import os
 import sys
 import tempfile
@@ -320,60 +319,3 @@ def test_per_shard_spans_and_transfers_in_event_log():
              if e.get("ph") == "M"}
     for sh in range(N_DEV):
         assert any(f"[chip {sh}]" in n for n in names), names
-
-
-# ---------------------------------------------------------------------------
-# MULTICHIP diff gate (tools/tpu_profile.py)
-# ---------------------------------------------------------------------------
-def _multichip_payload(eff=0.6, lowered=True, sharded=True, viol=()):
-    return {
-        "metric": "mesh_scaling", "n_devices": 8, "scale": 0.25,
-        "host_parallelism": 2,
-        "per_shape": {
-            "agg": {"tpu_ms": 100.0, "device_ms": 80.0,
-                    "scaling_efficiency": eff, "mesh_lowered": lowered,
-                    "sharded_scan": sharded},
-        },
-        "forecast_violations": list(viol),
-        "ok": not viol,
-    }
-
-
-def test_multichip_diff_flags_efficiency_drop(tmp_path):
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools"))
-    import tpu_profile as TP
-
-    text, bad = TP.diff_multichip(
-        _multichip_payload(eff=0.6), _multichip_payload(eff=0.3), 0.2)
-    assert bad == 1 and "scaling_efficiency: REGRESSION" in text
-    text, bad = TP.diff_multichip(
-        _multichip_payload(eff=0.6), _multichip_payload(eff=0.55), 0.2)
-    assert bad == 0
-    # mesh lowering lost -> structural regression even across scales
-    new = _multichip_payload(eff=0.6, lowered=False)
-    new["scale"] = 0.01
-    text, bad = TP.diff_multichip(_multichip_payload(), new, 0.2)
-    assert bad == 1 and "no longer lowers" in text
-    # forecast violations in the new run always gate
-    text, bad = TP.diff_multichip(
-        _multichip_payload(), _multichip_payload(viol=["x"]), 0.2)
-    assert bad >= 1 and "forecast violation" in text
-    # legacy dry-run old format: structural only, no crash
-    text, bad = TP.diff_multichip(
-        {"n_devices": 8, "ok": True}, _multichip_payload(), 0.2)
-    assert bad == 0
-
-
-def test_multichip_diff_file_dispatch(tmp_path):
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools"))
-    import tpu_profile as TP
-
-    old = tmp_path / "MULTICHIP_old.json"
-    new = tmp_path / "MULTICHIP_new.json"
-    old.write_text(json.dumps(_multichip_payload()))
-    new.write_text(json.dumps(_multichip_payload()))
-    text, bad = TP.run_diff(str(old), str(new), 0.2)
-    assert "diff (multichip)" in text
-    assert bad == 0

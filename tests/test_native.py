@@ -9,7 +9,8 @@ import pytest
 from spark_rapids_tpu import native
 
 pytestmark = pytest.mark.skipif(
-    not native.available(), reason="native toolchain unavailable")
+    not native.available(),
+    reason=f"native toolchain unavailable: {native.load_error()}")
 
 
 def test_lz4_round_trip_patterns():

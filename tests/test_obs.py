@@ -840,29 +840,3 @@ raise SystemExit(3)               # die WITHOUT close(); no query_end
     # the offline profiler reads the dump like any log
     text, _ = tpu_profile.build_report(recs)
     assert "query 1" in text
-
-
-# ---------------------------------------------------------------------------
-# 8. bench satellite: per-shape memory-pressure fields
-# ---------------------------------------------------------------------------
-def test_bench_mem_stats_fields():
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    from spark_rapids_tpu.io.scan_cache import DeviceScanCache
-
-    DeviceScanCache.reset()
-    before = bench._mem_snapshot()
-    cache = DeviceScanCache(1 << 20)
-    DeviceScanCache._instance = cache
-    try:
-        cache.get(("a",))          # miss
-        cache.put(("a",), 1, 10)
-        cache.get(("a",))          # hit
-        stats = bench._mem_stats(before)
-        assert stats["scan_cache_hit_rate"] == 0.5
-        assert stats["peak_device_bytes"] >= 0
-        assert stats["scan_cache_bytes"] == 10
-    finally:
-        DeviceScanCache.reset()

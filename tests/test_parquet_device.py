@@ -576,9 +576,8 @@ def test_decode_spans_carry_the_gathers_their_keys_count(tmp_path,
 def test_parquet_scan_footprint_and_predict_exec_hbm(tmp_path):
     """The unpack site finally has a layout bound: predict_exec_hbm over
     a live parquet scan tree is non-null (uploaded payloads + decoded
-    planes from the footers), so the bench parquet shape's
-    byte_amplification stops being null and the --diff growth gate
-    binds there."""
+    planes from the footers), so a parquet scan's byte amplification
+    has a denominator."""
     from spark_rapids_tpu.plugin.plananalysis import (
         parquet_scan_footprint,
         predict_exec_hbm,

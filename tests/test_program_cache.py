@@ -20,9 +20,9 @@ Pins the ISSUE 15 contracts:
      naming the avoided bill), feeding the roofline report, the
      '== program cache ==' profiler section, and the obs twins;
   7. zero overhead when off: conf off => no lookup, no store, no jax
-     config change, cached_pipeline's fast path untouched;
-  8. --diff: warm compile misses / a collapsed warm ratio / grown
-     compile_s_warm flag regressions in the bench cold_start lane.
+     config change, cached_pipeline's fast path untouched.
+
+And where the JAX compile cache itself lives (``envinfo.use_compile_cache``).
 """
 import importlib.util
 import json
@@ -660,47 +660,24 @@ def test_store_single_flight_lockfile(tmp_path):
     assert not os.path.exists(path + ".lock")
 
 
-def _cold_row(**over):
-    row = {"compile_s_cold": 4.0, "compile_s_warm": 0.3,
-           "warm_ratio": 0.075, "compile_miss_cold": 3,
-           "compile_miss_warm": 0, "from_cache_warm": 3}
-    row.update(over)
-    return row
+# ---------------------------------------------------------------------------
+# the JAX compile cache's directory (envinfo.use_compile_cache)
+# ---------------------------------------------------------------------------
+def test_compile_cache_is_the_env_var_else_the_checkout(monkeypatch):
+    import spark_rapids_tpu
+    from spark_rapids_tpu.envinfo import use_compile_cache
 
-
-def test_diff_gates_cold_start_lane():
-    old = {"cold_start": {"agg": _cold_row()}}
-    # clean new run: no regressions
-    _, n = tpu_profile.diff_bench(
-        old, {"cold_start": {"agg": _cold_row()}}, 0.25)
-    assert n == 0
-    # warm compile misses = the cache stopped hitting
-    _, n = tpu_profile.diff_bench(
-        old, {"cold_start": {"agg": _cold_row(compile_miss_warm=2)}}, 0.25)
-    assert n >= 1
-    # collapsed warm ratio
-    _, n = tpu_profile.diff_bench(
-        old, {"cold_start": {"agg": _cold_row(
-            compile_s_warm=3.6, warm_ratio=0.9)}}, 0.25)
-    assert n >= 1
-    # grown warm compile seconds vs the old round
-    _, n = tpu_profile.diff_bench(
-        old, {"cold_start": {"agg": _cold_row(
-            compile_s_warm=1.2, warm_ratio=0.3)}}, 0.25)
-    assert n >= 1
-    # a steady residual miss (timing-dependent keys, e.g. the parquet
-    # packed upload) is NOT a regression: same count as the old round
-    _, n = tpu_profile.diff_bench(
-        {"cold_start": {"pq": _cold_row(compile_miss_warm=1)}},
-        {"cold_start": {"pq": _cold_row(compile_miss_warm=1)}}, 0.25)
-    assert n == 0
-    # no baseline: misses flag only when the cache served NOTHING
-    _, n = tpu_profile.diff_bench(
-        {}, {"cold_start": {"agg": _cold_row(
-            compile_miss_warm=1, from_cache_warm=2)}}, 0.25)
-    assert n == 0
-    _, n = tpu_profile.diff_bench(
-        {}, {"cold_start": {"agg": _cold_row(
-            compile_miss_warm=3, from_cache_warm=0,
-            compile_s_warm=3.9, warm_ratio=0.975)}}, 0.25)
-    assert n >= 1
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/operator/dir")
+    assert use_compile_cache() == "/some/operator/dir"
+    assert jax.config.jax_compilation_cache_dir == before  # untouched
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(spark_rapids_tpu.__file__)))
+    try:
+        assert use_compile_cache() == os.path.join(
+            checkout, ".jax_compile_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            checkout, ".jax_compile_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -368,33 +368,6 @@ def test_diff_ignores_compile_jitter_below_noise_floor():
     assert n == 1 and "sort.compile: REGRESSION" in text
 
 
-def test_diff_bench_compares_hbm_frac_xla_when_present():
-    old = {"per_shape": {"agg": {"tpu_ms": 100.0, "hbm_frac_xla": 0.10}}}
-    new = {"per_shape": {"agg": {"tpu_ms": 100.0, "hbm_frac_xla": 0.02}}}
-    text, n = tpu_profile.diff_bench(old, new, threshold=0.2)
-    assert n == 1 and "agg.hbm_frac_xla: REGRESSION" in text
-    # a full collapse must fire at CI's --threshold 2.0 too: the gate is
-    # ratio-form like the ms gates (a drop-fraction saturates at 1.0 and
-    # could never clear 2.0), and small committed fracs (~0.004 on the
-    # CPU fallback) sit ABOVE the noise floor
-    collapsed = {"per_shape": {"agg": {"tpu_ms": 100.0,
-                                       "hbm_frac_xla": 0.0001}}}
-    small = {"per_shape": {"agg": {"tpu_ms": 100.0,
-                                   "hbm_frac_xla": 0.0038}}}
-    text, n = tpu_profile.diff_bench(old, collapsed, threshold=2.0)
-    assert n == 1 and "agg.hbm_frac_xla: REGRESSION" in text
-    text, n = tpu_profile.diff_bench(small, collapsed, threshold=2.0)
-    assert n == 1, text
-    # zero new-run frac (device fully idle) is the worst case, not a div0
-    zero = {"per_shape": {"agg": {"tpu_ms": 100.0, "hbm_frac_xla": 0.0}}}
-    text, n = tpu_profile.diff_bench(old, zero, threshold=2.0)
-    assert n == 1, text
-    # absent on either side: no gate (the runs aren't comparable)
-    new_absent = {"per_shape": {"agg": {"tpu_ms": 100.0}}}
-    text, n = tpu_profile.diff_bench(old, new_absent, threshold=0.2)
-    assert n == 0, text
-
-
 # ---------------------------------------------------------------------------
 # 9. explain_metrics lane labeling (the satellite fix) + xla columns
 # ---------------------------------------------------------------------------

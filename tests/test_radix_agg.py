@@ -152,6 +152,8 @@ def test_matrix_float_streams_inf_nan_huge():
     correction, RADIX NORMAL/BIG/flags) must agree with the plain f64
     scatter sum on normals, huge magnitudes (>2^500), infinities of one
     sign, mixed infinities (-> NaN), and NaN poisoning."""
+    # 22 rows in the smallest bucket (256): the seconds are five lowerings'
+    # compiles of a float sum (PALLAS interpreted), not the size
     cases = {
         0: [1.5, -2.25, 3e8],                      # plain normals
         1: [1e300, 1e300, -2.5e299],               # BIG stream only
@@ -206,6 +208,8 @@ def test_matrix_float_magnitude_disparity_across_groups():
 def test_matrix_dead_rows_never_contribute():
     """Rows past num_rows carry arbitrary garbage (incl. extreme values
     that would win any min/max) and must drop from every lowering."""
+    # four fifths of the slots dead is the point; the seconds are five
+    # lowerings' compiles of sum/min/max, not the 512 slots
     n, cap = 100, 512
     rng = np.random.default_rng(8)
     key = rng.integers(0, 7, cap)  # garbage keys on dead rows too
@@ -232,6 +236,8 @@ def test_matrix_tier_overflow_escalates_scatter_free():
     """Cardinality past the first hash tier (128 buckets) forces the
     tier-escalation retry; under RADIX/PALLAS the escalation (and the
     final sort fallback) must still produce the baseline's groups."""
+    # the size is the point: 2048 is the smallest capacity whose chain has
+    # all three hash tiers (128, 1024, 2048) above the sort fallback
     n, cap = 1500, 2048
     rng = np.random.default_rng(9)
     key = np.zeros(cap, np.int64)

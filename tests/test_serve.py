@@ -506,9 +506,8 @@ def test_concurrent_execution_overlaps():
     """The pipelining claim, asserted structurally: with headroom for
     several forecasts, concurrent submits are simultaneously admitted
     (peak_active >= 2) and all results stay correct. The wall-clock
-    queries/sec comparison lives in bench.py --serve, where the workload
-    is sized to dominate scheduler overhead (a micro-workload on a
-    shared 2-core CI box measures only noise)."""
+    queries/sec comparison is not made here: a micro-workload on a
+    shared 2-core CI box measures only noise."""
     forecast = _forecast_of()
     settings = {
         "spark.rapids.tpu.serve.enabled": True,

@@ -68,6 +68,12 @@ def _by_string_key(sess, directory):
 def lowered(tmp_path_factory):
     """{site: lowering text with locations} of every program the two
     queries build, caught at the one chokepoint."""
+    from spark_rapids_tpu import xla_cost
+
+    assert not xla_cost.harvesting(), (
+        "an earlier test file of this worker left a cost consumer on (an "
+        "event sink, the obs plane or FORCE_HARVEST): programs come back "
+        "wrapped and cannot be lowered here")
     directory = _write_table(tmp_path_factory.mktemp("names") / "t")
     texts = {}
     real = XB.cached_pipeline

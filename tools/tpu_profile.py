@@ -49,9 +49,7 @@ Report sections:
 
 Diff mode (``--diff A B``): compare two event logs (per-op host/device
 time and bytes, per-site XLA bytes/temp, per-site top-fusion bytes and
-scatter counts from hlo_summary events) or two bench JSON result files
-(``BENCH_*.json`` — the ``per_shape`` block's tpu_ms/device_ms plus the
-hlo_top_fusion_bytes/hlo_scatter_count gates). Regressions beyond
+scatter counts from hlo_summary events). Regressions beyond
 ``--threshold`` (default 20%) are flagged and make the exit code
 nonzero. When the two runs' ``env`` provenance blocks name different
 hardware (backend/device kind), a loud ENVIRONMENTS DIFFER banner
@@ -83,18 +81,6 @@ DEFAULT_STORM_THRESHOLD = 8
 #: (also applied to harvested compile-time deltas in --diff: trace/
 #: compile jitter below the floor is never flagged)
 DIFF_MIN_NS = 1_000_000
-#: same floor for bench-JSON ms fields (0.1ms of scheduler jitter on a
-#: 0.3ms shape is a 1.33x "ratio", not a regression)
-DIFF_MIN_MS = 1.0
-#: hbm_frac_* gates only fire when the OLD run's fraction was above this
-#: floor — below it the figure is quantization noise and any ratio is
-#: meaningless (must sit under the committed BENCH shape values, which
-#: run ~2e-4..6e-3 on the CPU fallback, or the gate is dead exactly
-#: where CI runs it)
-DIFF_MIN_FRAC = 1e-4
-#: per-op HBM peak growth below this many bytes is allocator jitter
-#: (padding, pool rounding), not an operator holding more memory
-DIFF_MIN_HBM_BYTES = 1 << 20
 
 #: per-backend (peak HBM GB/s, peak TFLOP/s) used when --peak-hbm-gbps /
 #: --peak-tflops are not given; MUST mirror
@@ -137,30 +123,6 @@ def load_events(paths: List[str]) -> List[dict]:
     return out
 
 
-def _is_bench_json(path: str) -> bool:
-    try:
-        with open(path) as f:
-            head = f.read(1 << 20)
-        return (("per_shape" in head or "cold_start" in head)
-                and path.endswith(".json"))
-    except OSError:
-        return False
-
-
-def _is_multichip_json(path: str) -> bool:
-    """A MULTICHIP_*.json (bench.py --mesh payload): mesh_scaling metric,
-    or the legacy dry-run {n_devices, ok} format."""
-    try:
-        with open(path) as f:
-            head = f.read(1 << 20)
-    except OSError:
-        return False
-    if not path.endswith(".json"):
-        return False
-    return "mesh_scaling" in head or (
-        "n_devices" in head and "per_shape" not in head)
-
-
 def _ms(ns: Optional[float]) -> str:
     return "-" if ns is None else f"{ns / 1e6:.1f}ms"
 
@@ -171,7 +133,7 @@ def _mb(b: Optional[float]) -> str:
 
 # ---------------------------------------------------------------------------
 # environment provenance (envinfo.environment_info blocks riding on
-# query_start events and BENCH json top levels)
+# query_start events)
 # ---------------------------------------------------------------------------
 def _env_of(events: List[dict]) -> Optional[dict]:
     """The first query_start env block in a log (None for pre-provenance
@@ -993,383 +955,14 @@ def run_alerts(events: List[dict], stall_ms: int, pressure_fraction: float,
 # ---------------------------------------------------------------------------
 # diff mode
 # ---------------------------------------------------------------------------
-def _byte_amp(shape_row: dict) -> Optional[float]:
-    """Per-shape byte amplification (XLA bytes-accessed / analyzer
-    layout bound). Newer BENCH jsons carry it first-class
-    (bench.byte_amplification); older rounds that recorded both inputs
-    are BACKFILLED here so the r09-era baselines still gate the fix."""
-    amp = shape_row.get("byte_amplification")
-    if amp is not None:
-        return amp
-    xb = shape_row.get("xla_bytes_accessed")
-    lb = shape_row.get("predicted_hbm_bytes")
-    if xb and lb:
-        return round(xb / lb, 2)
-    return None
-
-
-def diff_bench(old: dict, new: dict, threshold: float
-               ) -> Tuple[str, int]:
-    # driver-captured BENCH_*.json files wrap the bench line in a
-    # {"parsed": {...}} envelope; unwrap so rounds diff either layout
-    old = old.get("parsed", old) if "per_shape" not in old else old
-    new = new.get("parsed", new) if "per_shape" not in new else new
-    lines: List[str] = []
-    regressions = 0
-    # top-level env blocks (bench.py stamps envinfo.environment_info):
-    # different hardware -> loud warning, time gates stay advisory
-    lines.extend(_env_warning(old.get("env"), new.get("env")))
-    shapes = sorted(set(old.get("per_shape") or {})
-                    | set(new.get("per_shape") or {}))
-    for shape in shapes:
-        a = (old.get("per_shape") or {}).get(shape)
-        b = (new.get("per_shape") or {}).get(shape)
-        if a is None or b is None:
-            lines.append(f"  {shape}: only in "
-                         f"{'new' if a is None else 'old'} run")
-            continue
-        if not isinstance(a, dict) or not isinstance(b, dict):
-            # pre-round-6 layout: bare speedup floats — no timed fields
-            lines.append(f"  {shape}: no comparable timing fields "
-                         "(legacy bench layout)")
-            continue
-        sa, sb = a.get("agg_strategy"), b.get("agg_strategy")
-        if sa != sb and (sa or sb):
-            lines.append(f"  {shape}.agg_strategy: {sa} -> {sb} "
-                         "(lowering changed — compare device_ms)")
-        ja, jb = a.get("join_strategy"), b.get("join_strategy")
-        if ja != jb and (ja or jb):
-            lines.append(f"  {shape}.join_strategy: {ja} -> {jb} "
-                         "(join lowering changed — compare device_ms)")
-        # the same-lowering waiver below covers BOTH strategy fields: a
-        # deliberate agg OR join flip redraws the compiled-byte profile
-        # (incl. total bytes — AUTO legitimately resolves different
-        # tiers at different scales), so every byte gate binds only
-        # when neither changed; the flip itself is flagged above, and
-        # CI pins the committed rounds' ABSOLUTE amplification levels
-        # (events job: agg <= r09/5, join <= r10/3) so a flip that
-        # blows up bytes still cannot land
-        same_lowering = sa == sb and ja == jb
-        for field in ("tpu_ms", "device_ms"):
-            va, vb = a.get(field), b.get(field)
-            if va is None or vb is None or va <= 0:
-                continue
-            ratio = vb / va
-            if ratio > 1.0 + threshold and vb - va > DIFF_MIN_MS:
-                regressions += 1
-                lines.append(
-                    f"  {shape}.{field}: REGRESSION {va:.1f} -> {vb:.1f} "
-                    f"({ratio:.2f}x, threshold {1 + threshold:.2f}x)")
-            else:
-                lines.append(
-                    f"  {shape}.{field}: ok {va:.1f} -> {vb:.1f} "
-                    f"({ratio:.2f}x)")
-        # compiler-reported HBM utilization: compared only when BOTH
-        # runs harvested it (hbm_frac_xla = XLA bytes / device time /
-        # peak); a relative drop beyond the threshold means the device
-        # got less busy for the same compiled work
-        # ... unless the agg lowering deliberately changed (flagged
-        # above): a strategy flip rewrites what "the same compiled work"
-        # even is — e.g. the radix rewrite shrinks XLA bytes ~25x, which
-        # reads as a frac drop while being the fix itself
-        fa, fb = a.get("hbm_frac_xla"), b.get("hbm_frac_xla")
-        if fa is not None and fb is not None and fa > DIFF_MIN_FRAC \
-                and same_lowering:
-            # same unbounded ratio form as the tpu_ms/device_ms gates: a
-            # drop-fraction ((fa-fb)/fa) saturates at 1.0 and can never
-            # clear CI's threshold 2.0, so a full collapse would pass
-            ratio = fa / fb if fb > 0 else float("inf")
-            if ratio > 1.0 + threshold:
-                regressions += 1
-                lines.append(f"  {shape}.hbm_frac_xla: REGRESSION "
-                             f"{fa:.4f} -> {fb:.4f} ({ratio:.2f}x drop, "
-                             f"threshold {1 + threshold:.2f}x)")
-            else:
-                lines.append(f"  {shape}.hbm_frac_xla: ok {fa:.4f} -> "
-                             f"{fb:.4f}")
-        # per-fusion attribution gates, the bench twin of diff_logs'
-        # _site_hlo checks: the largest single-fusion byte figure must
-        # not grow beyond the threshold, and the scatter count must not
-        # rise (both shape-derived — meaningful across environments)
-        ta, tb = a.get("hlo_top_fusion_bytes"), b.get("hlo_top_fusion_bytes")
-        if ta and tb and same_lowering:
-            # a deliberate lowering flip redraws the fusion map (the
-            # radix loop IS one big fusion); its TOTAL bytes are gated
-            # by byte_amplification above, so the per-fusion gate only
-            # binds same-strategy runs
-            if tb > ta * (1.0 + threshold):
-                regressions += 1
-                lines.append(f"  {shape}.hlo_top_fusion_bytes: REGRESSION "
-                             f"{ta} -> {tb} (one fusion owns more traffic)")
-            else:
-                lines.append(f"  {shape}.hlo_top_fusion_bytes: ok "
-                             f"{ta} -> {tb}")
-        # byte amplification (XLA bytes / layout bound): the trended
-        # number of the round-12 kernel rewrite. Growth beyond the
-        # threshold means the compiled programs started touching bytes
-        # the layout never demanded — a regression even when wall clock
-        # on a noisy shared box hides it (backfilled for older jsons).
-        # Same-lowering only: AUTO resolves different tiers at
-        # different scales (a scale-0.1 smoke legitimately runs the
-        # SCATTER agg the committed scale-0.25 round replaced), and a
-        # deliberate flip owns its amplification — the committed-round
-        # ABSOLUTE levels are pinned by the events job instead
-        aa, ab = _byte_amp(a), _byte_amp(b)
-        if aa and ab and same_lowering:
-            if ab > aa * (1.0 + threshold):
-                regressions += 1
-                lines.append(f"  {shape}.byte_amplification: REGRESSION "
-                             f"{aa:.2f}x -> {ab:.2f}x of the layout "
-                             f"bound (threshold {1 + threshold:.2f}x "
-                             "growth)")
-            else:
-                lines.append(f"  {shape}.byte_amplification: ok "
-                             f"{aa:.2f}x -> {ab:.2f}x")
-        # peak temp (largest per-program temp allocation): growth beyond
-        # the threshold under the SAME lowering means a program started
-        # materializing bigger intermediates; a strategy flip owns its
-        # temp profile (flagged above)
-        pa, pb = a.get("xla_peak_temp_bytes"), b.get("xla_peak_temp_bytes")
-        if pa and pb and same_lowering:
-            if pb > pa * (1.0 + threshold):
-                regressions += 1
-                lines.append(f"  {shape}.xla_peak_temp_bytes: REGRESSION "
-                             f"{pa} -> {pb} (bigger materialized "
-                             "intermediates)")
-            else:
-                lines.append(f"  {shape}.xla_peak_temp_bytes: ok "
-                             f"{pa} -> {pb}")
-        # per-op HBM peak (the ledger's per-shape attribution,
-        # bench._mem_stats hbm_peak_by_op): any single op's peak growing
-        # beyond the threshold AND the 1MiB jitter floor means that
-        # operator started holding more device memory at once — gated
-        # same-lowering only (a strategy flip redraws who holds what)
-        ha, hb = a.get("hbm_peak_by_op"), b.get("hbm_peak_by_op")
-        if isinstance(ha, dict) and isinstance(hb, dict) and same_lowering:
-            for op in sorted(set(ha) | set(hb)):
-                oa, ob = ha.get(op) or 0, hb.get(op) or 0
-                if ob - oa <= DIFF_MIN_HBM_BYTES:
-                    continue
-                if oa and ob / oa <= 1.0 + threshold:
-                    continue
-                regressions += 1
-                lines.append(
-                    f"  {shape}.hbm_peak_by_op[{op}]: REGRESSION "
-                    f"{oa} -> {ob} bytes"
-                    + (f" ({ob / oa:.2f}x)" if oa else " (new op)"))
-        # leaked buffers are an absolute gate, not a diff: any nonzero
-        # count in the NEW run fails regardless of the old run
-        leaked_new = b.get("leaked_buffers")
-        if leaked_new:
-            regressions += 1
-            lines.append(f"  {shape}.leaked_buffers: REGRESSION "
-                         f"{leaked_new} buffer(s) outlived the query "
-                         "(must be 0)")
-        ka, kb = a.get("hlo_scatter_count"), b.get("hlo_scatter_count")
-        if ka is not None and kb is not None:
-            # growth is gated only when NEITHER lowering changed (agg
-            # and join strategy alike): a deliberate flip (already
-            # flagged above) owns its scatter-count delta, a
-            # same-strategy rise is a regression
-            if kb > ka and same_lowering:
-                regressions += 1
-                lines.append(f"  {shape}.hlo_scatter_count: REGRESSION "
-                             f"{ka} -> {kb} (a scatter lowering appeared)")
-            elif ka or kb:
-                lines.append(f"  {shape}.hlo_scatter_count: ok {ka} -> "
-                             f"{kb}")
-    # cold-start lane (bench.py --cold-start): the warm-cache compile
-    # seconds are the serving-restart bill, and they must stay ~zero.
-    # Structural gates on the new run alone (meaningful across
-    # environments): a warm run that counted compile misses means the
-    # AOT cache stopped hitting, and a warm/cold ratio above 0.5 means
-    # deserialize+cached-compile stopped being cheap. Relative gate vs
-    # the old round: compile_s_warm growth beyond the threshold.
-    ca, cb = old.get("cold_start"), new.get("cold_start")
-    if cb:
-        for shape, row in sorted(cb.items()):
-            if not isinstance(row, dict):
-                continue
-            misses = row.get("compile_miss_warm") or 0
-            old_row = (ca or {}).get(shape)
-            old_row = old_row if isinstance(old_row, dict) else None
-            # a site with timing-dependent keys (the parquet packed
-            # upload) legitimately carries a residual warm miss every
-            # round — gate on GROWTH vs the old round, or (with no
-            # baseline) on the cache having served nothing at all
-            if old_row is not None:
-                miss_bad = misses > (old_row.get("compile_miss_warm")
-                                     or 0)
-            else:
-                miss_bad = misses and not row.get("from_cache_warm")
-            if miss_bad:
-                regressions += 1
-                lines.append(
-                    f"  cold_start.{shape}: REGRESSION {misses} warm "
-                    "compile miss(es) — the AOT cache stopped hitting")
-            ratio = row.get("warm_ratio")
-            if ratio is not None and ratio > 0.5:
-                regressions += 1
-                lines.append(
-                    f"  cold_start.{shape}: REGRESSION warm/cold "
-                    f"compile ratio {ratio:.2f} > 0.5 (deserialize no "
-                    "longer avoids the compile bill)")
-            wa = ((ca or {}).get(shape) or {}).get("compile_s_warm") \
-                if isinstance((ca or {}).get(shape), dict) else None
-            wb = row.get("compile_s_warm")
-            if wa and wb is not None:
-                if wb > wa * (1.0 + threshold) \
-                        and (wb - wa) * 1e3 > DIFF_MIN_MS:
-                    regressions += 1
-                    lines.append(
-                        f"  cold_start.{shape}.compile_s_warm: "
-                        f"REGRESSION {wa:.2f}s -> {wb:.2f}s")
-                else:
-                    lines.append(
-                        f"  cold_start.{shape}.compile_s_warm: ok "
-                        f"{wa:.2f}s -> {wb:.2f}s")
-            elif wb is not None and not misses and (
-                    ratio is None or ratio <= 0.5):
-                lines.append(
-                    f"  cold_start.{shape}: ok warm {wb:.2f}s"
-                    + (f" ({ratio:.2f}x of cold)"
-                       if ratio is not None else ""))
-    elif ca:
-        lines.append("  cold_start: lane missing from new run (run "
-                     "bench.py --cold-start to compare)")
-    # serving lane (bench.py --serve): structural gates always — the new
-    # run must be internally clean (ok flag: no errors/rejects/bypass,
-    # summed forecasts within budget) and must still beat serialized
-    # submission; qps is noise-compared only when the runs match shape
-    sa, sb = old.get("serve"), new.get("serve")
-    if sa and sb:
-        if not sb.get("ok"):
-            regressions += 1
-            lines.append("  serve: REGRESSION new run not ok "
-                         f"(errors={sb.get('errors')}, "
-                         f"rejected={sb.get('rejected')}, "
-                         f"bypass={sb.get('bypass_admissions')})")
-        sp = sb.get("speedup_vs_serialized")
-        if sp is not None and sp <= 1.0:
-            regressions += 1
-            lines.append(f"  serve: REGRESSION concurrent qps no longer "
-                         f"beats serialized ({sp:.3f}x)")
-        elif sp is not None:
-            lines.append(f"  serve: ok {sp:.3f}x vs serialized "
-                         f"(qps {sb.get('qps')}, p95 {sb.get('p95_ms')}ms)")
-        comparable = (sa.get("scale") == sb.get("scale")
-                      and sa.get("threads") == sb.get("threads")
-                      and sa.get("queries_per_thread")
-                      == sb.get("queries_per_thread"))
-        va, vb = sa.get("qps"), sb.get("qps")
-        if comparable and va and vb and va / vb > 1.0 + threshold:
-            regressions += 1
-            lines.append(f"  serve.qps: REGRESSION {va} -> {vb}")
-    elif sa and not sb:
-        lines.append("  serve: lane missing from new run (run bench.py "
-                     "--serve to compare)")
-    lines.append(f"  {regressions} regression(s)")
-    return "\n".join(lines), regressions
-
-
-#: absolute scaling-efficiency drop per shape that flags a regression in
-#: the MULTICHIP diff (efficiency is already a 0..1 normalized quantity,
-#: so a relative threshold would over-trigger near zero)
-MULTICHIP_EFF_DROP = 0.1
-
-
-def diff_multichip(old: dict, new: dict, threshold: float,
-                   eff_drop: float = MULTICHIP_EFF_DROP
-                   ) -> Tuple[str, int]:
-    """Diff two MULTICHIP json payloads (bench.py --mesh). Structural
-    gates always apply: every old shape present, mesh-lowered shapes stay
-    mesh-lowered, zero forecast violations in the new run. Per-shape
-    scaling-efficiency regression (absolute drop > ``eff_drop``) and
-    device_ms regressions (relative ``threshold``) are compared only when
-    both runs measured the same scale AND device count — a reduced-scale
-    smoke against a committed full-scale round checks structure, not
-    noise."""
-    old = old.get("parsed", old) if "per_shape" not in old else old
-    new = new.get("parsed", new) if "per_shape" not in new else new
-    lines: List[str] = []
-    regressions = 0
-    if "per_shape" not in old:
-        # legacy dry-run format: only the ok flag existed
-        lines.append("  old run is the legacy dry-run format; structural "
-                     "gate on the new run only")
-        old = {"per_shape": {}}
-    if new.get("forecast_violations"):
-        regressions += 1
-        lines.append(
-            f"  REGRESSION: {len(new['forecast_violations'])} per-shard "
-            "forecast violation(s) in new run")
-    comparable = (
-        old.get("scale") == new.get("scale")
-        and old.get("n_devices") == new.get("n_devices")
-        and old.get("host_parallelism") == new.get("host_parallelism"))
-    if not comparable and old.get("per_shape"):
-        lines.append(
-            f"  scale/devices differ (old scale={old.get('scale')} "
-            f"n={old.get('n_devices')}, new scale={new.get('scale')} "
-            f"n={new.get('n_devices')}): structural checks only")
-    shapes = sorted(set(old.get("per_shape") or {})
-                    | set(new.get("per_shape") or {}))
-    for shape in shapes:
-        a = (old.get("per_shape") or {}).get(shape)
-        b = (new.get("per_shape") or {}).get(shape)
-        if b is None:
-            regressions += 1
-            lines.append(f"  {shape}: REGRESSION shape missing from new "
-                         "run")
-            continue
-        if a is None:
-            lines.append(f"  {shape}: new shape (no baseline)")
-            continue
-        if a.get("mesh_lowered") and not b.get("mesh_lowered"):
-            regressions += 1
-            lines.append(f"  {shape}: REGRESSION no longer lowers to the "
-                         "mesh")
-        if a.get("sharded_scan") and not b.get("sharded_scan"):
-            regressions += 1
-            lines.append(f"  {shape}: REGRESSION sharded scan fell back "
-                         "to host staging")
-        if not comparable:
-            continue
-        ea, eb = a.get("scaling_efficiency"), b.get("scaling_efficiency")
-        if ea is not None and eb is not None:
-            if ea - eb > eff_drop:
-                regressions += 1
-                lines.append(
-                    f"  {shape}.scaling_efficiency: REGRESSION "
-                    f"{ea:.3f} -> {eb:.3f} (drop > {eff_drop})")
-            else:
-                lines.append(f"  {shape}.scaling_efficiency: ok "
-                             f"{ea:.3f} -> {eb:.3f}")
-        for field in ("tpu_ms", "device_ms"):
-            va, vb = a.get(field), b.get(field)
-            if va is None or vb is None or va <= 0:
-                continue
-            ratio = vb / va
-            if ratio > 1.0 + threshold and vb - va > DIFF_MIN_MS:
-                regressions += 1
-                lines.append(
-                    f"  {shape}.{field}: REGRESSION {va:.1f} -> {vb:.1f} "
-                    f"({ratio:.2f}x)")
-            else:
-                lines.append(f"  {shape}.{field}: ok {va:.1f} -> "
-                             f"{vb:.1f} ({ratio:.2f}x)")
-    lines.append(f"  {regressions} regression(s)")
-    return "\n".join(lines), regressions
-
-
 def diff_logs(old_events: List[dict], new_events: List[dict],
               threshold: float) -> Tuple[str, int]:
     lines: List[str] = []
     regressions = 0
     # environment provenance first: when the two logs name different
     # hardware, every time/byte ratio below is apples-to-oranges — warn
-    # loudly (warning, not regression: CI diffs a fresh CPU smoke against
-    # committed device rounds on purpose, gating structure only)
+    # loudly (a warning, not a regression: the structural gates below
+    # still hold across environments)
     lines.extend(_env_warning(_env_of(old_events), _env_of(new_events)))
     a, b = aggregate_ops(old_events), aggregate_ops(new_events)
     for op in sorted(set(a) | set(b)):
@@ -1494,25 +1087,10 @@ def _site_costs(events: List[dict]) -> Dict[str, dict]:
 
 def run_diff(old_path: str, new_path: str, threshold: float
              ) -> Tuple[str, int]:
-    if _is_multichip_json(old_path) or _is_multichip_json(new_path):
-        with open(old_path) as f:
-            old = json.load(f)
-        with open(new_path) as f:
-            new = json.load(f)
-        head = [f"== diff (multichip) {old_path} -> {new_path} =="]
-        body, n = diff_multichip(old, new, threshold)
-    elif _is_bench_json(old_path) or _is_bench_json(new_path):
-        with open(old_path) as f:
-            old = json.load(f)
-        with open(new_path) as f:
-            new = json.load(f)
-        head = [f"== diff (bench) {old_path} -> {new_path} =="]
-        body, n = diff_bench(old, new, threshold)
-    else:
-        head = [f"== diff (event logs) {old_path} -> {new_path} =="]
-        body, n = diff_logs(load_events([old_path]),
-                            load_events([new_path]), threshold)
-    return "\n".join(head + [body]), n
+    head = f"== diff (event logs) {old_path} -> {new_path} =="
+    body, n = diff_logs(load_events([old_path]),
+                        load_events([new_path]), threshold)
+    return head + "\n" + body, n
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -1521,12 +1099,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "(see module docstring)")
     ap.add_argument("paths", nargs="+",
                     help="event-log files/dirs; with --diff, exactly two "
-                         "logs or bench JSON files (old new)")
+                         "logs (old new)")
     ap.add_argument("--top", type=int, default=10,
                     help="operators to show in the top-ops table")
     ap.add_argument("--diff", action="store_true",
-                    help="compare two logs / bench JSONs; nonzero exit on "
-                         "regressions beyond --threshold")
+                    help="compare two logs; nonzero exit on regressions "
+                         "beyond --threshold")
     ap.add_argument("--threshold", type=float, default=0.2,
                     help="relative regression threshold for --diff "
                          "(0.2 = 20%%)")
