@@ -88,14 +88,16 @@ _PIPELINE_CACHE_LOCK = ordered_lock("exec.pipeline_cache", reentrant=True)
 
 #: cache dicts that have passed through cached_pipeline (dedup by
 #: identity, O(1) via the id set) — the clear_pipeline_caches() sweep
-#: set. BOUNDED: most caches are module globals (~15 across the
-#: engine), but sort/window/join/exchange also route per-INSTANCE
-#: ``self._jits`` dicts through here, and registering those forever
-#: would pin every exec instance's compiled executables for the
-#: process lifetime (dicts aren't weakref-able). Past the cap new
-#: dicts simply aren't registered — they stay collectable with their
-#: owners, and the sweep (a test/maintenance helper) loses nothing it
-#: needs: a fresh session builds fresh exec instances anyway.
+#: set. Most caches are module globals (8 across the engine) and always
+#: register: a process keeps them for good, so the sweep has to reach
+#: them. sort/window/join/exchange also route per-INSTANCE
+#: ``self._jits`` dicts through here (``per_instance=True``), and
+#: registering those forever would pin every exec instance's compiled
+#: executables for the process lifetime (dicts aren't weakref-able):
+#: they are BOUNDED. Past the cap a per-instance dict simply isn't
+#: registered — it stays collectable with its owner, and the sweep (a
+#: test/maintenance helper) loses nothing it needs: a fresh session
+#: builds fresh exec instances anyway.
 _PIPELINE_CACHE_REGISTRY_CAP = 64
 _ALL_PIPELINE_CACHES: List[dict] = []
 _ALL_PIPELINE_CACHE_IDS: set = set()
@@ -117,7 +119,8 @@ def clear_pipeline_caches() -> int:
 def cached_pipeline(cache: dict, key, site: Optional[str],
                     build: Callable[[], Callable],
                     max_entries: int = 512,
-                    donate: Tuple[int, ...] = ()) -> Callable:
+                    donate: Tuple[int, ...] = (),
+                    per_instance: bool = False) -> Callable:
     if donate:
         # the donation mask is part of the program's identity: a
         # donating and a non-donating dispatch of the same logical
@@ -135,8 +138,8 @@ def cached_pipeline(cache: dict, key, site: Optional[str],
         fn = cache.get(key)
         if fn is None:
             if (id(cache) not in _ALL_PIPELINE_CACHE_IDS
-                    and len(_ALL_PIPELINE_CACHES)
-                    < _PIPELINE_CACHE_REGISTRY_CAP):
+                    and not (per_instance and len(_ALL_PIPELINE_CACHES)
+                             >= _PIPELINE_CACHE_REGISTRY_CAP)):
                 _ALL_PIPELINE_CACHES.append(cache)
                 _ALL_PIPELINE_CACHE_IDS.add(id(cache))
             if len(cache) > max_entries:

@@ -267,7 +267,8 @@ class TpuShuffleExchangeExec(TpuExec):
         # program and must not be invisible to the roofline report
         from .base import cached_pipeline
 
-        return cached_pipeline(self._jits, key, "exchange", build)
+        return cached_pipeline(self._jits, key, "exchange", build,
+                               per_instance=True)
 
     def _sample_range_bounds(self, parts: List[List[ColumnarBatch]]) -> None:
         """Sample key values host-side and set the range bounds

@@ -979,7 +979,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         return cached_pipeline(cache, key, "join",
                                lambda: jax.jit(program("join")(fn),
                                                donate_argnums=donate),
-                               donate=donate)
+                               donate=donate, per_instance=True)
 
     def _unmatched_build(self, build_cols, build_live_all, matched_any):
         """full outer: emit build rows no probe row matched (including live
@@ -1093,7 +1093,7 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
             fn = cached_pipeline(cache, key, "join",
                                  lambda: jax.jit(expand,
                                                  donate_argnums=mask),
-                                 donate=mask)
+                                 donate=mask, per_instance=True)
             with self.op_timed():
                 if mask:
                     # no retry harness wraps this dispatch: skip the

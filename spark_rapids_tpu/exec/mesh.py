@@ -639,13 +639,14 @@ class TpuMeshAggregateExec(_MeshStage):
         def chunked_partials(colflat, n, group_cap, pieces, reports):
             """The PARTIAL aggregate of a large shard, a chunk at a time:
             one loop over ``pieces`` slices of the planes, each slice's
-            groups compacted and cut to ``group_cap`` rows. Returns the
-            partial rows of all slices (keys, buffers, live mask) and
-            whether every slice's groups fitted; ``reports["update"]``
-            says how a slice's aggregate lowers."""
+            groups compacted and cut to ``group_cap`` rows; a slice past
+            the shard's ``n`` rows holds no row and skips its update.
+            Returns the partial rows of all slices (keys, buffers, live
+            mask) and whether every slice's groups fitted;
+            ``reports["update"]`` says how a slice's aggregate lowers."""
             report: dict = {}
 
-            def one(at):
+            def update(at, report):
                 # a slice of the resident planes, not a reshaped copy
                 cols = self._cols_of_flat(
                     [jax.lax.dynamic_slice(p, (at * chunk,), (chunk,))
@@ -666,6 +667,23 @@ class TpuMeshAggregateExec(_MeshStage):
                         pn <= group_cap,
                         report.pop("float_detour", jnp.bool_(False)))
 
+            def empty(at):
+                # what a slice with no live row comes to: no group, no
+                # detour, and it fitted
+                planes, count, _, detour = jax.tree.map(
+                    lambda s: jnp.zeros(s.shape, s.dtype), partial_shapes)
+                return planes, count, jnp.bool_(True), detour
+
+            partial_shapes = jax.eval_shape(
+                lambda at: update(at, {}), jnp.int32(0))
+
+            def one(at):
+                # a real branch (the loop keeps it one): a slice of
+                # padding costs a compare, not an update
+                return jax.lax.cond(
+                    at * chunk < n, lambda at: update(at, report), empty,
+                    at)
+
             planes, counts, fits, detours = jax.lax.map(
                 one, jnp.arange(pieces, dtype=jnp.int32))
             if report:
@@ -682,7 +700,12 @@ class TpuMeshAggregateExec(_MeshStage):
             out_layouts: dict = {}
             group_cap = 0 if gcap >= cap else gcap
             pieces = cap // chunk if 0 < group_cap < chunk < cap else 1
+            # chunks that hold a row, over all shards: the others skip
+            # their update inside the program
+            pieces_live = pieces if pieces == 1 else sum(
+                -(-int(c) // chunk) for c in counts)
             self.mesh_actuals["update_chunks"] = pieces
+            self.mesh_actuals["update_chunks_live"] = pieces_live
 
             def build(group_cap=group_cap, out_layouts=out_layouts,
                       pieces=pieces):
@@ -769,7 +792,8 @@ class TpuMeshAggregateExec(_MeshStage):
             t0 = _time.perf_counter_ns()
             with self.section("spmd", exchange_bytes=xbytes,
                               exchange_cap=group_cap or cap,
-                              update_chunks=pieces) as span:
+                              update_chunks=pieces,
+                              update_chunks_live=pieces_live) as span:
                 res = fn(*global_cols, cnt_in)
                 # known once the program is traced: how its aggregates
                 # lower (float_sums_fixed, row_scatters)

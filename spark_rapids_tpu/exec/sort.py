@@ -119,7 +119,7 @@ class TpuSortExec(TpuExec):
         from .base import cached_pipeline
 
         fn = cached_pipeline(self._jits, key, "sort",
-                             lambda: jax.jit(run))
+                             lambda: jax.jit(run), per_instance=True)
         vals = fn(
             vals_of_batch(batch), count_scalar(batch.num_rows_lazy))
         return batch_from_vals(

@@ -124,7 +124,7 @@ class TpuWindowExec(TpuExec):
         from .base import cached_pipeline
 
         fn = cached_pipeline(self._jits, key, "window",
-                             lambda: jax.jit(run))
+                             lambda: jax.jit(run), per_instance=True)
         with self.op_timed():
             vals = fn(
                 vals_of_batch(batch), count_scalar(batch.num_rows_lazy))

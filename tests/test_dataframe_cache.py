@@ -307,18 +307,16 @@ def test_a_served_query_has_a_serve_span_and_no_scan_span(traced):
     (spmd,) = _named(second, "TpuMeshAggregateExec.spmd")
     # 4 shards x (4 blocks x 4096 rows x (5 + 9 + 9 + 9) bytes + counts)
     assert spmd[3]["exchange_bytes"] == 4 * (4 * 4096 * 32 + 16)
-    assert spmd[3]["update_chunks"] == 1
+    assert spmd[3]["update_chunks"] == spmd[3]["update_chunks_live"] == 1
     assert {"TpuMeshAggregateExec.emit", "TpuSession.plan",
             "TpuSession.query", "ColumnarToRowExec.d2h"} <= names
 
 
-def test_the_spmd_program_carries_the_one_chip_scope_words(table):
-    """``agg_update`` and ``agg_merge`` as the one-chip programs have
-    them, ``mesh_exchange`` around the collective, in the lowering text of
-    the program a cached query runs."""
+def _mesh_agg_text(query, directory, conf, text_of):
+    """``text_of(the mesh aggregate's jitted program, its arguments)`` at
+    the dispatch of the cell's query."""
     from spark_rapids_tpu.exec import mesh as XM
 
-    directory, _, query, _ = table
     texts = {}
     real = XB.cached_pipeline
 
@@ -327,8 +325,8 @@ def test_the_spmd_program_carries_the_one_chip_scope_words(table):
         fn = got[0] if isinstance(got, tuple) else got
 
         def call(*call_args):
-            texts.setdefault(site, fn.lower(*call_args).as_text(
-                debug_info=True))
+            if site not in texts:
+                texts[site] = text_of(fn, call_args)
             return fn(*call_args)
 
         return (call,) + tuple(got[1:]) if isinstance(got, tuple) else call
@@ -336,13 +334,23 @@ def test_the_spmd_program_carries_the_one_chip_scope_words(table):
     XM._PROGRAM_CACHE.clear()
     XB.cached_pipeline = spy
     try:
-        sess = TpuSession(MESH4)
+        sess = TpuSession(conf)
         query.frame(sess, directory).collect()
         _close(sess)
     finally:
         XB.cached_pipeline = real
         XM._PROGRAM_CACHE.clear()
-    text = texts["mesh_agg"]
+    return texts["mesh_agg"]
+
+
+def test_the_spmd_program_carries_the_one_chip_scope_words(table):
+    """``agg_update`` and ``agg_merge`` as the one-chip programs have
+    them, ``mesh_exchange`` around the collective, in the lowering text of
+    the program a cached query runs."""
+    directory, _, query, _ = table
+    text = _mesh_agg_text(
+        query, directory, MESH4,
+        lambda fn, args: fn.lower(*args).as_text(debug_info=True))
     assert re.search(r"module @jit_mesh_agg\b", text)
     for scope in ("fused_chain", "agg_update", "agg_merge", "project"
                   ) + XB.MESH_SCOPE_WORDS:
@@ -547,8 +555,10 @@ def test_a_shard_larger_than_the_chunk_is_updated_in_chunks(
     # 5 row groups of 4096 pad to 32768 slots a shard: 8 chunks
     assert agg.mesh_actuals["staging"]["cap"] == 32768
     assert agg.mesh_actuals["update_chunks"] == 8
+    # 5, 5, 4 and 4 of them hold a row; the other 14 skip their update
+    assert agg.mesh_actuals["update_chunks_live"] == 18
     assert agg.mesh_actuals["exchange_cap"] == 128
-    # every chunk's 128 partial rows cross unmerged
+    # every chunk's 128 partial rows cross unmerged, a skipped chunk's too
     assert agg.mesh_actuals["exchange_bytes"] == 4 * (
         4 * 8 * 128 * 32 + 16)
     _held(query.frame(sess, directory).collect(), want, query)
@@ -584,6 +594,36 @@ def test_a_chunk_with_more_groups_than_the_cap_retries(
 # the report's float sum as fixed-point limbs (PR 31): the chip's lowering,
 # forced here, and the counts that say which lowering a program took
 # ---------------------------------------------------------------------------
+def test_clearing_reaches_a_module_cache_behind_many_instance_caches():
+    """``clear_pipeline_caches()`` before a forced lowering has to empty
+    the mesh stage's program cache whatever ran in the process before. The
+    registry it sweeps is bounded for the per-instance caches of sort,
+    window, join and exchange; a file that built 64 of them used to shut
+    out a module's cache that came later, and the tests below were then
+    served the other lowering's program (PR 32's tier-1 run)."""
+    held = list(XB._ALL_PIPELINE_CACHES)
+    cap = XB._PIPELINE_CACHE_REGISTRY_CAP
+    of_instances = [{} for _ in range(cap + 1)]
+    late = {}
+
+    def build():
+        return lambda: None
+
+    try:
+        for c in of_instances:
+            XB.cached_pipeline(c, "k", None, build, per_instance=True)
+        assert len(XB._ALL_PIPELINE_CACHES) == max(cap, len(held))
+        XB.cached_pipeline(late, "k", None, build)
+        assert late and XB.clear_pipeline_caches() >= 1
+        assert late == {}
+        # the instance cache the full registry turned away keeps its entry
+        assert of_instances[-1] != {}
+    finally:
+        XB._ALL_PIPELINE_CACHES[:] = held
+        XB._ALL_PIPELINE_CACHE_IDS.clear()
+        XB._ALL_PIPELINE_CACHE_IDS.update(id(c) for c in held)
+
+
 def _one_traced_query(query, directory, out):
     """The cell's query once, cached, on the 4-device mesh under the CPU
     profiler: (rows, the mesh aggregate, its spans by name)."""
@@ -632,6 +672,8 @@ def test_the_mesh_report_says_how_its_float_sum_lowers(
     _held(rows, want, query, limit=1e-12)
     assert agg.mesh_actuals["update_chunks"] == 8
     (spmd,) = spans["TpuMeshAggregateExec.spmd"]
+    assert spmd["update_chunks"] == 8 and spmd["update_chunks_live"] == 18
+    assert spmd["exchange_bytes"] == 4 * (4 * 8 * 128 * 32 + 16)
     (pull,) = spans["TpuMeshAggregateExec.overflow_pull"]
     if lowering == "matmul":
         assert spmd["float_sums_fixed"] == 1 and spmd["row_scatters"] == 0
@@ -686,3 +728,117 @@ def test_a_detour_on_a_shard_is_counted(table, small_chunks, monkeypatch):
             assert g[1] == np.inf
         else:
             assert abs(g[1] - w[1]) <= 1e-12 * abs(w[1])
+
+
+# ---------------------------------------------------------------------------
+# a chunk with no live row skips its update (PR 33)
+# ---------------------------------------------------------------------------
+#: rows, rows a row group -> (slots a shard, chunks of 4096 that hold a row).
+#: Row groups go to the four shards in turn
+FILLS = {
+    # 4 full row groups a shard
+    "every_chunk_live": (16 * 4096, 4096, 16384, 16),
+    # the ``table`` fixture's: 5, 5 (the last short), 4 and 4 of 8
+    "a_last_live_chunk_partly_filled": (70000, 4096, 32768, 18),
+    # 2, 1, 1 and 1 of 2
+    "one_live_chunk": (5 * 4096, 4096, 8192, 5),
+    # three row groups for four shards: 2, 2, 1 (of 3000 rows) and 0 of 2
+    "a_shard_with_no_row": (2 * 8192 + 3000, 8192, 8192, 5),
+}
+
+
+@pytest.fixture(scope="module")
+def filled(cell, tmp_path_factory):
+    """fill -> (directory, path), made once for both lowerings."""
+    made = {}
+
+    def make(fill):
+        if fill not in made:
+            rows, row_group = FILLS[fill][:2]
+            directory = str(tmp_path_factory.mktemp(fill))
+            made[fill] = directory, cell["generator"].generate(
+                cell["config"], SEED + 33, directory, rows, row_group)
+        return made[fill]
+
+    return make
+
+
+def _chunked_and_whole(query, directory, monkeypatch):
+    """The cell's query with the update in chunks of 4096 slots, then with
+    the engine's chunk (above the shard: one piece): rows and aggregate
+    of each."""
+    from spark_rapids_tpu.exec import mesh as XM
+
+    whole = XM.AGG_UPDATE_CHUNK_ROWS
+    out = []
+    for chunk in (4096, whole):
+        monkeypatch.setattr(XM, "AGG_UPDATE_CHUNK_ROWS", chunk)
+        sess = TpuSession(dict(MESH4, **SMALL_CAP))
+        rows = query.frame(sess, directory).collect()
+        assert sess.plan_fallbacks() == []
+        out.append((rows, sess.last_executed_plan.tpu_child))
+        _close(sess)
+    return out
+
+
+@pytest.mark.parametrize("lowering", ["matmul", "scatter"])
+@pytest.mark.parametrize("fill", sorted(FILLS))
+def test_skipping_empty_chunks_gives_the_one_piece_answer(
+        cell, filled, fill, lowering, monkeypatch):
+    from spark_rapids_tpu.ops import bucket_reduce as BR
+
+    query = cell["queries"][0]
+    directory, path = filled(fill)
+    cap, live = FILLS[fill][2:]
+    monkeypatch.setattr(BR, "FORCE_MATMUL", lowering == "matmul")
+    XB.clear_pipeline_caches()
+    try:
+        (chunked, agg), (whole, one) = _chunked_and_whole(
+            query, directory, monkeypatch)
+    finally:
+        XB.clear_pipeline_caches()
+    assert agg.mesh_actuals["staging"]["cap"] == cap
+    assert agg.mesh_actuals["update_chunks"] == cap // 4096
+    assert agg.mesh_actuals["update_chunks_live"] == live
+    assert one.mesh_actuals["update_chunks"] == 1
+    assert one.mesh_actuals["update_chunks_live"] == 1
+    assert (agg.mesh_actuals["float_sums_fixed"]
+            == one.mesh_actuals["float_sums_fixed"]
+            == (1 if lowering == "matmul" else 0))
+    assert agg.mesh_actuals["float_detour"] == 0
+    # keys, counts and integer sums exactly, the float sum to 1e-12
+    _held(chunked, whole, query, limit=1e-12)
+    _held(chunked, query.reference(path), query)
+
+
+@pytest.mark.parametrize("lowering", ["matmul", "scatter"])
+def test_a_chunks_update_sits_in_a_branch_of_the_compiled_program(
+        table, small_chunks, lowering, monkeypatch):
+    """No scatter and no matmul of a chunk's update outside the loop's
+    conditional, in the compiled text of the small program: by the name
+    stack (the branch comes before ``agg_update``) and by the computations
+    the compiler kept."""
+    from spark_rapids_tpu.ops import bucket_reduce as BR
+    from tpu_compile_asks import (
+        chunk_updates, computations_under_a_conditional, instructions_named)
+
+    directory, _, query, _ = table
+    monkeypatch.setattr(BR, "FORCE_MATMUL", lowering == "matmul")
+    XB.clear_pipeline_caches()
+    try:
+        text = _mesh_agg_text(
+            query, directory, dict(MESH4, **SMALL_CAP),
+            lambda fn, args: fn.lower(*args).compile().as_text())
+    finally:
+        XB.clear_pipeline_caches()
+    update, outside = chunk_updates(text)
+    kinds = {"dot_general" if "dot_general" in n else "scatter"
+             for _, n in update}
+    # MATMUL keeps scatters in its hash tiers' own branches
+    assert kinds == ({"dot_general", "scatter"} if lowering == "matmul"
+                     else {"scatter"}), kinds
+    assert outside == [], outside
+    # the merge of the chunks' partials is outside it
+    under = computations_under_a_conditional(text)
+    merge = instructions_named(text, r"/agg_merge/.*(scatter|dot_general)")
+    assert merge and not all(c in under for c, _ in merge)

@@ -8,8 +8,10 @@ import numpy as np
 import jax
 
 from tpu_compile_asks import (  # noqa: F401  (fixtures)
-    HBM_BYTES, compile_mesh_program_for_four_chips, load_cell,
-    no_persistent_cache, row_sized_scatters, topo)
+    HBM_BYTES, IN_CHUNK_BRANCH, chunk_updates,
+    compile_mesh_program_for_four_chips, computations_under_a_conditional,
+    instructions_named, load_cell, no_persistent_cache, row_sized_scatters,
+    topo)
 
 #: slots a shard of ``tpcds_sf100_store_sales_mesh4``: up to 73.4 M rows padded
 #: to a power of two
@@ -77,7 +79,23 @@ def test_mesh_aggregate_compiles_at_sf100_shard_capacity(
     # path; those that remain sit in a branch of a conditional (the float
     # detour, the hash and sort tiers). The exchange places its rows by
     # scatter under its own scope word: not the aggregate's
-    walks = [w for w in row_sized_scatters(compiled.as_text(), 1 << 16)
+    text = compiled.as_text()
+    walks = [w for w in row_sized_scatters(text, 1 << 16)
              if "/agg_update/" in w[1] or "/agg_merge/" in w[1]]
     assert {n for n, _ in walks} == {XM.AGG_UPDATE_CHUNK_ROWS, 4 << 16}, walks
     assert [w for w in walks if "/cond/branch_" not in w[1]] == [], walks
+    # a chunk of a shard with no live row skips its update (PR 33): 7 of
+    # the cell's 16. The whole update of a chunk, the row-sized walks of
+    # its hash tiers and the limb matmul over its 2^23 slots with them,
+    # sits in a branch of the loop's conditional, by its name stack and in
+    # the compiled program's own computations
+    assert [w for w in walks if "/agg_update/" in w[1]
+            and not IN_CHUNK_BRANCH.search(w[1])] == [], walks
+    update, outside = chunk_updates(text)
+    assert any(n.endswith("/agg_update/brl,brB->blB/dot_general")
+               for _, n in update), update  # the direct tier's limb matmul
+    assert outside == [], outside
+    # the merge of the exchanged partials runs whatever the chunks held
+    under = computations_under_a_conditional(text)
+    assert [c for c, _ in instructions_named(
+        text, r"/agg_merge/brl,brB->blB/dot_general") if c in under] == []

@@ -170,3 +170,66 @@ def row_sized_scatters(text, rows):
         if n >= rows:
             found.append((n, name.group(1) if name else ""))
     return found
+
+
+def _by_computation(text):
+    """(computation, line) of every instruction line of a compiled
+    program's text."""
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+        elif name is not None:
+            yield name, line
+
+
+def computations_under_a_conditional(text):
+    """Names of the computations of a compiled program's text that run
+    only inside a branch of a ``conditional``: the branch computations
+    and whatever they call (fusions, loops, reducers, nested branches)."""
+    calls, branches = {}, set()
+    for name, line in _by_computation(text):
+        taken = re.findall(
+            r"(?:true_computation|false_computation)=%?([\w.-]+)", line)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            taken += [b.strip().lstrip("%") for b in group.split(",")]
+        branches.update(taken)
+        calls.setdefault(name, set()).update(taken, re.findall(
+            r"(?:calls|to_apply|body|condition)=%?([\w.-]+)", line))
+    under, todo = set(), list(branches)
+    while todo:
+        c = todo.pop()
+        if c not in under:
+            under.add(c)
+            todo.extend(calls.get(c, ()))
+    return under
+
+
+def instructions_named(text, pattern):
+    """(computation, op_name) of every instruction of a compiled program's
+    text whose ``op_name`` (its name stack) matches ``pattern``."""
+    found = []
+    for name, line in _by_computation(text):
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if op_name and re.search(pattern, op_name.group(1)):
+            found.append((name, op_name.group(1)))
+    return found
+
+
+#: the name stack of an operation of a chunk's update in the mesh
+#: aggregate's loop (``exec/mesh.chunked_partials``): the branch of the
+#: loop's conditional comes before ``agg_update``
+IN_CHUNK_BRANCH = re.compile(
+    r"\bwhile/body/.*\bcond/branch_\d+_fun/agg_update/")
+
+
+def chunk_updates(text):
+    """The scatters and matmuls of the mesh aggregate's update in a
+    compiled program's text, and those of them that do NOT sit in the
+    branch a chunk with no live row skips: by the name stack, and by the
+    computations the compiler kept under a conditional."""
+    update = instructions_named(text, r"/agg_update/.*(scatter|dot_general)")
+    under = computations_under_a_conditional(text)
+    return update, [(c, n) for c, n in update
+                    if c not in under or not IN_CHUNK_BRANCH.search(n)]
