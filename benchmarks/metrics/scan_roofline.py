@@ -1,10 +1,12 @@
 """The traced slice's share of the HBM roofline: the least time the chip
 could take to read the bytes the slice's queries need (each query's
 ``needed_bytes`` from the schema's widths and the rows scanned, over the
-chip's peak HBM bytes/s) over the time the device was busy. Memory-bound by
-construction: the queries here move bytes, their arithmetic is nothing
-beside the chip's FLOP/s. A reader that finds no device time returns
-nothing."""
+published peak HBM bytes/s of the chips traced) over the time a chip was
+busy (``busy_s`` is averaged over the chips, so four chips' bytes stand
+against four chips' peak: the whole query's share, where
+``mesh_agg_roofline`` is the one program's). Memory-bound by construction:
+the queries here move bytes, their arithmetic is nothing beside the chip's
+FLOP/s. A reader that finds no device time returns nothing."""
 NAME = "scan_roofline"
 UNIT = "%"
 
@@ -18,5 +20,5 @@ def read(ctx):
                  for qi in trace["query_indices"])
     if not needed:
         return None
-    least_s = needed / (peaks["hbm_GB/s"] * 1e9)
+    least_s = needed / (trace["chips_traced"] * peaks["hbm_GB/s"] * 1e9)
     return 100.0 * least_s / trace["busy_s"]

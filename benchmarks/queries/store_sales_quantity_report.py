@@ -1,6 +1,7 @@
 """``where ss_sold_date_sk >= 2452015 group by ss_quantity:
-sum(ss_wholesale_cost), sum(ss_quantity), count(ss_item_sk)`` — the query
-of ``chip_smoke.py``, on the ``tpcds_sf10_store_sales`` deployment."""
+sum(ss_wholesale_cost), sum(ss_quantity), count(ss_item_sk)`` — the first
+query that ran on the chip (PR 22's smoke run), on the
+``tpcds_sf10_store_sales`` deployment."""
 from __future__ import annotations
 
 TABLE = "store_sales.parquet"

@@ -197,9 +197,9 @@ def run_window(driver: Driver, seconds: float, tracer=None,
 
 
 def placement_check(sess, platform: str) -> dict:
-    """chip_smoke.py's check, once, after the window: the plan's root is a
-    device subtree, its final batch's planes and every array the process
-    still holds live on ``platform``."""
+    """Where the answer was made, once, after the window: the plan's root
+    is a device subtree, its final batch's planes and every array the
+    process still holds live on ``platform``."""
     import jax
 
     from spark_rapids_tpu.exec.transitions import ColumnarToRowExec

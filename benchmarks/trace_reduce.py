@@ -35,9 +35,9 @@ SLICE_MARK = "bench.slice"
 QUERY_MARK = "bench.query"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
-#: the engine names an exec's hot section <NodeName>[.<section>]
-#: (exec/base.py op_timed): TpuHashAggregateExec.stage, ...
-EXEC_SPAN = re.compile(r"^(Tpu|Cpu)\w+Exec(\.\w+)?$")
+#: the engine names an exec's hot section <NodeName>[.<section>[.<part>]]
+#: (exec/base.py op_timed): TpuHashAggregateExec.stage, ...merge.concat
+EXEC_SPAN = re.compile(r"^(Tpu|Cpu)\w+Exec(\.\w+)*$")
 BETWEEN = "between queries"
 IN_QUERY = "collect() outside any exec span"
 UNATTRIBUTED = "unattributed"

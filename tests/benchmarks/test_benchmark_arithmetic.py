@@ -141,6 +141,35 @@ def test_reduce_busy_idle_ops_and_gap_attribution():
     assert 100 * (1 - r["busy_s"] / r["window_s"]) == pytest.approx(65.0)
 
 
+def test_idle_under_a_dotted_section_goes_to_its_innermost_part():
+    """``TpuHashAggregateExec.merge`` names its parts ``merge.<part>``: an
+    idle gap under one goes to the part, what no part covers stays under
+    ``merge``."""
+    ms = 1e6
+    merge = "TpuHashAggregateExec.merge"
+    for name in (merge, merge + ".concat", "CpuFooExec.a.b.c", "TpuXExec"):
+        assert TR.EXEC_SPAN.match(name), name
+    for name in ("TpuSession.query", "PjitFunction(run)", merge + ".",
+                 merge + "..concat", merge + ".concat x", "Exec.merge"):
+        assert not TR.EXEC_SPAN.match(name), name
+    planes = [{"name": "/host:CPU", "lines": [{"name": "python", "events": [
+        (TR.SLICE_MARK, 0.0, 100 * ms), (TR.QUERY_MARK, 0.0, 100 * ms),
+        (merge, 10 * ms, 80 * ms),
+        (merge + ".concat", 10 * ms, 30 * ms),   # starts with its parent
+        (merge + ".lengths", 50 * ms, 20 * ms),
+        ("TpuSession.plan", 2 * ms, 3 * ms)]}]}]  # no exec span
+    spans = TR.host_spans(planes)
+    assert len(spans) == 5
+    got = TR.attribute([(0.0, 100 * ms)], spans)
+    assert got == pytest.approx({
+        merge + ".concat": 0.030, merge + ".lengths": 0.020,
+        merge: 0.030,  # [40,50) and [70,90)
+        TR.IN_QUERY: 0.020})
+    # a gap that crosses a part's edge is cut there
+    assert TR.attribute([(35 * ms, 55 * ms)], spans) == pytest.approx({
+        merge + ".concat": 0.005, merge: 0.010, merge + ".lengths": 0.005})
+
+
 def test_reduce_without_marks_or_device():
     planes = _planes()
     planes[1]["lines"][0]["events"] = []

@@ -5,7 +5,6 @@ new files only."""
 import json
 import os
 import re
-import shutil
 
 import pytest
 
@@ -25,8 +24,7 @@ def _devices():
 @pytest.mark.parametrize("cell", CELLS)
 def test_benchmark_json_names_the_files(cell):
     """BENCHMARK.json and the files the harness finds by name agree."""
-    with open(os.path.join(lib.ROOT, "BENCHMARK.json")) as f:
-        spec = json.load(f)
+    spec = lib.load_spec()
     entry = next(w for w in spec["workloads"] if w["name"] == cell)
     bench = loader.load_cell(cell)
     assert bench["cell"]["config"] == entry["config"]
@@ -194,63 +192,19 @@ def test_a_query_that_raises_counts_as_failed(monkeypatch):
 
 # -- a new configuration, cell, query and metric are new files only --------
 def test_new_config_cell_query_and_metric_are_new_files_only(tmp_path):
-    root = str(tmp_path / "benchmarks")
-    shutil.copytree(lib.BENCH, root, ignore=shutil.ignore_patterns(
-        ".cache", ".scratch", "__pycache__"))
-    before = {os.path.join(d, f): os.path.getmtime(os.path.join(d, f))
-              for d, _, fs in os.walk(root) for f in fs}
-    with open(os.path.join(root, "configs", "dummy_table.json"), "w") as f:
-        json.dump({
-            "name": "dummy_table", "source": "a test", "chips": 1,
-            "rows": 4096, "row_group_rows": 1024,
-            "columns": [{"name": "k", "type": "int32", "width_bytes": 4},
-                        {"name": "v", "type": "int32", "width_bytes": 4}],
-            "conf": {}, "reduced": [], "assumed": [],
-            "rehearse": {"rows": 4096, "row_group_rows": 1024}}, f)
-    with open(os.path.join(root, "configs", "dummy_table.py"), "w") as f:
-        f.write(
-            "import numpy as np\n"
-            "def generate(config, seed, out_dir, rows, row_group):\n"
-            "    import pyarrow as pa\n"
-            "    from datagen import write_parquet\n"
-            "    rng = np.random.default_rng(seed)\n"
-            "    t = pa.table({'k': pa.array(rng.integers(0, 5, rows,"
-            " dtype=np.int32)), 'v': pa.array(rng.integers(0, 9, rows,"
-            " dtype=np.int32))})\n"
-            "    return write_parquet(t, out_dir, 'dummy.parquet',"
-            " row_group)\n")
-    with open(os.path.join(root, "queries", "dummy_sum.py"), "w") as f:
-        f.write(
-            "COLUMNS = ('k', 's')\nKEYS = (0,)\nEXACT = (1,)\nFLOAT = ()\n"
-            "ORDERED = False\nFLOAT_LIMIT = 0.0\n"
-            "def frame(sess, data_dir):\n"
-            "    from spark_rapids_tpu.expr import aggregates as A\n"
-            "    from spark_rapids_tpu.expr.expressions import col\n"
-            "    return (sess.read.parquet(data_dir).group_by('k')"
-            ".agg(A.agg(A.Sum(col('v')), 's')))\n"
-            "def reference(path, float_dtype='float64'):\n"
-            "    import pandas as pd\n"
-            "    g = pd.read_parquet(path).groupby('k').v.sum()\n"
-            "    return [(int(k), int(s)) for k, s in g.items()]\n"
-            "def needed_bytes(config):\n"
-            "    return int(config['rows']) * 8\n"
-            "def rows_scanned(config):\n"
-            "    return int(config['rows'])\n")
-    with open(os.path.join(root, "workloads", "dummy.sum.json"), "w") as f:
-        json.dump({"name": "dummy.sum", "config": "dummy_table",
-                   "traffic": "sum", "queries": ["dummy_sum"],
-                   "loop": "closed", "clients": 1,
-                   "compile_misses_per_query_at_most": 0,
-                   "why": "a test"}, f)
-    with open(os.path.join(root, "metrics", "dummy_metric.py"), "w") as f:
-        f.write("NAME = 'dummy_metric'\nUNIT = 'count'\n"
-                "def read(ctx):\n"
-                "    return ctx['counters']['window_queries']\n")
-    assert "dummy_metric" in loader.load_metrics(root)
-    result = bench_run.execute(lib.rehearse_args("dummy.sum", trace=1),
+    """The harness's side of the open door (the contract's side, without
+    the engine: ``test_benchmark_contract.py``): the grown copy passes
+    every rule, and ``run.py`` answers the new cell from it."""
+    import benchmark_contract as contract
+
+    root = lib.copy_benchmarks(tmp_path)
+    before = lib.file_mtimes(root)
+    lib.add_dummy_files(root)
+    contract.check_contract(lib.grown_spec(lib.load_spec()), root)
+    assert lib.DUMMY_METRIC in loader.load_metrics(root)
+    result = bench_run.execute(lib.rehearse_args(lib.DUMMY_CELL, trace=1),
                                _devices(), bench_root=root)
     assert result["answers_correct"] is True and result["attempted"] >= 2
     # and the cells that were there still load from the copy, untouched
     assert loader.load_cell(CELLS[0], root)["config"]["rows"] == 28_800_991
-    after = {p: os.path.getmtime(p) for p in before}
-    assert after == before
+    assert lib.touched_since(before) == []

@@ -5,18 +5,19 @@ rehearsal on four virtual CPU devices, the parent's failure mode (a
 planes built by hand and on the trace of its own rehearsal."""
 import json
 import os
-import shutil
 import time
 
 import numpy as np
 import pytest
 
+import benchmark_contract as contract
 import benchmark_testlib as lib
 import compare
 import loader
 import run as bench_run
 import trace_mesh
 import trace_programs as TP
+import trace_reduce as TR
 
 CELL = "store_sales_sf100.cached_report.mesh4"
 CONFIG = "tpcds_sf100_store_sales_mesh4"
@@ -35,60 +36,25 @@ def _devices():
     return jax.devices()
 
 
-def _spec():
-    with open(os.path.join(lib.ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
 # ---------------------------------------------------------------------------
 # the contract and the files found by name
 # ---------------------------------------------------------------------------
-def test_benchmark_json_names_the_cells_files():
-    spec = _spec()
-    entry = next(w for w in spec["workloads"] if w["name"] == CELL)
-    assert spec["workloads"][-1] is entry  # appended, nothing moved
-    bench = loader.load_cell(CELL)
-    assert bench["cell"]["config"] == entry["config"] == CONFIG
-    assert bench["cell"]["traffic"] == entry["traffic"] == "cached_report"
-    assert bench["config"]["chips"] == entry["chips"] == 4
-    assert bench["cell"]["why"] == entry["why"] and len(entry["why"]) <= 200
-    assert bench["query_names"] == [QUERY]
-    assert bench["cell"]["compile_misses_per_query_at_most"] == 0
-    cfg = spec["configs"][-1]
-    assert cfg["name"] == CONFIG
-    assert cfg["file"] == f"benchmarks/configs/{CONFIG}.json"
-    assert cfg["reduced"] == bench["config"]["reduced"] == ["columns"]
-    assert cfg["source"] == bench["config"]["source"]
-    assert len(cfg["source"]) <= 200 and len(cfg["why"]) <= 200
-    # four chips for one cell of three: within the contract's half
-    assert [w["chips"] for w in spec["workloads"]] == [1, 1, 4]
+def test_the_cell_and_its_configuration_are_there_as_accepted():
+    """Wherever they stand in ``BENCHMARK.json``: what may be appended
+    after them is ``test_benchmark_contract.py``'s to hold."""
+    assert (CELL, CONFIG, [QUERY]) == (
+        contract.MESH4["cell"], contract.MESH4["config"],
+        contract.MESH4["queries"])
+    contract.check_mesh4_cell(lib.load_spec(), lib.BENCH)
 
 
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
-def test_each_new_metric_has_a_reader_and_names_the_cell(name):
-    spec = _spec()
-    entry = next(m for m in spec["per_layer"] if m["name"] == name)
-    reader = loader.load_metrics()[name]
-    assert reader.UNIT == entry["unit"] == NEW_METRICS[name]
-    assert entry["workloads"] == [CELL]
+def test_each_new_metric_names_the_cell(name):
+    entry = contract.entry_of(lib.load_spec()["per_layer"], name, "metric")
+    assert entry["unit"] == NEW_METRICS[name]
+    assert CELL in entry["workloads"]
     assert entry["moves"] == "rows_per_s"
     assert entry["layer"] in ("memory", "mesh", "kernels")
-    # nothing to read: nothing returned, nothing raised
-    assert reader.read({"trace": None, "counters": {}}) is None
-
-
-def test_metrics_with_nothing_to_read_here_list_the_cells_that_have():
-    """Accepted metrics that read a file scan or the one-chip aggregate's
-    merge find nothing in a window served from resident planes: they list
-    the cells they read, the others are reported in the new cell too."""
-    spec = _spec()
-    listed = {m["name"]: m["workloads"] for m in spec["per_layer"]
-              if "workloads" in m and m["name"] not in NEW_METRICS}
-    assert listed == {name: ["store_sales.quantity_report", "lineitem.q1"]
-                      for name in (
-        "scan_host_ms_per_query", "host_fallback_columns",
-        "scan_cache_hit_share", "merge_host_ms_per_query",
-        "decode_gathers_per_query")}
 
 
 def test_the_configuration_states_the_deployment():
@@ -285,10 +251,12 @@ def _span(name, start_ms, dur_ms, **stats):
     return (name, start_ms * MS, dur_ms * MS, stats)
 
 
-def _mesh_planes(chips=4, served=True, names=True):
+def _mesh_planes(chips=4, served=True, names=True, row_scatters=None):
     """A slice of 100 ms, two queries on ``chips`` chips, each running
     ``jit_mesh_agg`` for 40 ms: 30 ms of update in a loop, 2 ms under
-    ``mesh_exchange``, 6 ms of merge, 2 ms outside every scope."""
+    ``mesh_exchange``, 6 ms of merge, 2 ms outside every scope. The
+    ``spmd`` spans carry ``row_scatters`` where one is given (PR 31's
+    count: the parent of that PR has none)."""
     prog = "jit(mesh_agg)/jit(main)/jit(shmap_body)/"
     planes = []
     for chip in range(chips):
@@ -326,7 +294,9 @@ def _mesh_planes(chips=4, served=True, names=True):
                   h2d_bytes=0 if hit else 24000, source="cached",
                   shards=chips, shard_rows_max=280, shard_rows_sum=1000),
             _span(agg + ".spmd", q0 + 7, 1, query=qid,
-                  exchange_bytes=80_000_000, exchange_cap=4096)]
+                  exchange_bytes=80_000_000, exchange_cap=4096,
+                  **({} if row_scatters is None
+                     else {"row_scatters": row_scatters}))]
     planes.append({"name": "/host:CPU", "lines": [
         {"name": "python", "events": events}]})
     return planes
@@ -372,6 +342,50 @@ def test_readers_on_a_window_served_from_resident_planes():
     assert got["mesh_shard_rows_skew"] == pytest.approx(280 / 250)
     for name in ("exchange_roofline", "mesh_agg_roofline"):
         assert 0 < got[name] < 100, name
+
+
+@pytest.mark.parametrize("planted,reads", [
+    (None, None),  # a program from before the count: left out of the line
+    (0, 0.0),      # every aggregate in the limb matmul: a 0 that is read
+    (3, 3.0),      # a scatter float sum and a min/max family came back
+])
+def test_row_scatters_are_the_spmd_spans_count_over_the_queries(
+        planted, reads):
+    reader = loader.load_metrics()["agg_row_scatters_per_query"]
+    assert (reader.NAME, reader.UNIT) == ("agg_row_scatters_per_query",
+                                          "count")
+    got = reader.read(_ctx(_mesh_planes(row_scatters=planted)))
+    assert got == reads and (got is None) == (reads is None)
+    # one chip, or no engine names: no such span, nothing read
+    assert reader.read(_ctx(_mesh_planes(names=False, row_scatters=0))) \
+        is None
+    entry = contract.entry_of(lib.load_spec()["per_layer"], reader.NAME,
+                              "metric")
+    assert CELL in entry["workloads"] and entry["better"] == "lower"
+    assert (entry["layer"], entry["moves"], entry["source"]) == (
+        "fused stage and groupby ops", "rows_per_s", "program_counter")
+
+
+@pytest.mark.parametrize("chips", [1, 2, 4])
+def test_scan_roofline_is_against_the_peak_of_the_chips_traced(chips):
+    """Four chips' bytes over four chips' peak: a mesh cell's reading can
+    pass 100% only where one chip's could."""
+    readers = loader.load_metrics()
+    planes = _mesh_planes(chips=chips)
+    ctx = _ctx(planes)
+    # busy seconds and the count of chips as the reduction itself gives them
+    ctx["trace"].update(TR.reduce_planes(
+        [dict(p, lines=[dict(line, events=[ev[:3] for ev in line["events"]])
+                        for line in p["lines"]]) for p in planes]))
+    assert ctx["trace"]["chips_traced"] == chips
+    assert ctx["trace"]["busy_s"] == pytest.approx(0.08)  # a chip's own
+    # 2 queries x 20,000 bytes over the chips' 819 GB/s each
+    got = readers["scan_roofline"].read(ctx)
+    assert got == pytest.approx(100 * (40_000 / (chips * 819e9)) / 0.08)
+    # the whole query's share beside the kernel's: here every busy second
+    # is mesh_agg's, so the two agree; one chip's peak for four chips'
+    # bytes would read four times that
+    assert got == pytest.approx(readers["mesh_agg_roofline"].read(ctx))
 
 
 def test_a_query_that_fills_is_not_served_from_residency():
@@ -434,9 +448,7 @@ def test_ici_peaks_name_the_kinds_peaks_json_has():
 # the readers, on the trace of the cell's own rehearsal
 # ---------------------------------------------------------------------------
 def test_readers_on_the_rehearsals_trace(tmp_path):
-    root = str(tmp_path / "benchmarks")
-    shutil.copytree(lib.BENCH, root, ignore=shutil.ignore_patterns(
-        ".cache", ".scratch", "__pycache__"))
+    root = lib.copy_benchmarks(tmp_path)
     result = bench_run.execute(lib.rehearse_args(CELL, trace=1), _devices(),
                                bench_root=root)
     assert result["answers_correct"] is True and result["metrics"] == {}
