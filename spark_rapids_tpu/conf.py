@@ -233,21 +233,20 @@ AGG_STRATEGY = conf(
     "(ops/bucket_reduce.py, ops/groupby.py, ops/radix_bin.py). MATMUL "
     "prices sums/counts as one-hot limb matmuls on the MXU over the "
     "hash-bucket tiers; SCATTER uses native segment scatters over the "
-    "same tiers; SORT radix-sorts rows by the grouping keys and reduces "
-    "each contiguous segment as prefix-sum differences (float sums and "
-    "min/max keep the scatter path); RADIX reduces EVERY aggregate "
+    "same tiers; RADIX reduces EVERY aggregate "
     "family over the radix-binned order in HBM-resident tiles — zero "
     "scatter instructions and no one-hot, so bytes-accessed approaches "
     "the layout bound; PALLAS runs the hash-groupby update as "
     "hand-written jax.experimental.pallas TPU kernels over the "
     "hash-bucket tiers (interpret mode executes the same kernels "
-    "off-TPU). AUTO picks per plan from the static layout (capacity, "
-    "aggregated column count/widths, backend) against the conf-declared "
-    "roofline peaks (spark.rapids.tpu.roofline.peakHbmGBps/.peakTflops) "
-    "and records its choice — with the reason — in explain_metrics() "
+    "off-TPU). AUTO picks per plan from what it can observe, the backend "
+    "and the capacity: MATMUL on an accelerator (the lowering the v5e "
+    "compiler takes at every capacity; it refuses RADIX at small ones), "
+    "SCATTER on the CPU backend below capacity 2^21 and RADIX from there "
+    "on. It records its choice — with the reason — in explain_metrics() "
     "and the event log ('agg_strategy'), so a wrong prediction is "
     "visible in tools/tpu_profile.py instead of only as wall-clock.",
-    valid_values=("AUTO", "MATMUL", "SCATTER", "SORT", "RADIX", "PALLAS"))
+    valid_values=("AUTO", "MATMUL", "SCATTER", "RADIX", "PALLAS"))
 
 # ---------------------------------------------------------------------------
 # Memory (reference: RapidsConf.scala:200-340, GpuDeviceManager.scala:160-271)

@@ -12,6 +12,7 @@ columns), FINAL (merge buffer columns, evaluate results).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -43,27 +44,17 @@ _AGG_CACHE: dict = {}
 
 
 # ---------------------------------------------------------------------------
-# Aggregation strategy chooser (conf sql.agg.strategy). The cost model
-# reads the SAME roofline peaks the profiler's roofline report measures
-# against (spark.rapids.tpu.roofline.peakHbmGBps/.peakTflops, with
-# xla_cost.BACKEND_PEAKS per-backend defaults) — one peak source, so a
-# deployment that calibrates the conf moves the chooser and the report
-# together. The DERATE fractions below are calibrated from the r05
-# profile, not spec sheets: the profiled one-hot limb matmul ran at
-# ~7e11 MAC/s (143 ms for cap=2^26 x ~12 limbs x B=128) — ~0.7% of the
-# v5e MXU peak, because the one-hot compare-select feed, not the
-# multiply, is the bottleneck. That gap is exactly what makes the
-# bandwidth-sized lowerings competitive. Re-check on a TPU-backed round.
+# Aggregation strategy chooser (conf sql.agg.strategy). AUTO picks from
+# what the code can observe: the backend and the capacity bucket. On an
+# accelerator it resolves MATMUL and nothing else: the one lowering the
+# v5e compiler takes at every capacity the benchmark's cells run (it
+# refuses RADIX at capacity 256: "Scoped allocation with size 19.14M and
+# limit 16.00M" in the 64-bit ``cumsum`` of ops/radix_bin._tile_diffs),
+# which sums exact floats too, as fixed-point limbs (ops/bucket_reduce).
+# The roofline peaks below price the join chooser.
 # ---------------------------------------------------------------------------
-#: measured effective one-hot limb-matmul MAC rate / MXU peak MAC rate
-_MATMUL_PEAK_FRAC = 7.3e-3
-#: sustained streaming fraction of peak HBM bandwidth
+#: sustained streaming fraction of peak HBM bandwidth (exec/join.py)
 _HBM_DERATE = 0.6
-#: near-serial TPU scatter cost per row (why min/max batch per family)
-_SCATTER_SEC_PER_ROW = 10e-9
-#: first hash tier (ops/groupby.py B0) — the optimistic common-case
-#: matmul price; wider key ranges escalate tiers and multiply it
-_FIRST_TIER_B = 128
 #: CPU-backend AUTO: below this capacity the native scatter's serial
 #: walk is cheap and the radix sort dominates, so SCATTER keeps its
 #: round-1-measured win; at or above it the SCATTER dialect's byte
@@ -79,15 +70,14 @@ _RADIX_CPU_MIN_CAP = 1 << 21
 
 
 def _roofline_peaks(conf: RapidsConf, backend: str) -> Tuple[float, float]:
-    """(peak HBM bytes/s, peak MAC/s) for the chooser: the conf-declared
+    """(peak HBM bytes/s, peak MAC/s) for a chooser: the conf-declared
     roofline peaks when set, else the per-backend defaults — the same
     resolution order the roofline report uses."""
     from ..xla_cost import (BACKEND_PEAKS, ROOFLINE_PEAK_HBM_GBPS,
                             ROOFLINE_PEAK_TFLOPS)
 
     if backend not in BACKEND_PEAKS:
-        # a peak assumed for a device nobody measured prices both sides
-        # of the chooser wrong — refuse instead of borrowing the CPU row
+        # a backend nobody measured: refuse instead of borrowing a row
         raise ValueError(
             f"no roofline peaks for backend {backend!r} (known: "
             f"{sorted(BACKEND_PEAKS)}); the aggregation chooser cannot "
@@ -103,29 +93,25 @@ def choose_agg_strategy(
     cap: int,
     update_ops: Sequence[str],
     update_exprs: Sequence[Optional[E.Expression]],
-    key_dtypes: Sequence[T.DataType],
     backend: Optional[str] = None,
 ) -> Tuple[str, str]:
     """Pick the grouped-aggregation lowering for ONE plan shape from its
-    STATIC layout — capacity bucket, aggregated column count/widths, key
-    widths — never from data (the choice must be a trace-time constant or
-    it would churn the compile cache). Returns ``(strategy, reason)``;
-    the reason rides into explain_metrics and the 'agg_strategy' event so
-    a wrong prediction is debuggable offline. AUTO resolves:
+    STATIC layout — backend, capacity bucket, aggregated columns — never
+    from data (the choice must be a trace-time constant or it would churn
+    the compile cache). Returns ``(strategy, reason)``; the reason rides
+    into explain_metrics and the 'agg_strategy' event so a wrong
+    prediction is debuggable offline. AUTO resolves:
 
       * CPU backend -> SCATTER below _RADIX_CPU_MIN_CAP (native segment
         scatters; both the materialized one-hot and the bitonic sort
         lose there in wall clock, measured in round 1), RADIX at or
         above it — the scatter dialect's XLA-charged byte amplification
         dominates at scale and the merge gate is bytes, not the wall
-        clock of a shared box;
-      * otherwise the cheaper of MATMUL (cap x limbs x B MACs at the
-        derated peak MAC rate) and RADIX (bitonic radix-key sort passes
-        + one tile-resident bandwidth pass per reduced stream at the
-        derated peak HBM rate). Exact float sums without
-        variableFloatAgg keep RADIX out of AUTO (its stream split is
-        order-insensitive) and compare MATMUL against SORT instead,
-        whose float sums stay on the order-preserving scatter path.
+        clock of a shared box. Exact float sums without variableFloatAgg
+        keep RADIX out (its stream split is order-insensitive);
+      * an accelerator -> MATMUL, at every capacity and for every
+        aggregate: the lowering the chip is known to compile and to sum
+        right (see above).
     """
     from ..conf import AGG_STRATEGY, IMPROVED_FLOAT_OPS
 
@@ -134,71 +120,36 @@ def choose_agg_strategy(
         return mode, "forced by spark.rapids.tpu.sql.agg.strategy"
     if backend is None:
         backend = jax.default_backend()
-    approx = conf.get(IMPROVED_FLOAT_OPS)
-    n_int = n_cnt = n_fapprox = n_fexact = n_other = 0
-    for op, e in zip(update_ops, update_exprs):
-        floating = e is not None and getattr(e.dtype, "is_floating", False)
-        if op in ("count", "count_star"):
-            n_cnt += 1
-        elif op == "sum" and not floating:
-            n_int += 1
-            n_cnt += 1  # nullability count rides the same pass
-        elif op == "sum" and approx:
-            n_fapprox += 1
-            n_cnt += 1
-        elif op == "sum":
-            n_fexact += 1
-            n_cnt += 1
-        else:
-            n_other += 1  # min/max/first/last
+    if backend != "cpu":
+        from ..xla_cost import BACKEND_PEAKS
+
+        if backend not in BACKEND_PEAKS:
+            # a backend nobody ran: refuse instead of guessing a lowering
+            raise ValueError(
+                f"the aggregation chooser knows no backend {backend!r} "
+                f"(known: {sorted(BACKEND_PEAKS)}); AUTO resolves only a "
+                "lowering the backend is known to compile")
+        return ("MATMUL",
+                f"AUTO: {backend} backend — the one-hot limb matmul is "
+                "the lowering its compiler takes at every capacity (it "
+                "refuses RADIX's 64-bit cumsum at small ones)")
     # exact float sums demand the order-preserving scatter adds; RADIX's
     # NORMAL/BIG stream split is order-insensitive, so AUTO may only
     # pick it when the query opted into variableFloatAgg semantics
-    radix_ok = n_fexact == 0
-    if backend == "cpu":
-        if cap >= _RADIX_CPU_MIN_CAP and radix_ok:
-            return ("RADIX",
-                    "AUTO: CPU backend at cap>=2^21 — the scatter "
-                    "dialect's while-loop accumulator amplifies "
-                    "XLA-charged bytes ~25x past the layout bound "
-                    "(BENCH_r09); the tiled radix lowering is sized to "
-                    "the bound")
-        return ("SCATTER",
-                "AUTO: CPU backend — native segment scatters beat both "
-                "the materialized one-hot and the bitonic sort")
-    hbm_bps, mac_s = _roofline_peaks(conf, backend)
-    hbm_eff = _HBM_DERATE * hbm_bps
-    mac_eff = _MATMUL_PEAK_FRAC * mac_s
-    limbs = 8 * n_int + n_cnt + 2 * n_fapprox
-    matmul_s = cap * limbs * _FIRST_TIER_B / mac_eff
-    import math
-
-    lg = max(1, math.ceil(math.log2(max(2, cap))))
-    sort_passes = lg * (lg + 1) / 2  # bitonic compare-exchange rounds
-    from ..plugin.plananalysis import _storage_bytes
-
-    key_bytes = 0
-    for dt in key_dtypes:
-        try:
-            key_bytes += _storage_bytes(dt)
-        except Exception:  # strings etc: radix chunks, ~8B per pass
-            key_bytes += 8
-    key_bytes = key_bytes or 4
-    # every reduced stream is one tile-resident bandwidth pass under
-    # RADIX (winner sorts ride tile-local memory); under SORT min/max/
-    # first/last and float sums keep their scatter families, which
-    # cancel against the matmul side's identical scatters
-    bw_cols = n_int + n_fapprox + n_cnt + (n_other if radix_ok else 0)
-    bw_s = (cap * (key_bytes + 4) * sort_passes
-            + cap * 8 * max(1, bw_cols) * 3) / hbm_eff
-    bw_pick = "RADIX" if radix_ok else "SORT"
-    pick = bw_pick if bw_s < matmul_s else "MATMUL"
-    return (pick,
-            f"AUTO: est matmul {matmul_s * 1e3:.1f}ms "
-            f"({limbs} limbs x B={_FIRST_TIER_B}) vs {bw_pick.lower()} "
-            f"{bw_s * 1e3:.1f}ms ({sort_passes:.0f} passes, "
-            f"{bw_cols} stream(s)) at cap={cap}, "
-            f"peaks {hbm_bps / 1e9:.0f}GB/s {2 * mac_s / 1e12:.0f}TF")
+    exact_float_sum = not conf.get(IMPROVED_FLOAT_OPS) and any(
+        op == "sum" and e is not None
+        and getattr(e.dtype, "is_floating", False)
+        for op, e in zip(update_ops, update_exprs))
+    if cap >= _RADIX_CPU_MIN_CAP and not exact_float_sum:
+        return ("RADIX",
+                "AUTO: CPU backend at cap>=2^21 — the scatter "
+                "dialect's while-loop accumulator amplifies "
+                "XLA-charged bytes ~25x past the layout bound "
+                "(BENCH_r09); the tiled radix lowering is sized to "
+                "the bound")
+    return ("SCATTER",
+            "AUTO: CPU backend — native segment scatters beat both "
+            "the materialized one-hot and the bitonic sort")
 
 
 def _agg_pipeline(
@@ -488,8 +439,7 @@ class TpuHashAggregateExec(TpuExec):
         if hit is not None:
             return hit
         strategy, reason = choose_agg_strategy(
-            self.conf, cap, self._update_ops, self._update_exprs,
-            self._key_dtypes())
+            self.conf, cap, self._update_ops, self._update_exprs)
         self._strategy_by_cap[cap] = strategy
         self._strategy_choice = (strategy, reason)
         from .. import events as _events
@@ -503,6 +453,17 @@ class TpuHashAggregateExec(TpuExec):
         return strategy
 
     # -- helpers -----------------------------------------------------------
+    @contextlib.contextmanager
+    def _agg_timed(self, section: str):
+        """``op_timed`` for the aggregate's hot sections: the span says
+        which half of a partial -> exchange -> final plan it belongs to
+        (``mode``) and, once the section has resolved one, the lowering it
+        ran (``strategy``)."""
+        with self.op_timed(section, mode=self.mode) as span:
+            yield span
+            if span.on and self._strategy_choice is not None:
+                span.set(strategy=self._strategy_choice[0])
+
     def _key_dtypes(self) -> Tuple[T.DataType, ...]:
         return tuple(f.dataType for f in self._key_fields)
 
@@ -1005,7 +966,7 @@ class TpuHashAggregateExec(TpuExec):
         if fsp is not None and self._can_fuse_stage() and self._stage_fusion_on():
             stage = fsp(index)
             if stage:
-                with self.op_timed("stage") as span:
+                with self._agg_timed("stage") as span:
                     if span.on:
                         from ..io.parquet_device import stage_gathers
 
@@ -1052,7 +1013,7 @@ class TpuHashAggregateExec(TpuExec):
 
         def flush_buffered():
             for b in batches:
-                with self.op_timed("update"):
+                with self._agg_timed("update"):
                     update_with_retry(b)
             batches.clear()
 
@@ -1061,7 +1022,7 @@ class TpuHashAggregateExec(TpuExec):
             if isinstance(nr, int) and nr == 0 and self.group_exprs and not chain:
                 continue
             if not use_fused:
-                with self.op_timed("update"):
+                with self._agg_timed("update"):
                     update_with_retry(batch)
                 continue
             batches.append(batch)
@@ -1075,7 +1036,7 @@ class TpuHashAggregateExec(TpuExec):
                 flush_buffered()
         if use_fused and batches:
             try:
-                with self.op_timed("plan"):
+                with self._agg_timed("plan"):
                     from .. import faults as _faults
 
                     if _faults.enabled():
@@ -1114,7 +1075,7 @@ class TpuHashAggregateExec(TpuExec):
             zb = ColumnarBatch.from_pydict(
                 {f.name: [] for f in child_schema.fields}, child_schema
             )
-            with self.op_timed("update"):
+            with self._agg_timed("update"):
                 partials = [self._run_batch(zb, ops, exprs)]
         from ..memory.retry import with_oom_retry_nosplit
 
@@ -1125,7 +1086,7 @@ class TpuHashAggregateExec(TpuExec):
             with self.section("merge.eval"):
                 return self._evaluate(merged)
 
-        with self.op_timed("merge"):
+        with self._agg_timed("merge"):
             # the merge consumes compacted partials (group-cardinality
             # sized, not input sized) — not meaningfully splittable, so
             # it gets the retry-only harness: spill + backoff, then the

@@ -284,6 +284,10 @@ SCOPE_WORDS = ("pq_decode", "upload_unpack", "fused_chain", "agg_update",
 #: scopes only a program across chips has: the collective exchange
 #: between a mesh stage's partial and final halves (parallel/distributed)
 MESH_SCOPE_WORDS = ("mesh_exchange",)
+#: the scope of the three ``jit_exchange*`` programs of the one-host
+#: shuffle (exec/exchange.py): partition ids and sort, the slice of a
+#: piece, the concat of a reduce partition's pieces
+EXCHANGE_SCOPE_WORDS = ("shuffle_exchange",)
 
 
 def program(site: str):
@@ -350,16 +354,16 @@ def section_metric(section: str) -> str:
     return head + "".join(w.capitalize() for w in rest) + "Time"
 
 
-def phase(section: str):
+def phase(section: str, **counts):
     """A named host phase in code BELOW the exec layer (io/, columnar/):
     times into ``section_metric(section)`` of the exec whose op_timed
     section is open on this thread and, when that exec traces, is a span
-    ``<Exec>.<section>`` nested in it. With no exec above (a scanner
-    driven directly), nothing."""
+    ``<Exec>.<section>`` nested in it, with the keyword ``counts``. With
+    no exec above (a scanner driven directly), nothing."""
     op = getattr(_AMBIENT, "op", None)
     if op is None:
         return contextlib.nullcontext(NO_SPAN)
-    return op.section(section)
+    return op.section(section, **counts)
 
 
 class _Span:

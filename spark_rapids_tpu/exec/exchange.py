@@ -40,6 +40,7 @@ from ..types import StructType
 from ..utils.locks import ordered_lock
 from ..columnar.column import choose_capacity
 from .base import (
+    EXCHANGE_SCOPE_WORDS,
     TOTAL_TIME,
     TpuExec,
     batch_from_vals,
@@ -99,6 +100,10 @@ def make_transport(conf: RapidsConf) -> ShuffleTransport:
 
 
 _SLICE_CACHE: Dict[tuple, object] = {}
+#: the map side's programs, process-wide: an exchange is built anew for
+#: every query's plan, and a cache of its own would re-trace (and count a
+#: compile miss for) the same partition program in every query
+_MAP_CACHE: Dict[tuple, object] = {}
 
 
 def _piece_slicer(sig: tuple, pcap: int, ccaps: Tuple[int, ...]):
@@ -113,9 +118,10 @@ def _piece_slicer(sig: tuple, pcap: int, ccaps: Tuple[int, ...]):
     def build():
         @program("exchange_slice")
         def run(cols, start, n):
-            idx = jnp.arange(pcap, dtype=jnp.int32) + start
-            valid_slot = jnp.arange(pcap, dtype=jnp.int32) < n
-            return filter_gather.gather(cols, idx, valid_slot, ccaps)
+            with jax.named_scope(EXCHANGE_SCOPE_WORDS[0]):
+                idx = jnp.arange(pcap, dtype=jnp.int32) + start
+                valid_slot = jnp.arange(pcap, dtype=jnp.int32) < n
+                return filter_gather.gather(cols, idx, valid_slot, ccaps)
 
         return jax.jit(run)
 
@@ -153,6 +159,12 @@ def _slice_piece(
     return ShufflePiece(out, n, byte_lens)
 
 
+def _piece_bytes(piece: ShufflePiece) -> int:
+    """Device bytes of a piece's planes, from their shapes."""
+    return sum(int(a.size) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(piece.vals))
+
+
 _CONCAT_CACHE: Dict[tuple, object] = {}
 
 
@@ -175,8 +187,9 @@ def concat_pieces(
     def build():
         @program("exchange_concat")
         def run(col_parts, counts, byte_counts):
-            return concat_ops.concat_pieces_traced(
-                col_parts, counts, byte_counts, out_cap, out_char_caps)
+            with jax.named_scope(EXCHANGE_SCOPE_WORDS[0]):
+                return concat_ops.concat_pieces_traced(
+                    col_parts, counts, byte_counts, out_cap, out_char_caps)
 
         return jax.jit(run)
 
@@ -205,7 +218,6 @@ class TpuShuffleExchangeExec(TpuExec):
         self._map_done = False
         self._consumed: set = set()
         self._map_lock = ordered_lock("exec.exchange_map", reentrant=True)
-        self._jits: Dict[tuple, object] = {}
         self.metrics[PARTITION_SIZE] = self.metric(PARTITION_SIZE)
         self.metrics[DATA_SIZE] = self.metric(DATA_SIZE)
 
@@ -224,7 +236,8 @@ class TpuShuffleExchangeExec(TpuExec):
     def _part_cache_key(self) -> tuple:
         p = self.partitioning
         if isinstance(p, RangePartitioning) and p.bounds is not None:
-            return (p.describe(), tuple(tuple(b) for b in p.bounds))
+            return (p.describe(), tuple(tuple(b) for b in p.bounds),
+                    tuple((o.ascending, o.nulls_first) for o in p.orders))
         return (p.describe(),)
 
     def _key_str_lens(self, batch: ColumnarBatch) -> Tuple[int, ...]:
@@ -242,22 +255,24 @@ class TpuShuffleExchangeExec(TpuExec):
     def _map_fn(self, sig: tuple, cap: int, schema: StructType,
                 sml: Tuple[int, ...]):
         P = self.num_partitions
-        key = (sig, cap, P, sml, self._part_cache_key())
+        key = (sig, cap, P, sml, schema, self._part_cache_key())
 
         def build():
             part = self.partitioning
 
             @program("exchange")
             def run(cols, num_rows, map_index):
-                live = filter_gather.live_of(num_rows, cap)
-                pids = part.partition_ids(
-                    cols, schema, live, map_index, str_max_lens=sml)
-                sorted_cols, offsets = partition_cols(cols, pids, num_rows, P)
-                byte_offs = [
-                    jnp.take(c.offsets, offsets, mode="clip")
-                    for c in sorted_cols if isinstance(c, StrV)
-                ]
-                return sorted_cols, offsets, byte_offs
+                with jax.named_scope(EXCHANGE_SCOPE_WORDS[0]):
+                    live = filter_gather.live_of(num_rows, cap)
+                    pids = part.partition_ids(
+                        cols, schema, live, map_index, str_max_lens=sml)
+                    sorted_cols, offsets = partition_cols(
+                        cols, pids, num_rows, P)
+                    byte_offs = [
+                        jnp.take(c.offsets, offsets, mode="clip")
+                        for c in sorted_cols if isinstance(c, StrV)
+                    ]
+                    return sorted_cols, offsets, byte_offs
 
             return jax.jit(run)
 
@@ -267,8 +282,7 @@ class TpuShuffleExchangeExec(TpuExec):
         # program and must not be invisible to the roofline report
         from .base import cached_pipeline
 
-        return cached_pipeline(self._jits, key, "exchange", build,
-                               per_instance=True)
+        return cached_pipeline(_MAP_CACHE, key, "exchange", build)
 
     def _sample_range_bounds(self, parts: List[List[ColumnarBatch]]) -> None:
         """Sample key values host-side and set the range bounds
@@ -363,7 +377,10 @@ class TpuShuffleExchangeExec(TpuExec):
 
             P = self.num_partitions
             self.partition_rows = [0] * P
-            with self.op_timed(), named_oom(f"{self.node_name}.map"):
+            wrote0 = self.transport.bytes_written()
+            inputs = 0
+            with self.op_timed("map", partitions=P) as span, \
+                    named_oom(f"{self.node_name}.map"):
                 # exchange map-side staging (partition sort + piece
                 # slicing) sits outside the per-batch retry harness: a
                 # device allocation failure here is a named
@@ -371,6 +388,7 @@ class TpuShuffleExchangeExec(TpuExec):
                 for map_id, batch in batch_iter:
                     if not batch.columns:
                         continue
+                    inputs += 1
                     # dict-encoded columns materialize at the shuffle
                     # boundary: pieces serialize/slice the plain Arrow
                     # layout and peers don't share dictionaries
@@ -405,6 +423,10 @@ class TpuShuffleExchangeExec(TpuExec):
                         # re-plans from these (reference: MapOutputStats
                         # feeding ShuffledBatchRDD's partition specs)
                         self.partition_rows[j] += b - a
+                # what the map side handed the transport: the pieces'
+                # bytes and rows over all of its input batches
+                span.set(bytes=self.transport.bytes_written() - wrote0,
+                         rows=sum(self.partition_rows), inputs=inputs)
             self.metrics[DATA_SIZE].set(self.transport.bytes_written())
             self._note_transport_stats()
             self._map_done = True
@@ -441,14 +463,22 @@ class TpuShuffleExchangeExec(TpuExec):
                 self.transport.release(self.shuffle_id)
                 self._consumed.clear()
                 self._map_done = False
-        if not pieces:
-            return
+        if pieces:
+            yield self.record_batch(self.reduce(pieces))
+
+    def reduce(self, pieces: List[ShufflePiece]) -> ColumnarBatch:
+        """One reduce partition's pieces as one dense batch (the adaptive
+        reads hand their pieces in too, so the reduce side has one span
+        whoever reads)."""
         from ..memory.retry import named_oom
 
-        schema = self.output_schema
-        with named_oom(f"{self.node_name}.reduce"):
-            out = concat_pieces(pieces, schema)
-        yield self.record_batch(out)
+        with self.op_timed("reduce") as span, \
+                named_oom(f"{self.node_name}.reduce"):
+            if span.on:
+                span.set(partitions=len(pieces),
+                         rows=sum(p.n for p in pieces),
+                         bytes=sum(_piece_bytes(p) for p in pieces))
+            return concat_pieces(pieces, self.output_schema)
 
 
 # ---------------------------------------------------------------------------
@@ -506,9 +536,8 @@ class TpuAQEShuffleReadExec(TpuExec):
             ex.transport.release(ex.shuffle_id)
             self._consumed.clear()
             ex._map_done = False
-        if not pieces:
-            return
-        yield self.record_batch(concat_pieces(pieces, self.output_schema))
+        if pieces:
+            yield self.record_batch(ex.reduce(pieces))
 
 
 def _slice_pieces_by_rows(

@@ -386,8 +386,13 @@ class TpuFileSourceScanExec(TpuExec):
         fn = getattr(self.scanner, "device_stage_plans", None)
         if fn is None:
             return None
-        with self.op_timed("plan", SCAN_TIME):
-            return fn(index)
+        with self.op_timed("plan", SCAN_TIME) as span:
+            stage = fn(index)
+            if stage is not None:
+                # one span a split the fused stage takes: over a query
+                # the counts add up to its scan partitions
+                span.set(splits=1, row_groups=len(stage))
+            return stage
 
     def host_prefetch(self) -> None:
         """Serving-path phase split: start every split's host decode (+
@@ -438,7 +443,10 @@ class TpuFileSourceScanExec(TpuExec):
         # GpuParquetScan.scala:1157): host uploads encoded bytes, XLA
         # kernels expand dictionary/RLE pages on-device
         if hasattr(self.scanner, "read_split_device"):
-            with self.op_timed("decode", DECODE_TIME):
+            with self.op_timed("decode", DECODE_TIME) as span:
+                if span.on:
+                    span.set(splits=1, row_groups=len(
+                        self.scanner.splits()[index].row_groups))
                 self._consumed_splits.add(index)
                 fut = None
                 if self._prefetch_dev is not None:

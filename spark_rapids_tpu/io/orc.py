@@ -13,7 +13,7 @@ from typing import Any, Dict, List, Optional, Sequence
 from .. import types as T
 from ..conf import RapidsConf
 from .arrow_convert import arrow_schema_to_tpu
-from .parquet import PushedFilter, discover_files
+from .parquet import PushedFilter, discover_files, prune_columns
 
 
 def apply_filters_host(table, filters: Sequence[PushedFilter]):
@@ -50,7 +50,8 @@ class OrcScanner:
 
     def __init__(self, path: str, conf: RapidsConf,
                  columns: Optional[Sequence[str]] = None,
-                 filters: Optional[Sequence[PushedFilter]] = None):
+                 filters: Optional[Sequence[PushedFilter]] = None,
+                 required: Optional[frozenset] = None):
         from pyarrow import orc
 
         self.conf = conf
@@ -64,6 +65,8 @@ class OrcScanner:
             self.file_schema.field(i).name
             for i in range(len(self.file_schema.names))
         ]
+        if required is not None:
+            self.columns = prune_columns(self.columns, required)
         self.schema = arrow_schema_to_tpu(
             self.file_schema.empty_table().select(self.columns).schema)
         self._splits = [

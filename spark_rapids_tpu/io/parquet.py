@@ -137,12 +137,20 @@ def prune_row_groups(pf, filters: Sequence[PushedFilter]) -> List[int]:
     return keep
 
 
+def prune_columns(columns: List[str], required: frozenset) -> List[str]:
+    """The columns a plan reads (``required``, names) of those a file
+    scan would read; a plan that reads none (``count(*)``) keeps the
+    first, for its row counts."""
+    return [c for c in columns if c in required] or columns[:1]
+
+
 class ParquetScanner:
     """Plans splits and reads them as pyarrow tables."""
 
     def __init__(self, path: str, conf: RapidsConf,
                  columns: Optional[Sequence[str]] = None,
-                 filters: Sequence[PushedFilter] = ()):
+                 filters: Sequence[PushedFilter] = (),
+                 required: Optional[frozenset] = None):
         import pyarrow.parquet as pq
 
         self.path = path
@@ -156,6 +164,8 @@ class ParquetScanner:
         self.columns = list(columns) if columns is not None else [
             f.name for f in self.file_schema
         ]
+        if required is not None:
+            self.columns = prune_columns(self.columns, required)
         # partition columns come from directory names (string-typed);
         # only keys present on EVERY file become schema columns (ragged
         # layouts keep the common prefix)
@@ -374,8 +384,11 @@ def _open_mapped(path: str):
 
     from ..exec.base import phase
 
-    with phase("read_file"):
+    with phase("read_file") as span:
         pf = pq.ParquetFile(path)
+        # the footer is what opening reads; the chunks' bytes are counted
+        # where they are paged in (``page_plan``)
+        span.set(file_bytes=pf.metadata.serialized_size)
         f = open(path, "rb")
         try:
             file_bytes = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)

@@ -1173,13 +1173,16 @@ def _plan_columns(path, pf, rgmd, pqschema, name_to_ci, columns, file_bytes):
     plans: Dict[str, ChunkPlan] = {}
     if candidates:
         if file_bytes is None:
-            with phase("read_file"), open(path, "rb") as f:
+            with phase("read_file") as span, open(path, "rb") as f:
                 file_bytes = f.read()
+                span.set(file_bytes=len(file_bytes))
 
         def plan_one(item):
             name, ci = item
             pqcol = pqschema.column(ci)
-            with phase("page_plan"):
+            # the chunk's pages are the file bytes this plan touches
+            with phase("page_plan",
+                       file_bytes=rgmd.column(ci).total_compressed_size):
                 try:
                     return name, plan_chunk(
                         file_bytes, rgmd.column(ci),
@@ -1254,8 +1257,9 @@ def read_row_groups_pipelined(
     pqschema = pf.schema
     pool = _decode_pool()
     if file_bytes is None:
-        with phase("read_file"), open(path, "rb") as f:
+        with phase("read_file") as span, open(path, "rb") as f:
             file_bytes = f.read()
+            span.set(file_bytes=len(file_bytes))
     fields_by_name = {f.name: f for f in tpu_fields}
 
     def plan_one(rg, rgmd, name, ci):
@@ -1263,7 +1267,8 @@ def read_row_groups_pipelined(
         if ci is None:
             return name, None, 0
         pqcol = pqschema.column(ci)
-        with phase("page_plan"):
+        with phase("page_plan",
+                   file_bytes=rgmd.column(ci).total_compressed_size):
             try:
                 plan = plan_chunk(
                     file_bytes, rgmd.column(ci),

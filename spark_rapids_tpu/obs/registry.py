@@ -150,7 +150,7 @@ METRICS: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         "(stall/hbm_pressure/recompile_storm)", ("kind",)),
     "tpu_agg_strategy": (
         COUNTER, "Aggregation lowering choices by resolved strategy "
-        "(MATMUL/SCATTER/SORT — conf sql.agg.strategy)", ("strategy",)),
+        "(MATMUL/SCATTER/RADIX/PALLAS — conf sql.agg.strategy)", ("strategy",)),
     "tpu_join_strategy": (
         COUNTER, "Join probe lowering choices by resolved strategy "
         "(SEARCH/DIRECT/RADIX/PALLAS — conf sql.join.strategy; the "

@@ -1,6 +1,6 @@
-"""Bucket reductions under THREE interchangeable lowerings: one-hot limb
-matmul (MXU), native segment scatter, and sort + prefix-sum differences
-(HBM bandwidth).
+"""Bucket reductions under interchangeable lowerings: one-hot limb matmul
+(MXU) and native segment scatter (the Pallas kernels of
+ops/pallas_groupby ride the same entry).
 
 TPU-first design with no reference analog: XLA's scatter (what
 ``jax.ops.segment_sum`` lowers to) runs near-serially on TPU (~10ns/row),
@@ -9,14 +9,8 @@ while the MXU multiplies 256x256 tiles for free. A bucket reduction
 XLA fuses the one-hot generation into the matmul so the (n, B) matrix never
 materializes.
 
-The matmul prices the reduction in MXU flops (cap x limbs x B MACs); the
-round-5 profile showed the agg program touching HBM at 1.3% of roofline
-while ~100% of device wait sat inside it, so round 7 adds a lowering
-sized to BANDWIDTH instead: order rows by bucket id, then every bucket's
-sum is a difference of prefix sums at the bucket boundaries
-(:func:`contiguous_segment_reduce`) — one stable sort, one cumsum pass
-per dtype family, 2*(B+1) boundary gathers, zero scatters and no one-hot.
-The strategy is selected per plan by the aggregate exec's chooser
+The matmul prices the reduction in MXU flops (cap x limbs x B MACs). The
+strategy is selected per plan by the aggregate exec's chooser
 (``spark.rapids.tpu.sql.agg.strategy``, exec/aggregate.py) and recorded
 in the event log so a wrong prediction is visible in tools/tpu_profile.
 
@@ -76,7 +70,7 @@ FORCE_PER_COLUMN = False
 def _resolve_strategy(strategy=None) -> str:
     """Resolve the lowering for one reduction (trace-time static, so each
     jit cache entry is per-strategy and per-backend). ``strategy`` is an
-    already-chosen MATMUL/SCATTER/SORT/PALLAS from the aggregate exec's
+    already-chosen MATMUL/SCATTER/PALLAS from the aggregate exec's
     chooser; None/AUTO falls back to the backend default: the MXU
     tradeoff inverts on XLA CPU, where the one-hot never fuses — it
     materializes (n, B) compare-selects at ~7ns/element (measured:
@@ -87,7 +81,7 @@ def _resolve_strategy(strategy=None) -> str:
     path stays differentially covered on the CPU backend."""
     if FORCE_MATMUL:
         return "MATMUL"
-    if strategy in ("MATMUL", "SCATTER", "SORT", "PALLAS"):
+    if strategy in ("MATMUL", "SCATTER", "PALLAS"):
         return strategy
     return "SCATTER" if jax.default_backend() == "cpu" else "MATMUL"
 
@@ -130,115 +124,6 @@ def _bucket_reduce_scatter(
     return out_int, out_cnt, out_flt
 
 
-def _prefix_boundaries(sorted_seg: jax.Array, B: int) -> jax.Array:
-    """``bounds[b]`` = first position in the NONDECREASING id array with
-    id >= b, shape (B+1,). Out-of-range ids (padding/dead rows, id >= B)
-    sort past ``bounds[B]`` and drop out of every prefix difference;
-    negative ids sort before ``bounds[0]`` and drop the same way."""
-    return jnp.searchsorted(
-        sorted_seg, jnp.arange(B + 1, dtype=sorted_seg.dtype), side="left")
-
-
-def contiguous_segment_reduce(
-    seg: jax.Array,
-    B: int,
-    int_cols: Sequence[Tuple[jax.Array, jax.Array]] = (),
-    count_cols: Sequence[jax.Array] = (),
-    float_cols: Sequence[Tuple[jax.Array, jax.Array]] = (),
-) -> Tuple[List[jax.Array], List[jax.Array], List[jax.Array]]:
-    """Per-bucket sums/counts over a NONDECREASING ``seg`` as prefix-sum
-    differences at the bucket boundaries — the bandwidth-sized reduction:
-    one cumsum pass per dtype family plus 2*(B+1) boundary gathers, zero
-    scatters, no one-hot. Integer sums and counts are BIT-exact: prefix
-    sums wrap mod 2^64 and differences of wrapped prefixes equal the
-    wrapped segment sum (Java int64 wraparound included). Float sums are
-    order-insensitive like the matmul hi/lo split (callers gate them
-    behind variableFloatAgg the same way); a non-finite row would poison
-    every later bucket's prefix, so those rows detour through a rare
-    scatter correction cond'd on actually seeing one (the matmul overflow
-    pattern). Callers with unsorted ids use the SORT lowering of
-    :func:`bucket_reduce`, which stable-sorts by id first; ops/groupby's
-    radix-sorted path feeds its already-contiguous segment ids straight
-    in."""
-    bounds = _prefix_boundaries(seg, B)
-    lo, hi = bounds[:-1], bounds[1:]
-
-    def diffs(mat: jax.Array) -> jax.Array:
-        c = jnp.cumsum(mat, axis=0)
-        padded = jnp.concatenate(
-            [jnp.zeros((1, mat.shape[1]), mat.dtype), c])
-        return (jnp.take(padded, hi, axis=0, mode="clip")
-                - jnp.take(padded, lo, axis=0, mode="clip"))
-
-    out_int: List[jax.Array] = []
-    out_cnt: List[jax.Array] = []
-    out_flt: List[jax.Array] = []
-    icols = [
-        jnp.where(valid, data.astype(jnp.int64),
-                  jnp.int64(0)).astype(jnp.uint64)
-        for data, valid in int_cols
-    ]
-    ccols = [valid.astype(jnp.uint64) for valid in count_cols]
-    if icols or ccols:
-        s = diffs(jnp.stack(icols + ccols, axis=-1))
-        out_int = [s[:, i].astype(jnp.int64) for i in range(len(icols))]
-        out_cnt = [s[:, len(icols) + i].astype(jnp.int64)
-                   for i in range(len(ccols))]
-    if float_cols:
-        # route non-finite AND huge-magnitude rows through the (rare)
-        # scatter correction: a NaN/inf poisons every later bucket's
-        # prefix, and a ~1e300 value annihilates the prefix's low bits —
-        # the matmul lowering's F32_MAX overflow detour, same idea
-        F64_BIG = jnp.float64(2.0) ** 500
-        finite_cols: List[jax.Array] = []
-        corrections: List[Tuple[jax.Array, jax.Array]] = []
-        for data, valid in float_cols:
-            d = jnp.where(valid, data, 0.0).astype(jnp.float64)
-            bad = ~jnp.isfinite(d) | (jnp.abs(d) > F64_BIG)
-            finite_cols.append(jnp.where(bad, 0.0, d))
-            corrections.append((jnp.any(bad), jnp.where(bad, d, 0.0)))
-        f = diffs(jnp.stack(finite_cols, axis=-1))
-        for i, (any_bad, d_bad) in enumerate(corrections):
-            corr = jax.lax.cond(
-                any_bad,
-                lambda d=d_bad: jax.ops.segment_sum(d, seg, num_segments=B),
-                lambda: jnp.zeros(B, jnp.float64),
-            )
-            out_flt.append(f[:, i] + corr)
-    return out_int, out_cnt, out_flt
-
-
-def _bucket_reduce_sort(
-    seg: jax.Array,
-    B: int,
-    int_cols: Sequence[Tuple[jax.Array, jax.Array]],
-    count_cols: Sequence[jax.Array],
-    float_cols: Sequence[Tuple[jax.Array, jax.Array]],
-) -> Tuple[List[jax.Array], List[jax.Array], List[jax.Array]]:
-    """SORT lowering: stable-sort rows by bucket id (one ``lax.sort``
-    carrying a row permutation — the same machinery ops/groupby's radix
-    path uses), gather every column into bucket order with ONE row take
-    per column, then reduce each now-contiguous bucket with
-    :func:`contiguous_segment_reduce`. Every pass is elementwise or a
-    contiguous stream — HBM bandwidth, not MXU flops or scatter latency,
-    is the price."""
-    from jax import lax
-
-    n = seg.shape[0]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    sseg, perm = lax.sort([seg, iota], num_keys=1, is_stable=True)
-
-    def g(a: jax.Array) -> jax.Array:
-        return jnp.take(a, perm, mode="clip")
-
-    return contiguous_segment_reduce(
-        sseg, B,
-        [(g(d), g(v)) for d, v in int_cols],
-        [g(v) for v in count_cols],
-        [(g(d), g(v)) for d, v in float_cols],
-    )
-
-
 def bucket_reduce(
     seg: jax.Array,
     B: int,
@@ -264,7 +149,7 @@ def bucket_reduce(
     int_cols:   [(data int64/int32, valid bool)] -> exact int64 sums (B,)
     count_cols: [valid bool] -> int64 counts (B,)
     float_cols: [(data f64/f32, valid bool)] -> f64 sums (B,) (hi/lo split)
-    strategy:   MATMUL / SCATTER / SORT, or None for the backend default
+    strategy:   MATMUL / SCATTER / PALLAS, or None for the backend default
                 (see :func:`_resolve_strategy`).
     fixed_cols: [(data f64/f32, valid bool)] -> (f64 sums (B,), whether a
                 row took the detour): float sums that may not be
@@ -313,9 +198,6 @@ def _bucket_reduce_pass(
             "fixed-point float sums are the MATMUL lowering's", resolved)
         if resolved == "SCATTER":
             out = _bucket_reduce_scatter(
-                seg, B, int_cols, count_cols, float_cols)
-        elif resolved == "SORT":
-            out = _bucket_reduce_sort(
                 seg, B, int_cols, count_cols, float_cols)
         else:
             from .pallas_groupby import pallas_bucket_reduce

@@ -239,62 +239,6 @@ def string_minmax_ranks(
     return recover
 
 
-def _sorted_segment_aggs(
-    agg_ops: Sequence[str],
-    sorted_vals: Sequence[Optional[ColV]],
-    seg: jax.Array,
-    ncap: int,
-    live: jax.Array,
-) -> List[ColV]:
-    """Bandwidth-sized reduction over ALREADY-SORTED segment ids (the SORT
-    aggregation strategy): sum/count/count_star batch through ONE
-    prefix-difference pass (ops/bucket_reduce.contiguous_segment_reduce —
-    the segments are contiguous after the radix sort, so no scatter walk
-    per aggregate). Integer sums, counts and count_star are bit-identical
-    to :func:`segment_reduce`; FLOAT sums and min/max/first/last keep the
-    segment-scatter path — float prefix differences would reorder adds on
-    queries that never opted into variableFloatAgg, and cummax has no
-    inverse."""
-    from .bucket_reduce import contiguous_segment_reduce
-
-    int_specs: List[Tuple[jax.Array, jax.Array]] = []
-    cnt_specs: List[jax.Array] = []
-    plan: List[tuple] = []
-    for op, v in zip(agg_ops, sorted_vals):
-        if op == "count_star":
-            plan.append(("cnt", len(cnt_specs)))
-            cnt_specs.append(live)
-        elif op == "count":
-            plan.append(("cnt", len(cnt_specs)))
-            cnt_specs.append(v.validity & live)
-        elif (op == "sum" and v is not None
-                and not jnp.issubdtype(v.data.dtype, jnp.floating)):
-            ci = len(cnt_specs)
-            cnt_specs.append(v.validity & live)
-            plan.append(("isum", (len(int_specs), ci, v.data.dtype)))
-            int_specs.append((v.data, v.validity & live))
-        else:
-            plan.append(("seg", (op, v)))
-    isums, counts, _ = contiguous_segment_reduce(
-        seg, ncap, int_specs, cnt_specs, ())
-    out: List[ColV] = []
-    for kind, payload in plan:
-        if kind == "cnt":
-            out.append(ColV(counts[payload], jnp.ones(ncap, jnp.bool_)))
-        elif kind == "isum":
-            si, ci, dt = payload
-            data = isums[si]
-            if dt != jnp.int64:
-                data = data.astype(dt)  # mod-2^32 of a mod-2^64 sum: exact
-            has = counts[ci] > 0
-            out.append(ColV(jnp.where(has, data,
-                                      jnp.zeros((), data.dtype)), has))
-        else:
-            op, v = payload
-            out.append(segment_reduce(op, v, seg, ncap, live))
-    return out
-
-
 def _jnp_reduce_dtype(dtype) -> T.DataType:
     """Engine DataType standing in for a jnp dtype when only the RADIX
     encoding family matters (float total-order trick vs bool cast vs int
@@ -470,7 +414,6 @@ def sort_groupby(
     agg_ops: Sequence[str],
     num_rows: Union[int, jax.Array],
     str_max_lens: Sequence[int] = (),
-    prefix_reduce: bool = False,
     radix_reduce: bool = False,
 ) -> Tuple[List[Val], List[ColV], jax.Array]:
     """Full groupby via sort: sort by keys, segment, reduce.
@@ -478,9 +421,6 @@ def sort_groupby(
     ``value_cols[i]`` is the (pre-cast) input for ``agg_ops[i]`` (None for
     count_star). Returns (group key columns, aggregate columns, num_groups);
     outputs are compacted to the front at the input capacity.
-    ``prefix_reduce`` (the SORT aggregation strategy) reduces sums/counts
-    via prefix differences over the contiguous segments instead of one
-    segment scatter per aggregate (see :func:`_sorted_segment_aggs`).
     ``radix_reduce`` (the RADIX strategy) reduces EVERY aggregate family
     — float sums and min/max/first/last included — on the tiled
     radix-binned machinery with zero scatters (:func:`_radix_groupby`).
@@ -524,13 +464,10 @@ def sort_groupby(
     out_live = jnp.arange(cap, dtype=jnp.int32) < nseg
     first_row = jnp.clip(first_row, 0, cap - 1)
     out_keys = gather(sorted_keys, first_row, out_live)
-    if prefix_reduce:
-        out_aggs = _sorted_segment_aggs(agg_ops, sorted_vals, seg, cap, live)
-    else:
-        out_aggs = [
-            segment_reduce(op, v, seg, cap, live)
-            for op, v in zip(agg_ops, sorted_vals)
-        ]
+    out_aggs = [
+        segment_reduce(op, v, seg, cap, live)
+        for op, v in zip(agg_ops, sorted_vals)
+    ]
     # aggregate outputs: zero validity in dead slots
     out_aggs = [
         ColV(jnp.where(out_live, a.data, jnp.zeros((), a.data.dtype)),
@@ -998,10 +935,9 @@ def groupby_agg(
     ``strategy`` is the plan-level aggregation lowering chosen by the
     exec's strategy chooser (conf spark.rapids.tpu.sql.agg.strategy):
     MATMUL/SCATTER force the hash-bucket tiers' reduction lowering
-    (ops/bucket_reduce.py), SORT skips the hash tiers entirely and
-    radix-sorts by the grouping keys, reducing each contiguous segment
-    via prefix differences — the HBM-bandwidth-sized path. None keeps
-    the backend default (identical to round 6).
+    (ops/bucket_reduce.py), RADIX skips the hash tiers and reduces over
+    the radix-binned order (ops/radix_bin.py). None keeps the backend
+    default.
     Plain string keys always take the sort path; DICT-ENCODED string keys
     whose dictionary is unique group directly on their int32 codes (no
     byte-wise hashing or chunk-key sort at all — the cudf-dictionary32
@@ -1060,19 +996,18 @@ def groupby_agg(
                 aggs[ai] = rec(aggs[ai])
         return keys, aggs, n
 
-    prefix = strategy == "SORT"
     # PALLAS hash tiers cover fixed-width keys; its string/keyless
     # fallback rides the RADIX tiled path so the plan stays scatter-free
     radix = strategy == "RADIX" or strategy == "PALLAS"
     if not key_cols:
         return _rewrap(*sort_groupby(
             key_cols, key_dtypes, value_cols, agg_ops, num_rows,
-            str_max_lens, prefix_reduce=prefix, radix_reduce=radix))
-    if strategy == "RADIX" or prefix or any(
+            str_max_lens, radix_reduce=radix))
+    if strategy == "RADIX" or any(
             isinstance(c, StrV) for c in key_cols):
         return _rewrap(*sort_groupby(
             key_cols, key_dtypes, value_cols, agg_ops, num_rows,
-            str_max_lens, prefix_reduce=prefix, radix_reduce=radix))
+            str_max_lens, radix_reduce=radix))
     cap = key_cols[0].validity.shape[0]
 
     def pow2_floor(x: int) -> int:

@@ -514,11 +514,17 @@ def combine_float_sum(normal: jax.Array, big: jax.Array,
     semantics: NaN (or mixed infinities) dominates, then the surviving
     infinity, else normal + big * 2^600 (which may itself overflow to
     the mathematically correct infinity). ``flags`` is the (cap,) uint8
-    presence output of the OR stream."""
+    presence output of the OR stream.
+
+    The rescale is taken only where the BIG stream holds something. The
+    f64 a TPU emulates has f32's exponent range: ``2^600`` is ``inf``
+    there, no finite value passes ``F64_BIG`` (also ``inf``), so the BIG
+    stream is all zeros, and an unguarded ``0 * inf`` made every sum
+    NaN on the chip."""
     p = (flags & jnp.uint8(1)) != 0
     m = (flags & jnp.uint8(2)) != 0
     q = (flags & jnp.uint8(4)) != 0
-    s = normal + big * BIG_SCALE_UP
+    s = normal + jnp.where(big != 0.0, big * BIG_SCALE_UP, 0.0)
     r = jnp.where(p, jnp.inf, jnp.where(m, -jnp.inf, s))
     return jnp.where(q | (p & m), jnp.nan, r)
 

@@ -1106,7 +1106,7 @@ class _Analyzer:
                                   if in_batches else in_cap)
                 strat, sreason = choose_agg_strategy(
                     self.conf, cap_for_choice, agg._update_ops,
-                    agg._update_exprs, agg._key_dtypes())
+                    agg._update_exprs)
                 report.notes.append(f"agg strategy: {strat} — {sreason}")
             else:
                 report.notes.append(

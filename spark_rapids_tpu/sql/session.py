@@ -76,7 +76,7 @@ def _make_scanner(fmt: str, path: str, opts: tuple, conf: RapidsConf,
 
             sc = ParquetScanner(
                 path, conf, columns=od.get("columns"),
-                filters=list(pushed))
+                filters=list(pushed), required=od.get("required"))
         elif fmt == "csv":
             from ..io.csv import CsvScanner
 
@@ -87,7 +87,8 @@ def _make_scanner(fmt: str, path: str, opts: tuple, conf: RapidsConf,
             from ..io.orc import OrcScanner
 
             sc = OrcScanner(path, conf, columns=od.get("columns"),
-                            filters=list(pushed))
+                            filters=list(pushed),
+                            required=od.get("required"))
         else:
             raise ValueError(f"unknown file format {fmt}")
         if len(_SCANNER_CACHE) > 256:
@@ -155,14 +156,46 @@ def _resolve_udfs(e: E.Expression, conf: RapidsConf) -> E.Expression:
     return e.transform(rw)
 
 
-def _lower(node: LNode, conf: RapidsConf, cache=None) -> C.CpuExec:
+def _column_refs(exprs) -> Optional[frozenset]:
+    """Names of the columns the expressions read; None where a node cannot
+    be walked (then nothing is pruned below it)."""
+    import dataclasses
+
+    names = set()
+    todo = list(exprs)
+    while todo:
+        e = todo.pop()
+        if not dataclasses.is_dataclass(e):
+            return None
+        if isinstance(e, E.UnresolvedAttribute):
+            names.add(e.name)
+        todo.extend(e.children)
+    return frozenset(names)
+
+
+def _pruned_scanner(fmt: str, path: str, opts: tuple, conf: RapidsConf,
+                    pushed: tuple, required: Optional[frozenset]):
+    """The file's scanner, reading only the columns the plan above it
+    reads (``required``; None = all): a chunk of a column nobody reads is
+    never planned, paged in or uploaded (reference: Spark's
+    ColumnPruning feeding the scan's readDataSchema). A ``columns`` option
+    of the user's own stands."""
+    if (required is not None and fmt in ("parquet", "orc")
+            and not dict(opts).get("columns")):
+        opts = opts + (("required", required),)
+    return _make_scanner(fmt, path, opts, conf, pushed)
+
+
+def _lower(node: LNode, conf: RapidsConf, cache=None,
+           required: Optional[frozenset] = None) -> C.CpuExec:
     """Logical plan -> CPU physical plan. ``cache`` is the session's
     ``CacheManager``: a subtree that ``DataFrame.cache()`` marked lowers
     to a ``CpuInMemoryTableScanExec`` over itself (Spark's useCachedData),
-    whichever DataFrame it is reached from."""
+    whichever DataFrame it is reached from. ``required``: the columns the
+    plan above reads of this node's output (None = all of them)."""
     rel = cache.lookup(node) if cache else None
     if rel is None:
-        return _lower_node(node, conf, cache)
+        return _lower_node(node, conf, cache, required)
     from .cache import file_scan_paths, files_identity
 
     now = files_identity(node)
@@ -179,9 +212,32 @@ def _lower(node: LNode, conf: RapidsConf, cache=None) -> C.CpuExec:
         conf, _lower_node(node, conf, cache), rel, now)
 
 
-def _lower_node(node: LNode, conf: RapidsConf, cache=None) -> C.CpuExec:
+def _child_columns(node: LNode, required: Optional[frozenset]
+                   ) -> Optional[frozenset]:
+    """What ``node`` reads of its child, given what is read of ``node``:
+    an aggregate or a projection reads what its expressions name, a
+    filter and a sort that and what passes through them, a limit what
+    passes through; any other node (a join, a window, a union) reads
+    everything."""
+    k = node.kind
+    if k == "aggregate":
+        keys, aggs = node.args
+        return _column_refs(keys + aggs)
+    if k == "project":
+        return _column_refs(node.args[0])
+    if k in ("filter", "sort"):
+        own = _column_refs(node.args[:1] if k == "filter" else node.args[0])
+        return None if required is None or own is None else required | own
+    if k in ("limit", "collect_limit"):
+        return required
+    return None
+
+
+def _lower_node(node: LNode, conf: RapidsConf, cache=None,
+                required: Optional[frozenset] = None) -> C.CpuExec:
     k = node.kind
     rx = lambda ex: _resolve_udfs(ex, conf)  # noqa: E731
+    below = _child_columns(node, required)
     if k == "filter" and node.children[0].kind == "file_scan" and not (
             cache and cache.lookup(node.children[0])):
         # (a cached scan holds every row group: nothing is pushed into it)
@@ -191,13 +247,13 @@ def _lower_node(node: LNode, conf: RapidsConf, cache=None) -> C.CpuExec:
         fmt, path, opts = node.children[0].args
         pushed = (
             _extract_pushed_filters(cond) if fmt in ("parquet", "orc") else ())
-        sc = _make_scanner(fmt, path, opts, conf, pushed)
+        sc = _pruned_scanner(fmt, path, opts, conf, pushed, below)
         return C.CpuFilterExec(conf, cond, C.CpuFileScanExec(conf, sc, fmt))
-    kids = [_lower(c, conf, cache) for c in node.children]
+    kids = [_lower(c, conf, cache, below) for c in node.children]
     if k == "file_scan":
         fmt, path, opts = node.args
         return C.CpuFileScanExec(
-            conf, _make_scanner(fmt, path, opts, conf), fmt)
+            conf, _pruned_scanner(fmt, path, opts, conf, (), required), fmt)
     if k == "scan":
         rows, schema, nparts = node.args
         per = (len(rows) + nparts - 1) // nparts if rows else 0

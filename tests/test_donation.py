@@ -61,7 +61,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOL = os.path.join(REPO, "tools", "tpu_donate.py")
 FIXTURES = os.path.join(REPO, "tests", "donation_fixtures")
 
-AGG_STRATEGIES = ("SCATTER", "MATMUL", "SORT", "RADIX", "PALLAS")
+AGG_STRATEGIES = ("SCATTER", "MATMUL", "RADIX", "PALLAS")
 JOIN_STRATEGIES = ("AUTO", "SEARCH", "DIRECT", "RADIX", "PALLAS")
 
 NO_BACKOFF = {"spark.rapids.tpu.memory.oomRetry.backoffMs": 0}

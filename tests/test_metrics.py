@@ -36,20 +36,12 @@ from spark_rapids_tpu.sql import TpuSession
 
 
 # ---------------------------------------------------------------------------
-# fused vs per-column bucket reduce (all three lowerings)
+# fused vs per-column bucket reduce (both lowerings)
 # ---------------------------------------------------------------------------
-def _strategy_of(lowering):
-    """The explicit strategy to pass for a fixture param (sort is selected
-    via the strategy argument — the round-7 lowering; matmul still rides
-    the FORCE_MATMUL hook, which outranks any passed strategy)."""
-    return "SORT" if lowering == "sort" else None
-
-
-@pytest.fixture(params=["scatter", "matmul", "sort"])
+@pytest.fixture(params=["scatter", "matmul"])
 def lowering(request):
-    """Run the differential against ALL THREE lowerings: the CPU scatter
-    family, the forced MXU limb-matmul path, and the sort+prefix-diff
-    bandwidth path (round-7 sql.agg.strategy=SORT)."""
+    """Run the differential against both lowerings: the CPU scatter
+    family and the forced MXU limb-matmul path."""
     prev = BR.FORCE_MATMUL
     BR.FORCE_MATMUL = request.param == "matmul"
     try:
@@ -91,7 +83,7 @@ def test_fused_reduce_int64_wraparound(lowering):
     out = _diff_bucket_reduce(
         seg, 8,
         [(jnp.asarray(big), valid), (jnp.asarray(mixed), valid)],
-        [valid], [], strategy=_strategy_of(lowering))
+        [valid], [])
     # cross-check column 0 against numpy's wrapping sum per bucket
     segs = np.asarray(seg)
     for b in range(7):
@@ -113,8 +105,7 @@ def test_fused_reduce_all_null_columns(lowering):
         seg, 8,
         [(data_i, none_valid), (data_i, some_valid)],
         [none_valid, some_valid],
-        [(data_f, none_valid), (data_f, some_valid)],
-        strategy=_strategy_of(lowering))
+        [(data_f, none_valid), (data_f, some_valid)])
     assert np.all(np.asarray(out[0][0]) == 0)  # all-null sums to 0
     assert np.all(np.asarray(out[1][0]) == 0)  # all-null counts to 0
     assert np.all(np.asarray(out[2][0]) == 0.0)
@@ -132,8 +123,7 @@ def test_fused_reduce_float_hilo_split(lowering):
     valid = jnp.asarray(rng.random(n) < 0.9)
     _diff_bucket_reduce(
         seg, 4, [], [],
-        [(jnp.asarray(precise), valid), (jnp.asarray(huge), valid)],
-        strategy=_strategy_of(lowering))
+        [(jnp.asarray(precise), valid), (jnp.asarray(huge), valid)])
 
 
 def test_fused_minmax_family_matches_per_column(lowering):
@@ -195,16 +185,11 @@ def _cmp_rows(lhs, rhs):
 def test_mixed_plan_fused_vs_per_column(lowering):
     """Exec-level differential for a mixed sum/count/min/max plan: the
     fused multi-column kernel vs the per-column baseline, same results on
-    all three lowerings (and fused single-program plan vs per-batch
-    paths). The sort lowering is selected the way users select it — the
-    sql.agg.strategy conf."""
+    both lowerings (and fused single-program plan vs per-batch paths)."""
     schema = schema_of(k=T.INT, a=T.LONG, b=T.DOUBLE)
     batches = _mk_batches(schema)
-    strategy = "SORT" if lowering == "sort" else "AUTO"
-    on = RapidsConf({"spark.rapids.tpu.sql.agg.fusedPlan": "ON",
-                     "spark.rapids.tpu.sql.agg.strategy": strategy})
-    off = RapidsConf({"spark.rapids.tpu.sql.agg.fusedPlan": "OFF",
-                      "spark.rapids.tpu.sql.agg.strategy": strategy})
+    on = RapidsConf({"spark.rapids.tpu.sql.agg.fusedPlan": "ON"})
+    off = RapidsConf({"spark.rapids.tpu.sql.agg.fusedPlan": "OFF"})
     fused_rows = _mixed_plan_exec(on, batches, schema).collect()
     prev = BR.FORCE_PER_COLUMN
     BR.FORCE_PER_COLUMN = True
@@ -215,11 +200,10 @@ def test_mixed_plan_fused_vs_per_column(lowering):
     _cmp_rows(fused_rows, percol_rows)
 
 
-def test_sort_lowering_dead_and_out_of_range_rows(lowering):
+def test_dead_and_out_of_range_rows_drop_out(lowering):
     """Out-of-range segment ids — padding rows at id B, dead rows past it,
-    and NEGATIVE ids — must drop out of every reduction under all three
-    lowerings (the sort lowering's boundary search must exclude both
-    tails)."""
+    and NEGATIVE ids — must drop out of every reduction under both
+    lowerings."""
     n = 257  # off the block/tile sizes on purpose
     rng = np.random.default_rng(31)
     seg_np = rng.integers(-3, 12, n).astype(np.int32)  # B=8: both tails
@@ -227,8 +211,7 @@ def test_sort_lowering_dead_and_out_of_range_rows(lowering):
     data = rng.integers(-(2**62), 2**62, n).astype(np.int64)
     valid = jnp.asarray(rng.random(n) < 0.7)
     out = _diff_bucket_reduce(
-        seg, 8, [(jnp.asarray(data), valid)], [valid], [],
-        strategy=_strategy_of(lowering))
+        seg, 8, [(jnp.asarray(data), valid)], [valid], [])
     v = np.asarray(valid)
     for b in range(8):
         m = (seg_np == b) & v
@@ -240,9 +223,9 @@ def test_sort_lowering_dead_and_out_of_range_rows(lowering):
         assert int(np.asarray(out[1][0])[b]) == int(m.sum())
 
 
-def test_three_lowerings_bit_identical_int_sums():
-    """Acceptance pin: MATMUL, SCATTER and SORT produce BIT-identical
-    integer sums and counts over the same inputs (incl. wraparound)."""
+def test_both_lowerings_bit_identical_int_sums():
+    """Acceptance pin: MATMUL and SCATTER produce BIT-identical integer
+    sums and counts over the same inputs (incl. wraparound)."""
     n = 600
     rng = np.random.default_rng(43)
     seg = jnp.asarray(rng.integers(0, 16, n).astype(np.int32))
@@ -250,23 +233,21 @@ def test_three_lowerings_bit_identical_int_sums():
              jnp.asarray(rng.random(n) < 0.8)) for _ in range(3)]
     cnts = [v for _, v in cols]
     outs = {}
-    for strat in ("SCATTER", "SORT"):
-        outs[strat] = BR.bucket_reduce(seg, 16, cols, cnts, [],
-                                       strategy=strat)
+    outs["SCATTER"] = BR.bucket_reduce(seg, 16, cols, cnts, [],
+                                       strategy="SCATTER")
     prev = BR.FORCE_MATMUL
     BR.FORCE_MATMUL = True
     try:
         outs["MATMUL"] = BR.bucket_reduce(seg, 16, cols, cnts, [])
     finally:
         BR.FORCE_MATMUL = prev
-    for strat in ("SORT", "MATMUL"):
-        for i in range(3):
-            np.testing.assert_array_equal(
-                np.asarray(outs["SCATTER"][0][i]),
-                np.asarray(outs[strat][0][i]))
-            np.testing.assert_array_equal(
-                np.asarray(outs["SCATTER"][1][i]),
-                np.asarray(outs[strat][1][i]))
+    for i in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(outs["SCATTER"][0][i]),
+            np.asarray(outs["MATMUL"][0][i]))
+        np.testing.assert_array_equal(
+            np.asarray(outs["SCATTER"][1][i]),
+            np.asarray(outs["MATMUL"][1][i]))
 
 
 # ---------------------------------------------------------------------------
@@ -278,54 +259,48 @@ def test_strategy_chooser_forced_and_auto_branches():
     ops = ("sum", "count", "count_star")
     exprs = (E.BoundReference(1, T.LONG, True),
              E.BoundReference(1, T.LONG, True), None)
-    keys = (T.INT,)
-    forced = RapidsConf({"spark.rapids.tpu.sql.agg.strategy": "SORT"})
-    s, why = choose_agg_strategy(forced, 1 << 20, ops, exprs, keys)
-    assert s == "SORT" and "forced" in why
+    forced = RapidsConf({"spark.rapids.tpu.sql.agg.strategy": "MATMUL"})
+    s, why = choose_agg_strategy(forced, 1 << 20, ops, exprs)
+    assert s == "MATMUL" and "forced" in why
+    # a lowering that went with the cost model is no value of the conf
+    with pytest.raises(ValueError, match="not in allowed values"):
+        RapidsConf({"spark.rapids.tpu.sql.agg.strategy": "SORT"})
     auto = RapidsConf({})
-    s, why = choose_agg_strategy(auto, 1 << 20, ops, exprs, keys,
-                                 backend="cpu")
+    s, why = choose_agg_strategy(auto, 1 << 20, ops, exprs, backend="cpu")
     assert s == "SCATTER" and "CPU backend" in why
-    # on an accelerator backend AUTO compares the derated-peak models;
-    # a wide aggregate (many limb columns) pushes the matmul cost up
-    # until the bandwidth-sized tiled radix lowering wins
+    # on an accelerator AUTO resolves MATMUL whatever the shape: the one
+    # lowering the v5e compiler takes at every capacity (RADIX is refused
+    # at small ones and sums floats to NaN where it compiles)
     wide_ops = tuple(["sum"] * 40)
     wide_exprs = tuple(E.BoundReference(i, T.LONG, True) for i in range(40))
-    s_wide, why_wide = choose_agg_strategy(
-        auto, 1 << 24, wide_ops, wide_exprs, keys, backend="tpu")
-    s_narrow, _ = choose_agg_strategy(
-        auto, 1 << 24, ("count_star",), (None,), keys, backend="tpu")
-    assert s_wide == "RADIX", why_wide
-    assert s_narrow == "MATMUL"
-    assert "est matmul" in why_wide and "radix" in why_wide
-    # exact float sums (variableFloatAgg off) keep RADIX out of AUTO:
-    # the bandwidth pick degrades to SORT, whose float sums stay on the
-    # order-preserving scatter path
-    fwide_ops = tuple(["sum"] * 40)
     fwide_exprs = tuple(E.BoundReference(i, T.DOUBLE, True)
                         for i in range(40))
-    s_f, why_f = choose_agg_strategy(
-        auto, 1 << 24, fwide_ops, fwide_exprs, keys, backend="tpu")
-    assert s_f == "SORT", why_f
+    for cap in (1 << 7, 1 << 11, 1 << 24):
+        for shape in ((wide_ops, wide_exprs), (wide_ops, fwide_exprs),
+                      (("count_star",), (None,))):
+            s_tpu, why_tpu = choose_agg_strategy(
+                auto, cap, *shape, backend="tpu")
+            assert s_tpu == "MATMUL" and "tpu backend" in why_tpu
     # CPU AUTO flips to RADIX at the byte-amplification capacity
     # threshold (the merge gate is XLA bytes, not shared-box wall clock)
     s_big, why_big = choose_agg_strategy(
-        auto, 1 << 24, ops, exprs, keys, backend="cpu")
+        auto, 1 << 24, ops, exprs, backend="cpu")
     assert s_big == "RADIX" and "amplif" in why_big
-    # the chooser reads the conf-declared roofline peaks (one peak
-    # source with the roofline report): a huge declared MXU peak makes
-    # the matmul model win the same wide shape RADIX just won
-    fast_mxu = RapidsConf(
-        {"spark.rapids.tpu.roofline.peakTflops": 197000.0})
-    s_conf, why_conf = choose_agg_strategy(
-        fast_mxu, 1 << 24, wide_ops, wide_exprs, keys, backend="tpu")
-    assert s_conf == "MATMUL", why_conf
-    assert "197000TF" in why_conf
+    # ... unless a float sum has to stay exact (variableFloatAgg off):
+    # RADIX's stream split is order-insensitive
+    s_exact, _ = choose_agg_strategy(
+        auto, 1 << 24, wide_ops, fwide_exprs, backend="cpu")
+    assert s_exact == "SCATTER"
+    s_approx, _ = choose_agg_strategy(
+        RapidsConf(
+            {"spark.rapids.tpu.sql.variableFloatAgg.enabled": True}),
+        1 << 24, wide_ops, fwide_exprs, backend="cpu")
+    assert s_approx == "RADIX"
 
 
 def test_strategy_visible_in_events_and_explain_metrics():
     sess = TpuSession({"spark.rapids.tpu.eventLog.enabled": True,
-                       "spark.rapids.tpu.sql.agg.strategy": "SORT"})
+                       "spark.rapids.tpu.sql.agg.strategy": "RADIX"})
     n = 64
     data = {"k": [i % 4 for i in range(n)], "v": list(range(n))}
     schema = schema_of(k=T.INT, v=T.LONG)
@@ -336,13 +311,13 @@ def test_strategy_visible_in_events_and_explain_metrics():
         for k in range(4))
     evs = [r for r in sess.events.records()
            if r["event"] == "agg_strategy"]
-    assert evs and evs[0]["strategy"] == "SORT"
+    assert evs and evs[0]["strategy"] == "RADIX"
     assert "forced" in evs[0]["reason"]
-    assert "strategy=SORT" in sess.explain_metrics()
+    assert "strategy=RADIX" in sess.explain_metrics()
     # the analyzer's forecast note names the same strategy (explain)
     df = sess.create_dataframe(data, schema).group_by("k").agg(
         A.agg(A.Sum(col("v")), "s"))
-    assert "agg strategy: SORT" in df.explain()
+    assert "agg strategy: RADIX" in df.explain()
     sess.close()
 
 
@@ -367,10 +342,10 @@ def test_auto_strategy_resolution_does_not_double_compile():
     rows2 = again.collect()
     assert exec_base.compile_miss_count() == before2
     _cmp_rows(rows1, rows2)
-    # and a SORT-forced plan is a DIFFERENT program (one fresh compile),
+    # and a RADIX-forced plan is a DIFFERENT program (one fresh compile),
     # not a silent reuse of the scatter executable
     forced = RapidsConf({"spark.rapids.tpu.sql.agg.fusedPlan": "ON",
-                         "spark.rapids.tpu.sql.agg.strategy": "SORT"})
+                         "spark.rapids.tpu.sql.agg.strategy": "RADIX"})
     sorted_agg = _mixed_plan_exec(forced, batches, schema)
     before3 = exec_base.compile_miss_count()
     rows3 = sorted_agg.collect()

@@ -227,7 +227,7 @@ def test_conf_fingerprint_ignores_observability_confs(tmp_path):
                             "spark.rapids.tpu.metrics.http.enabled": True}))
     assert fp(a) == fp(b), "observability confs must not shatter the key"
     c = RapidsConf(_conf(tmp_path,
-                         **{"spark.rapids.tpu.sql.agg.strategy": "SORT"}))
+                         **{"spark.rapids.tpu.sql.agg.strategy": "RADIX"}))
     assert fp(a) != fp(c), "engine-shaping confs must key apart"
 
 

@@ -2,8 +2,8 @@
 lowerings (round 12: kill the 25x byte amplification).
 
 Coverage, per the issue checklist:
-  * the five-strategy differential matrix — MATMUL / SCATTER / SORT /
-    RADIX (+ PALLAS via interpret mode off-TPU) — over the torture set:
+  * the four-strategy differential matrix — MATMUL / SCATTER / RADIX
+    (+ PALLAS via interpret mode off-TPU) — over the torture set:
     int64 wraparound, all-null columns, the float hi/lo + NORMAL/BIG
     stream splits (incl. inf/NaN/huge magnitudes), dead and negative
     segment ids;
@@ -48,14 +48,14 @@ from spark_rapids_tpu.sql import TpuSession
 
 from harness import assert_tpu_and_cpu_equal
 
-STRATEGIES = ("SCATTER", "MATMUL", "SORT", "RADIX", "PALLAS")
+STRATEGIES = ("SCATTER", "MATMUL", "RADIX", "PALLAS")
 #: strategies whose float sums are exact f64 accumulations (vs the
 #: order-insensitive f32 hi/lo decompositions of MATMUL/PALLAS)
-_TIGHT_FLOAT = {"SCATTER", "SORT", "RADIX"}
+_TIGHT_FLOAT = {"SCATTER", "RADIX"}
 
 
 # ---------------------------------------------------------------------------
-# ops-level five-strategy matrix over groupby_agg
+# ops-level four-strategy matrix over groupby_agg
 # ---------------------------------------------------------------------------
 def _groups_of(keys, aggs, nseg):
     """{key tuple -> ((value, valid), ...)} over the live segments, so
@@ -203,6 +203,59 @@ def test_matrix_float_magnitude_disparity_across_groups():
     assert got[1] == 6.0 and got[2] == -4.5, got
     np.testing.assert_allclose(got[0], 3.5e30, rtol=1e-12)
     np.testing.assert_allclose(got[3], 3e-20, rtol=1e-12)
+
+
+#: the BIG stream's constants as a TPU reads them: the f64 it emulates
+#: has f32's exponent range, so 2^500 and 2^600 are inf and 2^-600 is 0
+AS_THE_CHIP_READS = {"F64_BIG": float("inf"), "BIG_SCALE_DOWN": 0.0,
+                     "BIG_SCALE_UP": float("inf")}
+COMBINE_CASES = {
+    # (normal, big, flags) -> the sum with f64's constants, and with the
+    # chip's where that differs (a BIG stream it could never have filled)
+    "nothing_big": ([1.0, -2.5, 0.0, 3.0e30], [0.0] * 4, [0] * 4,
+                    [1.0, -2.5, 0.0, 3.0e30], None),
+    "a_big_addend": ([1.0, 2.0], [2.0 ** -50, 0.0], [0, 0],
+                     [2.0 ** 550 + 1.0, 2.0], [float("inf"), 2.0]),
+    "the_flags_outrank_the_sum": (
+        [1.0] * 5, [0.0] * 5, [1, 2, 4, 3, 0],
+        [float("inf"), float("-inf"), float("nan"), float("nan"), 1.0],
+        None),
+}
+
+
+@pytest.mark.parametrize("chip", [False, True], ids=["f64", "as_the_chip"])
+@pytest.mark.parametrize("case", sorted(COMBINE_CASES))
+def test_combine_float_sum_rescales_only_what_the_big_stream_holds(
+        case, chip, monkeypatch):
+    """PR 25's NaN: ``normal + big * 2^600`` was ``x + 0 * inf`` on the
+    chip, for every group of every RADIX float sum."""
+    if chip:
+        for name, value in AS_THE_CHIP_READS.items():
+            monkeypatch.setattr(RBX, name, value)
+    normal, big, flags, want, want_chip = COMBINE_CASES[case]
+    got = np.asarray(RBX.combine_float_sum(
+        jnp.asarray(normal), jnp.asarray(big),
+        jnp.asarray(flags, jnp.uint8)))
+    np.testing.assert_array_equal(
+        got, np.asarray(want_chip if chip and want_chip else want))
+
+
+def test_radix_float_sums_are_no_nan_with_the_chips_constants(monkeypatch):
+    """The whole RADIX groupby with the constants as the chip reads them
+    (the merge of cell 4's partials at capacity 2048: 100 groups)."""
+    for name, value in AS_THE_CHIP_READS.items():
+        monkeypatch.setattr(RBX, name, value)
+    cap = 2048
+    rng = np.random.default_rng(1)
+    key = rng.integers(1, 101, cap)
+    val = np.round(rng.random(cap) * 100, 2)
+    ok = np.ones(cap, bool)
+    got = _groups_of(*_run_strategy("RADIX", key, [(val, ok)], cap, ["sum"]))
+    want = np.bincount(key, weights=val, minlength=101)
+    assert sorted(k for (k,) in got) == list(range(1, 101))
+    for (k,), ((s, valid),) in got.items():
+        assert valid and not np.isnan(s)
+        np.testing.assert_allclose(s, want[k], rtol=1e-12)
 
 
 def test_matrix_dead_rows_never_contribute():

@@ -231,7 +231,7 @@ def test_split_floor_raises():
 # 3. five-strategy aggregation matrix under forced splits
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize(
-    "strategy", ["MATMUL", "SCATTER", "SORT", "RADIX", "PALLAS"])
+    "strategy", ["MATMUL", "SCATTER", "RADIX", "PALLAS"])
 def test_agg_strategies_row_exact_under_forced_splits(strategy):
     n = 1000  # non-pow2; capacity bucket 1024 > the >256 fault threshold
     data = {
