@@ -24,7 +24,7 @@ from ..conf import (
     SHUFFLE_COMPRESSION_CODEC,
     SHUFFLE_TRANSPORT_CLASS,
 )
-from ..expr.eval import ColV, StrV, Val
+from ..expr.eval import ColV, DictV, StrV, Val
 from ..ops import concat as concat_ops
 from ..ops import filter_gather
 from ..ops.sort import max_string_len
@@ -129,6 +129,49 @@ def _piece_slicer(sig: tuple, pcap: int, ccaps: Tuple[int, ...]):
 
     return cached_pipeline(_SLICE_CACHE, key, None, build,
                            max_entries=1024)
+
+
+_PREFIX_CACHE: Dict[tuple, object] = {}
+
+
+def _live_prefix(batch: ColumnarBatch, live_cap: int) -> ColumnarBatch:
+    """The first ``live_cap`` slots of every plane of a batch whose live
+    rows (a dense prefix, by the batch's contract) fit that bucket: what
+    the map side partitions where a batch holds fewer rows than it was
+    given slots (a ``PARTIAL`` aggregate's 100 groups at the capacity of
+    its stacked row groups). A static slice, so no gather; a string keeps
+    its byte pool, a dictionary column its dictionary, with the byte bound
+    of its expansion cut to what ``live_cap`` rows can hold."""
+    key = (batch_signature(batch), live_cap)
+
+    def build():
+        @program("exchange_slice")
+        def run(cols):
+            with jax.named_scope(EXCHANGE_SCOPE_WORDS[0]):
+                out = []
+                for v in cols:
+                    if isinstance(v, StrV):
+                        out.append(StrV(v.offsets[:live_cap + 1], v.chars,
+                                        v.validity[:live_cap]))
+                    elif isinstance(v, DictV):
+                        out.append(DictV(
+                            v.codes[:live_cap], v.dictionary,
+                            v.validity[:live_cap],
+                            min(v.mat_cap, choose_capacity(
+                                max(1, live_cap * v.max_len), 128)),
+                            v.max_len, v.unique))
+                    else:
+                        out.append(ColV(v.data[:live_cap],
+                                        v.validity[:live_cap]))
+                return out
+
+        return jax.jit(run)
+
+    from .base import cached_pipeline
+
+    fn = cached_pipeline(_PREFIX_CACHE, key, None, build, max_entries=1024)
+    return batch_from_vals(
+        fn(vals_of_batch(batch)), batch.schema, batch.num_rows)
 
 
 def _vals_signature(vals: Sequence[Val]) -> tuple:
@@ -378,7 +421,7 @@ class TpuShuffleExchangeExec(TpuExec):
             P = self.num_partitions
             self.partition_rows = [0] * P
             wrote0 = self.transport.bytes_written()
-            inputs = 0
+            inputs = slots = cut = 0
             with self.op_timed("map", partitions=P) as span, \
                     named_oom(f"{self.node_name}.map"):
                 # exchange map-side staging (partition sort + piece
@@ -389,6 +432,21 @@ class TpuShuffleExchangeExec(TpuExec):
                     if not batch.columns:
                         continue
                     inputs += 1
+                    # the map program sorts and gathers every slot it is
+                    # given, so it is given the bucket of the rows the
+                    # batch HOLDS: an aggregate's or a filter's output
+                    # keeps its input's capacity for a handful of rows.
+                    # Where the count is a device scalar this is the
+                    # wait for the child's program that the pull of the
+                    # offsets made, one dispatch earlier
+                    n = batch.num_rows
+                    if n == 0:
+                        continue
+                    live_cap = choose_capacity(n, self.conf.shape_bucket_min)
+                    if live_cap < batch.capacity:
+                        batch = _live_prefix(batch, live_cap)
+                        cut += 1
+                    slots += batch.capacity
                     # dict-encoded columns materialize at the shuffle
                     # boundary: pieces serialize/slice the plain Arrow
                     # layout and peers don't share dictionaries
@@ -424,9 +482,12 @@ class TpuShuffleExchangeExec(TpuExec):
                         # feeding ShuffledBatchRDD's partition specs)
                         self.partition_rows[j] += b - a
                 # what the map side handed the transport: the pieces'
-                # bytes and rows over all of its input batches
+                # bytes and rows over all of its input batches; and what
+                # its program partitioned: the slots, and the inputs it
+                # took under their capacity
                 span.set(bytes=self.transport.bytes_written() - wrote0,
-                         rows=sum(self.partition_rows), inputs=inputs)
+                         rows=sum(self.partition_rows), inputs=inputs,
+                         slots=slots, cut=cut)
             self.metrics[DATA_SIZE].set(self.transport.bytes_written())
             self._note_transport_stats()
             self._map_done = True

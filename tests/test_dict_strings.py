@@ -237,15 +237,24 @@ def test_filter_project_keeps_dict_then_collects(dict_mode):
     assert proj.collect() == expect
 
 
-def test_dict_key_through_exchange(dict_mode):
+@pytest.mark.parametrize("slots", [None, 2048])
+def test_dict_key_through_exchange(dict_mode, slots):
+    # ``slots``: the batch's capacity where it is not the bucket of its
+    # rows (the map side then cuts the codes to the bucket BEFORE the
+    # dictionary is expanded), with the count a device scalar
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.columnar.column import HostColumn
+
     n = 120
     rng = random.Random(41)
     keys = [rng.choice(POOL[:5]) for _ in range(n)]
     vals = [rng.randrange(1000) for _ in range(n)]
     schema = schema_of(k=T.STRING, v=T.LONG)
     batch = ColumnarBatch(
-        [dict_column_from_pylist(keys, T.STRING),
-         column_from_pylist(vals, T.LONG)], schema, n)
+        [dict_column_from_pylist(keys, T.STRING, capacity=slots),
+         HostColumn.from_pylist(vals, T.LONG).to_device(slots)],
+        schema, jnp.int32(n) if slots else n)
     P = 4
     ex = TpuShuffleExchangeExec(
         CONF, InMemoryScanExec(CONF, [[batch]], schema),

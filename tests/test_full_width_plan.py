@@ -287,6 +287,9 @@ def test_exchange_spans_carry_bytes_rows_and_partitions(traced):
     (mapped,) = _named(spans, EXCHANGE + ".map")
     assert mapped["partitions"] == 4 and mapped["inputs"] == 4
     assert mapped["rows"] == 4 * 100 and mapped["bytes"] > 0
+    # each PARTIAL hands its 100 groups over at its row group's capacity:
+    # the map program partitions their bucket, so every input is cut
+    assert mapped["slots"] == 4 * 128 and mapped["cut"] == 4
     reduced = _named(spans, EXCHANGE + ".reduce")
     assert sum(s["rows"] for s in reduced) == mapped["rows"]
     assert sum(s["bytes"] for s in reduced) == mapped["bytes"]

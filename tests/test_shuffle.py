@@ -269,3 +269,166 @@ def test_shuffle_partitions_conf_sets_reducer_count():
         .group_by("k").agg(A.agg(A.Count(), "c")).collect()
     )
     compare_rows(out1, out)
+
+
+# ---------------------------------------------------------------------------
+# the map side partitions the rows a batch holds, not the slots it was given
+# ---------------------------------------------------------------------------
+_LIVE_SCHEMA = T.StructType([
+    T.StructField("i", T.INT), T.StructField("d", T.DOUBLE),
+    T.StructField("n", T.LONG), T.StructField("s", T.STRING)])
+_LIVE_ROWS = 150   # bucket 256
+_GIVEN_SLOTS = 4096
+
+
+def _live_batch(capacity=None, lazy=False, rows=_LIVE_ROWS):
+    """``rows`` rows of an int, a float64, a nullable and a string column
+    at ``capacity`` slots (their own bucket by default), the count a
+    device scalar where ``lazy``: an aggregate's or a filter's output."""
+    from spark_rapids_tpu.columnar.column import HostColumn
+
+    rnd = random.Random(5)
+    data = {
+        "i": [rnd.randint(-40, 40) for _ in range(rows)],
+        "d": [rnd.random() * 1e6 for _ in range(rows)],
+        "n": [rnd.randint(0, 9) if rnd.random() > 0.3 else None
+              for _ in range(rows)],
+        "s": [rnd.choice(["", "alpha", "βήτα", "w" * 70, None])
+              for _ in range(rows)],
+    }
+    cols = [HostColumn.from_pylist(data[f.name], f.dataType)
+            .to_device(capacity, name=f.name) for f in _LIVE_SCHEMA.fields]
+    return ColumnarBatch(cols, _LIVE_SCHEMA,
+                         jnp.int32(rows) if lazy else rows)
+
+
+def _live_partitioning(kind):
+    from spark_rapids_tpu.ops.sort import SortOrder
+
+    return {
+        "hash": lambda: HashPartitioning([0, 3], 5),
+        "range": lambda: RangePartitioning(
+            [0, 3], [SortOrder(True, None), SortOrder(False, None)], 4),
+        "round_robin": lambda: RoundRobinPartitioning(3),
+        "single": lambda: SinglePartitioning(),
+    }[kind]()
+
+
+class _Counts:
+    """Stands in for a section's span: keeps the counts set on it."""
+    on = True
+
+    def __init__(self):
+        self.counts = {}
+
+    def set(self, **counts):
+        self.counts.update(counts)
+
+
+def _exchanged(kind, batch, monkeypatch):
+    """(rows of every reduce partition in order, the map span's counts,
+    the keys the map side asked ``jit_exchange`` by, the slots of the
+    planes ``partition_cols`` was handed) of one batch through an
+    exchange of its own."""
+    import contextlib
+
+    from spark_rapids_tpu.conf import RapidsConf
+    from spark_rapids_tpu.exec import InMemoryScanExec
+    from spark_rapids_tpu.exec import exchange as X
+    from spark_rapids_tpu.expr.values import val_capacity
+
+    conf = RapidsConf({})
+    ex = X.TpuShuffleExchangeExec(
+        conf, InMemoryScanExec(conf, [[batch]], _LIVE_SCHEMA),
+        _live_partitioning(kind))
+    spans, keys, slots = {}, [], []
+    real_map_fn, real_partition = X.TpuShuffleExchangeExec._map_fn, \
+        X.partition_cols
+
+    def map_fn(self, sig, cap, schema, sml):
+        keys.append((sig, cap, self.num_partitions, sml, schema,
+                     self._part_cache_key()))
+        return real_map_fn(self, sig, cap, schema, sml)
+
+    def partition(cols, pids, num_rows, P):
+        slots.append({val_capacity(c) for c in cols} | {pids.shape[0]})
+        return real_partition(cols, pids, num_rows, P)
+
+    with monkeypatch.context() as m:
+        m.setattr(ex, "op_timed", lambda section="", *a, **k:
+                  contextlib.nullcontext(spans.setdefault(section, _Counts())))
+        m.setattr(X.TpuShuffleExchangeExec, "_map_fn", map_fn)
+        m.setattr(X, "partition_cols", partition)
+        X._MAP_CACHE.clear()  # the map program is traced under the spy
+        try:
+            parts = [[r for b in ex.execute_partition(p) for r in b.to_rows()]
+                     for p in range(ex.num_partitions)]
+        finally:
+            X._MAP_CACHE.clear()
+    return parts, spans.get("map", _Counts()).counts, keys, slots
+
+
+_KINDS = ["hash", "range", "round_robin", "single"]
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_exchange_of_a_sparse_batch_is_the_exchange_of_its_rows(
+        kind, monkeypatch):
+    # few live rows at a large capacity, the count a device scalar: the
+    # same rows reach the same partitions in the same order as from a
+    # batch of their own bucket
+    own, own_counts, _, _ = _exchanged(kind, _live_batch(), monkeypatch)
+    sparse, counts, keys, _ = _exchanged(
+        kind, _live_batch(_GIVEN_SLOTS, lazy=True), monkeypatch)
+    assert sum(len(p) for p in sparse) == _LIVE_ROWS
+    for got, want in zip(sparse, own):
+        compare_rows(want, got, ignore_order=False)
+    assert counts["cut"] == 1 and own_counts["cut"] == 0
+    assert counts["slots"] == own_counts["slots"] == 256
+    assert counts["rows"] == _LIVE_ROWS and counts["inputs"] == 1
+    assert [k[1] for k in keys] == [256]
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_exchange_of_a_dense_batch_resolves_the_key_it_did(
+        kind, monkeypatch):
+    from spark_rapids_tpu.exec import exchange as X
+    from spark_rapids_tpu.exec.base import batch_signature
+
+    monkeypatch.setattr(X, "_live_prefix", None)  # not to be called
+    for rows, lazy in ((256, False), (_LIVE_ROWS, True)):
+        batch = _live_batch(lazy=lazy, rows=rows)
+        # the key as the map side built it before it looked at the count
+        before = (batch_signature(batch), batch.capacity)
+        parts, counts, keys, _ = _exchanged(kind, batch, monkeypatch)
+        assert sum(len(p) for p in parts) == rows
+        assert [k[:2] for k in keys] == [before]
+        assert counts["cut"] == 0 and counts["slots"] == batch.capacity
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_exchange_of_a_batch_of_no_rows_writes_no_piece(kind, monkeypatch):
+    from spark_rapids_tpu.shuffle.transport import DeviceShuffleTransport
+
+    wrote = []
+    monkeypatch.setattr(DeviceShuffleTransport, "write",
+                        lambda self, *a: wrote.append(a))
+    batch = _live_batch(_GIVEN_SLOTS, rows=0)
+    batch = ColumnarBatch(batch.columns, _LIVE_SCHEMA, jnp.int32(0))
+    parts, counts, keys, slots = _exchanged(kind, batch, monkeypatch)
+    assert not any(parts) and not wrote
+    assert not keys and not slots  # and runs no program for it
+    assert counts == {"bytes": 0, "rows": 0, "inputs": 1, "slots": 0,
+                      "cut": 0}
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_partition_cols_is_handed_the_bucket_of_the_rows_held(
+        kind, monkeypatch):
+    # the case that goes red if the cut is lost: every plane the sort and
+    # the gather see has choose_capacity(rows) slots, not the batch's
+    from spark_rapids_tpu.columnar.column import choose_capacity
+
+    _, _, _, slots = _exchanged(
+        kind, _live_batch(_GIVEN_SLOTS, lazy=True), monkeypatch)
+    assert slots == [{choose_capacity(_LIVE_ROWS)}]

@@ -26,6 +26,9 @@ from tpu_compile_asks import (  # noqa: F401  (fixtures)
 CELL = "store_sales_full.quantity_report"
 #: capacity of the final merge: two splits x 100 groups, rounded up
 FINAL_CAP = 256
+#: slots the exchange's map program partitions: the bucket of a partial's
+#: 100 groups, whatever capacity the partial was left at
+MAP_CAP = 128
 
 
 @pytest.fixture(scope="module")
@@ -86,6 +89,25 @@ def test_final_merge_of_the_exchanged_partials_compiles_for_v5e(
     ((secs, mem),) = compile_all(programs, one_chip)
     assert mem.temp_size_in_bytes < HBM_BYTES // 64
     assert secs < 120, secs
+
+
+def test_map_programs_partition_the_bucket_of_a_partials_groups(
+        full_programs):
+    """What cell 4's exchange costs rests on this: a ``PARTIAL`` stage
+    leaves its 100 groups at the capacity of its stacked row groups (here
+    one of 16,384 rows, on the chip 2^25 and 2^24 slots), and the map
+    program sorts and gathers every slot it is handed."""
+    programs = full_programs.get("exchange")
+    assert programs and len(programs) == 2, sorted(full_programs)  # a split
+    for fn, args, kw in programs:
+        caps = {x.shape[0] for x in jax.tree.leaves(args) if x.ndim == 1}
+        assert caps == {MAP_CAP}, caps
+    # and the cut that hands it over is a ``jit_exchange_slice`` of its own
+    cuts = [args for fn, args, kw in full_programs["exchange_slice"]
+            if len(args) == 1]
+    assert len(cuts) == 2
+    for args in cuts:
+        assert {x.shape[0] for x in jax.tree.leaves(args)} == {16384}
 
 
 @pytest.mark.parametrize("word", [
