@@ -1086,7 +1086,8 @@ class TpuHashAggregateExec(TpuExec):
             with self.section("merge.eval"):
                 return self._evaluate(merged)
 
-        with self._agg_timed("merge"):
+        with self._agg_timed("merge") as span:
+            span.set(partials=len(partials))
             # the merge consumes compacted partials (group-cardinality
             # sized, not input sized) — not meaningfully splittable, so
             # it gets the retry-only harness: spill + backoff, then the

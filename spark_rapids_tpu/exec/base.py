@@ -90,11 +90,12 @@ _PIPELINE_CACHE_LOCK = ordered_lock("exec.pipeline_cache", reentrant=True)
 #: identity, O(1) via the id set) — the clear_pipeline_caches() sweep
 #: set. Most caches are module globals (8 across the engine) and always
 #: register: a process keeps them for good, so the sweep has to reach
-#: them. sort/window/join/exchange also route per-INSTANCE
-#: ``self._jits`` dicts through here (``per_instance=True``), and
-#: registering those forever would pin every exec instance's compiled
-#: executables for the process lifetime (dicts aren't weakref-able):
-#: they are BOUNDED. Past the cap a per-instance dict simply isn't
+#: them. window and join also route per-INSTANCE ``self._jits`` dicts
+#: through here (``per_instance=True``; the exchange's and the sort's
+#: are process-wide since PRs 35 and 37), and a registered dict pins its
+#: exec's compiled executables and, through the program's closure, the
+#: exec's whole plan for the process lifetime (dicts aren't
+#: weakref-able): they are BOUNDED. Past the cap a per-instance dict simply isn't
 #: registered — it stays collectable with its owner, and the sweep (a
 #: test/maintenance helper) loses nothing it needs: a fresh session
 #: builds fresh exec instances anyway.

@@ -327,13 +327,14 @@ class TpuShuffleExchangeExec(TpuExec):
 
         return cached_pipeline(_MAP_CACHE, key, "exchange", build)
 
-    def _sample_range_bounds(self, parts: List[List[ColumnarBatch]]) -> None:
+    def _sample_range_bounds(self, parts: List[List[ColumnarBatch]]) -> int:
         """Sample key values host-side and set the range bounds
-        (reference: GpuRangePartitioner.sketch/determineBounds)."""
+        (reference: GpuRangePartitioner.sketch/determineBounds). Returns
+        the number of rows sampled."""
         part = self.partitioning
         assert isinstance(part, RangePartitioning)
         if part.bounds is not None:
-            return
+            return 0
         from ..cpu.plan import _SparkOrderKey
 
         from .base import vals_of_batch
@@ -372,7 +373,7 @@ class TpuShuffleExchangeExec(TpuExec):
         P = part.num_partitions
         if not samples:
             part.bounds = [[None] * (P - 1) for _ in part.key_indices]
-            return
+            return 0
         orders = part.orders
         samples.sort(key=lambda row: tuple(
             _SparkOrderKey(v, o.ascending, o.nulls_first_resolved)
@@ -386,6 +387,7 @@ class TpuShuffleExchangeExec(TpuExec):
             [row[k] for row in bounds_rows]
             for k in range(len(part.key_indices))
         ]
+        return len(samples)
 
     def _run_map_side(self) -> None:
         with self._map_lock:
@@ -406,7 +408,13 @@ class TpuShuffleExchangeExec(TpuExec):
                     list(child.execute_partition(p))
                     for p in range(child.num_partitions)
                 ]
-                self._sample_range_bounds(parts)
+                # the child has run; what follows is the sampling's own:
+                # a gather and a pull an input, and the host's sort
+                with self.op_timed("sample") as span:
+                    samples = self._sample_range_bounds(parts)
+                    span.set(samples=samples,
+                             inputs=sum(len(bs) for bs in parts),
+                             bounds=self.num_partitions - 1)
                 batch_iter = [
                     (p, b) for p, bs in enumerate(parts) for b in bs
                 ]
@@ -422,7 +430,8 @@ class TpuShuffleExchangeExec(TpuExec):
             self.partition_rows = [0] * P
             wrote0 = self.transport.bytes_written()
             inputs = slots = cut = 0
-            with self.op_timed("map", partitions=P) as span, \
+            with self.op_timed("map", partitions=P,
+                               kind=self.partitioning.kind) as span, \
                     named_oom(f"{self.node_name}.map"):
                 # exchange map-side staging (partition sort + piece
                 # slicing) sits outside the per-batch retry harness: a

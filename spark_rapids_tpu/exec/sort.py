@@ -9,7 +9,7 @@ radix-key bitonic sort; batches within a partition concatenate first
 """
 from __future__ import annotations
 
-from typing import Iterator, List, Sequence, Tuple
+from typing import Dict, Iterator, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,13 @@ from .base import (
 from .join import _concat_all
 
 
+#: the sort's programs, process-wide: a sort exec is built anew for every
+#: query's plan, and a cache of its own would re-trace (and count a compile
+#: miss for) the same program in every query — and, registered with the
+#: pipeline caches' sweep, would keep that query's whole plan alive
+_SORT_CACHE: Dict[tuple, object] = {}
+
+
 class TpuSortExec(TpuExec):
     def __init__(
         self,
@@ -52,7 +59,6 @@ class TpuSortExec(TpuExec):
         self._bound = [
             E.bind_references(e, child.output_schema) for e in self.sort_exprs
         ]
-        self._jits = {}
 
     @property
     def output_schema(self) -> StructType:
@@ -103,23 +109,28 @@ class TpuSortExec(TpuExec):
         program)."""
         cap = batch.capacity
         sml = self._str_lens(batch)
+        # the program closes over what its key states and not over this
+        # exec, which belongs to one query's plan
+        bound, orders = tuple(self._bound), tuple(self.orders)
 
-        @program("sort")
-        def run(cols, num_rows):
-            live = filter_gather.live_of(num_rows, cap)
-            keys = [lower(b, cols, cap) for b in self._bound]
-            perm = sort_permutation(
-                keys, [b.dtype for b in self._bound], self.orders, live, sml)
-            live_sorted = jnp.take(live, perm, mode="clip")
-            return filter_gather.gather(cols, perm, live_sorted)
+        def build():
+            @program("sort")
+            def run(cols, num_rows):
+                live = filter_gather.live_of(num_rows, cap)
+                keys = [lower(b, cols, cap) for b in bound]
+                perm = sort_permutation(
+                    keys, [b.dtype for b in bound], orders, live, sml)
+                live_sorted = jnp.take(live, perm, mode="clip")
+                return filter_gather.gather(cols, perm, live_sorted)
 
-        key = (batch_signature(batch), cap, sml)
+            return jax.jit(run)
+
+        key = (bound, orders, batch_signature(batch), cap, sml)
         # the shared pipeline-cache guard: miss accounting + the
         # compiled-program cost plane ride cached_pipeline (xla_cost.py)
         from .base import cached_pipeline
 
-        fn = cached_pipeline(self._jits, key, "sort",
-                             lambda: jax.jit(run), per_instance=True)
+        fn = cached_pipeline(_SORT_CACHE, key, "sort", build)
         vals = fn(
             vals_of_batch(batch), count_scalar(batch.num_rows_lazy))
         return batch_from_vals(

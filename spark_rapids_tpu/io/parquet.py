@@ -288,7 +288,7 @@ class ParquetScanner:
         # not re-pay the footer parse / mmap it is cached to avoid
         # (the dict-strings flag is part of the key: the two layouts must
         # never serve each other's cached batches)
-        cache, keys, batches = _probe_scan_cache(
+        cache, keys, batches, asker = _probe_scan_cache(
             self.conf, s, file_cols, "batch-dict" if dict_strings else "batch")
         if all(b is not None for b in batches):
             return batches, s.partition_values
@@ -305,7 +305,7 @@ class ParquetScanner:
                 # safe — outstanding decode tasks drop their results)
                 return None, s.partition_values
             if cache is not None:
-                cache.put(keys[j], b, b.device_memory_size())
+                cache.put(keys[j], b, b.device_memory_size(), by=asker)
             batches[j] = b
         return batches, s.partition_values
 
@@ -331,7 +331,7 @@ class ParquetScanner:
         file_cols = [c for c in self.columns if c not in split_pcols(s)]
         nfields = [f for f in self.schema.fields if f.name in file_cols]
         # probe the cache BEFORE opening the file (see read_split_device)
-        cache, keys, out = _probe_scan_cache(
+        cache, keys, out, asker = _probe_scan_cache(
             self.conf, s, file_cols, "stage-dict" if dict_strings else "stage")
         if all(x is not None for x in out):
             return out
@@ -348,30 +348,35 @@ class ParquetScanner:
                 nbytes = sum(
                     int(a.size) * a.dtype.itemsize
                     for (args, _, _, _) in stage[2] for a in args)
-                cache.put(keys[i], stage, nbytes)
+                cache.put(keys[i], stage, nbytes, by=asker)
             out[i] = stage
         return out
 
 
 
 def _probe_scan_cache(conf, split: FileSplit, file_cols, layout: str):
-    """(cache or None, keys, one cached value or None per row group). The
-    lookup is a span of the scan exec above, with what it found: a later
-    reader tells a warm scan from a cold one by ``cache=hit|miss``."""
-    from ..exec.base import phase
+    """(cache or None, keys, one cached value or None per row group, who
+    asks). The lookup is a span of the scan exec above, with what it
+    found: a later reader tells a warm scan from a cold one by
+    ``cache=hit|miss``. Who asks is the query and this split of it: the
+    puts of the query's other splits will not evict what this one is
+    handed (``scan_cache``'s docstring)."""
+    from ..exec.base import current_query, phase
     from .scan_cache import DeviceScanCache, file_key
 
     cache = DeviceScanCache.get_instance(conf)
     if cache is None:
-        return None, None, [None] * len(split.row_groups)
+        return None, None, [None] * len(split.row_groups), None
+    qid = current_query()
+    asker = None if qid is None else (qid, (split.path, split.row_groups))
     with phase("cache_lookup") as span:
         keys = [file_key(split.path, rg, file_cols, layout)
                 for rg in split.row_groups]
-        found = [cache.get(k) for k in keys]
+        found = [cache.get(k, by=asker) for k in keys]
         hits = sum(x is not None for x in found)
         span.set(cache="hit" if hits == len(found) else "miss",
                  hits=hits, lookups=len(found))
-    return cache, keys, found
+    return cache, keys, found, asker
 
 
 def _open_mapped(path: str):

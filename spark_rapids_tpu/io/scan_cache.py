@@ -10,7 +10,13 @@ for free from the OS page cache.
 Keys carry (path, mtime, size), so a rewritten file never serves stale
 data. Values are opaque (the scanner stores whatever it rebuilds per row
 group); byte accounting is supplied by the caller. Eviction is LRU under
-a conf byte budget.
+a conf byte budget, with one exception: a split's puts never evict what
+ANOTHER split of the same query has asked for (``by`` = the query and the
+part of it that asks). A scan that comes round again is the opposite of
+what LRU is for: a table past the budget, scanned a split at a time,
+evicted in one split what the other had just been handed and would ask
+for again, and kept 11 of 29 row groups where the budget holds 20. Within
+one split (and for a caller that names no query) the order is plain LRU.
 """
 from __future__ import annotations
 
@@ -45,6 +51,9 @@ class DeviceScanCache:
         #: HBM ledger is unarmed (the zero-overhead-off path)
         self._entries: "OrderedDict[tuple, Tuple[Any, int, Any]]" = \
             OrderedDict()
+        #: key -> (query, part of it) that last asked for the entry or
+        #: put it; None where the caller named no query
+        self._asked_by: Dict[tuple, Any] = {}
         self._bytes = 0
         self.hits = 0
         self.misses = 0
@@ -76,15 +85,20 @@ class DeviceScanCache:
         with self._lock:
             self.max_bytes = int(max_bytes)
             while self._bytes > self.max_bytes and self._entries:
-                _, (_, sz, lid) = self._entries.popitem(last=False)
-                self._bytes -= sz
-                self.evictions += 1
-                if lid is not None:
-                    _ledger().note_free(lid, reason="evict")
-                if _events.enabled():
-                    _events.emit("scan_cache", op="evict", bytes=sz)
-                if _obs.enabled():
-                    self._obs_note("evict", sz)
+                self._evict(next(iter(self._entries)))
+
+    def _evict(self, key: tuple) -> None:
+        """Drop one entry as an eviction (under ``self._lock``)."""
+        _, sz, lid = self._entries.pop(key)
+        self._asked_by.pop(key, None)
+        self._bytes -= sz
+        self.evictions += 1
+        if lid is not None:
+            _ledger().note_free(lid, reason="evict")
+        if _events.enabled():
+            _events.emit("scan_cache", op="evict", bytes=sz)
+        if _obs.enabled():
+            self._obs_note("evict", sz)
 
     def _obs_note(self, op: str, nbytes: int) -> None:
         """Mirror one cache op into the live registry (called under
@@ -112,7 +126,10 @@ class DeviceScanCache:
         with cls._instance_lock:
             cls._instance = None
 
-    def get(self, key: tuple) -> Optional[Any]:
+    def get(self, key: tuple, by: Any = None) -> Optional[Any]:
+        """``by`` = (query, part) names who asks (the query of
+        ``exec.base.current_query`` and the split of its scan): the puts
+        of the query's other parts will not evict what is handed here."""
         with self._lock:
             hit = self._entries.get(key)
             if hit is None:
@@ -123,6 +140,7 @@ class DeviceScanCache:
                     self._obs_note("miss", 0)
                 return None
             self._entries.move_to_end(key)
+            self._asked_by[key] = by
             self.hits += 1
             if _events.enabled():
                 _events.emit("scan_cache", op="hit", bytes=hit[1])
@@ -130,35 +148,44 @@ class DeviceScanCache:
                 self._obs_note("hit", hit[1])
             return hit[0]
 
-    def put(self, key: tuple, value: Any, nbytes: int) -> None:
+    def put(self, key: tuple, value: Any, nbytes: int,
+            by: Any = None) -> None:
         with self._lock:
             if key in self._entries:
                 _, old, old_lid = self._entries.pop(key)
+                self._asked_by.pop(key, None)
                 self._bytes -= old
                 if old_lid is not None:
                     _ledger().note_free(old_lid, reason="replace")
             # one oversized entry must not wedge the pool
             if nbytes > self.max_bytes:
                 return
+            # what this put may evict, oldest first: everything but what
+            # another part of the same query has asked for. Where that is
+            # not enough the entry is not admitted
+            victims, over = [], self._bytes + nbytes - self.max_bytes
+            for k, (_, sz, _) in self._entries.items():
+                if over <= 0:
+                    break
+                asked = self._asked_by.get(k)
+                if (by is None or asked is None or asked[0] != by[0]
+                        or asked == by):
+                    victims.append(k)
+                    over -= sz
+            if over > 0:
+                return
             led = _ledger()
             lid = led.note_alloc(nbytes, kind="scan_cache") \
                 if led.armed() else None
             self._entries[key] = (value, nbytes, lid)
+            self._asked_by[key] = by
             self._bytes += nbytes
             if _events.enabled():
                 _events.emit("scan_cache", op="put", bytes=nbytes)
             if _obs.enabled():
                 self._obs_note("put", nbytes)
-            while self._bytes > self.max_bytes and self._entries:
-                _, (_, sz, elid) = self._entries.popitem(last=False)
-                self._bytes -= sz
-                self.evictions += 1
-                if elid is not None:
-                    _ledger().note_free(elid, reason="evict")
-                if _events.enabled():
-                    _events.emit("scan_cache", op="evict", bytes=sz)
-                if _obs.enabled():
-                    self._obs_note("evict", sz)
+            for k in victims:
+                self._evict(k)
 
     def drop_under_pressure(self) -> int:
         """Drop EVERY resident entry (OOM recovery, memory/retry.py):
@@ -172,6 +199,7 @@ class DeviceScanCache:
                 if lid is not None:
                     _ledger().note_free(lid, reason="pressure_drop")
             self._entries.clear()
+            self._asked_by.clear()
             self._bytes = 0
             self.evictions += n
             if freed and _events.enabled():
@@ -191,6 +219,7 @@ class DeviceScanCache:
             dead = [k for k in self._entries if k and k[0] == path]
             for k in dead:
                 _, sz, lid = self._entries.pop(k)
+                self._asked_by.pop(k, None)
                 self._bytes -= sz
                 if lid is not None:
                     _ledger().note_free(lid, reason="invalidate")

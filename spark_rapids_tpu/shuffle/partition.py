@@ -35,6 +35,8 @@ class Partitioning:
     #: key-based partitionings define num_partitions and key_indices
     #: (column ordinals); callers read key_indices via getattr
     num_partitions: int
+    #: the word a trace's ``.map`` span says which partitioning it ran by
+    kind = "other"
 
     def partition_ids(self, cols: Sequence[Val], schema: T.StructType,
                       live: jax.Array, map_index: int,
@@ -76,6 +78,8 @@ def count_bounds_le(
 class SinglePartitioning(Partitioning):
     """Everything to partition 0 (reference: GpuSinglePartitioning.scala)."""
 
+    kind = "single"
+
     num_partitions: int = 1
 
     def partition_ids(self, cols, schema, live, map_index, str_max_lens=()):
@@ -93,6 +97,8 @@ class RoundRobinPartitioning(Partitioning):
     Spark starts each task's cycle at a random position; here the start is
     the map partition index so results are deterministic and still spread.
     """
+
+    kind = "round_robin"
 
     num_partitions: int
 
@@ -114,6 +120,8 @@ class HashPartitioning(Partitioning):
     their full bytes: the exchange passes the per-batch max byte length
     via ``str_max_lens``.
     """
+
+    kind = "hash"
 
     key_indices: List[int]
     num_partitions: int
@@ -139,6 +147,8 @@ class RangePartitioning(Partitioning):
     bound with full Spark ordering (nulls/NaN/-0.0) via the same radix-key
     encoding the sort kernel uses.
     """
+
+    kind = "range"
 
     key_indices: List[int]
     orders: List[SortOrder]

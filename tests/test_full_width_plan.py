@@ -26,6 +26,8 @@ from spark_rapids_tpu.expr import expressions as E
 from spark_rapids_tpu.sql import TpuSession
 
 CELL = "store_sales_full.quantity_report"
+#: TPC-H Q1 on the 16-column ``lineitem``: two string keys and an order
+Q1_CELL = "lineitem_full.q1"
 BATCH_BYTES = "spark.rapids.tpu.sql.reader.batchSizeBytes"
 TRACE = "spark.rapids.tpu.sql.trace.enabled"
 FUSION = "spark.rapids.tpu.sql.stageFusion"
@@ -226,7 +228,7 @@ def _traced_spans(tmp_path_factory, bench, directory, conf):
     finally:
         jax.profiler.stop_trace()
         one_chip.stop()
-    assert len(rows) == 100
+    assert len(rows) == (4 if bench["name"] == Q1_CELL else 100)
     path = glob.glob(os.path.join(out, "plugins", "profile", "*",
                                   "*.xplane.pb"))[0]
     spans = []
@@ -354,3 +356,58 @@ def test_the_exchange_map_program_is_not_rebuilt_by_a_second_query(
     before = XB.COMPILE_COUNTER.snapshot()[1].get("exchange", 0)
     _collect(bench, directory, {BATCH_BYTES: rg_bytes + 1})
     assert XB.COMPILE_COUNTER.snapshot()[1].get("exchange", 0) == before
+
+
+# ---------------------------------------------------------------------------
+# (e) Q1 on the 16-column lineitem: string keys through a hash and a range
+# exchange, from a real trace of the CPU profiler
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def q1_traced(tmp_path_factory):
+    """The spans of ``lineitem_full.q1``'s query at its rehearse size,
+    its four row groups cut into two scan splits as the timed file's 29
+    are."""
+    import pyarrow.parquet as pq
+
+    bench = load_cell(Q1_CELL)
+    size = bench["config"]["rehearse"]
+    directory = str(tmp_path_factory.mktemp("li_full"))
+    path = bench["generator"].generate(
+        bench["config"], 2**31 + 37, directory, size["rows"],
+        size["row_group_rows"])
+    md = pq.ParquetFile(path).metadata
+    assert md.num_columns == 16 and md.num_row_groups == 4
+    return _traced_spans(
+        tmp_path_factory, bench, directory,
+        {BATCH_BYTES: 2 * md.row_group(0).total_byte_size + 1000})
+
+
+def test_q1s_exchanges_say_their_kind_and_the_range_one_its_sampling(
+        q1_traced):
+    spans = q1_traced
+    mapped = _named(spans, EXCHANGE + ".map")
+    assert [m["kind"] for m in mapped] == ["hash", "range"]
+    hashed, ranged = mapped
+    # a PARTIAL a split into the hash exchange; the FINAL's one batch of
+    # four groups into the range exchange
+    assert (hashed["inputs"], hashed["partitions"]) == (2, 2)
+    assert (ranged["inputs"], ranged["rows"], ranged["partitions"]) == (
+        1, 4, 2)
+    (sample,) = _named(spans, EXCHANGE + ".sample")
+    assert (sample["samples"], sample["inputs"], sample["bounds"]) == (
+        4, 1, 1)
+    # the sampling's pull is a part of its span
+    names = [n for n, _ in spans]
+    assert names.index(EXCHANGE + ".sample") < names.index(
+        EXCHANGE + ".d2h") < len(names)
+    # both of the range exchange's partitions hold rows: two reduce sides
+    # after it, one adaptive read's before
+    reduced = _named(spans, EXCHANGE + ".reduce")
+    assert [r["rows"] for r in reduced] == [8, 2, 2]
+
+
+def test_q1s_merges_count_their_partials(q1_traced):
+    merges = _named(q1_traced, AGG + ".merge")
+    # two row groups' updates a split, then the one exchanged batch
+    assert [(m["mode"], m["partials"]) for m in merges] == [
+        (A.PARTIAL, 2), (A.PARTIAL, 2), (A.FINAL, 1)]

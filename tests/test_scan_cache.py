@@ -157,3 +157,54 @@ def test_stage_fusion_modes_agree(tmp_path, fusion):
     rows = _query(sess, d)
     assert rows == sorted(
         (k, sum(v for v in range(256) if v % 8 == k)) for k in range(8))
+
+
+def _rg(i):
+    return ("f", 0, 0, i, (), None)
+
+
+@pytest.mark.parametrize("splits", [
+    [range(29)], [range(16), range(16, 29)],
+    [range(8), range(8, 16), range(16, 24), range(24, 29)]],
+    ids=["one_split", "two_splits", "four_splits"])
+def test_a_scan_that_comes_round_again_keeps_what_the_budget_holds(splits):
+    """A table of 29 row groups under a budget of 20, scanned a split at a
+    time in every query (probe the split's keys, then put its misses): a
+    split's puts do not evict what another split of the same query has
+    asked for, so every query after the first finds 20 row groups however
+    the scan is split. Plain LRU found 20 under one split and 11 under
+    two; one split still IS plain LRU (nothing is ever protected)."""
+    c, plain = DeviceScanCache(20 * 10), DeviceScanCache(20 * 10)
+    for query in range(1, 6):
+        hits = 0
+        for part, split in enumerate(splits):
+            by = (query, part)
+            found = [c.get(_rg(i), by=by) for i in split]
+            hits += sum(v is not None for v in found)
+            for i, v in zip(split, found):
+                if v is None:
+                    c.put(_rg(i), i, 10, by=by)
+            for i, v in zip(split, [plain.get(_rg(i)) for i in split]):
+                if v is None:
+                    plain.put(_rg(i), i, 10)
+        assert hits == (0 if query == 1 else 20), (query, hits)
+        assert c.stats()["bytes"] == 200
+        if len(splits) == 1:  # entry for entry what plain LRU keeps
+            assert list(c._entries) == list(plain._entries)
+
+
+def test_what_only_this_part_or_an_earlier_query_asked_for_is_evicted():
+    c = DeviceScanCache(30)
+    for i in range(3):
+        c.put(_rg(i), i, 10, by=(1, "a"))
+    assert c.get(_rg(0), by=(2, "a")) == 0   # query 2's first part asks
+    c.put(_rg(3), 3, 10, by=(2, "b"))        # its second evicts query 1's
+    c.put(_rg(4), 4, 10, by=(2, "b"))
+    assert sorted(k[3] for k in c._entries) == [0, 3, 4]
+    c.put(_rg(5), 5, 10, by=(2, "b"))        # then its own, in LRU order
+    assert sorted(k[3] for k in c._entries) == [0, 4, 5]
+    c.get(_rg(4), by=(2, "a")), c.get(_rg(5), by=(2, "a"))
+    c.put(_rg(6), 6, 10, by=(2, "b"))        # all another part's: not admitted
+    assert sorted(k[3] for k in c._entries) == [0, 4, 5]
+    c.put(_rg(7), 7, 10)                     # no query named: plain LRU
+    assert sorted(k[3] for k in c._entries) == [4, 5, 7]

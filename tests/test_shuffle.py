@@ -432,3 +432,111 @@ def test_partition_cols_is_handed_the_bucket_of_the_rows_held(
     _, _, _, slots = _exchanged(
         kind, _live_batch(_GIVEN_SLOTS, lazy=True), monkeypatch)
     assert slots == [{choose_capacity(_LIVE_ROWS)}]
+
+
+# ---------------------------------------------------------------------------
+# TPC-H Q1's plan over several scan partitions: string keys through both
+# exchanges (the benchmark's cell lineitem_full.q1)
+# ---------------------------------------------------------------------------
+_Q1_SCHEMA = T.StructType([
+    T.StructField("l_quantity", T.DOUBLE),
+    T.StructField("l_extendedprice", T.DOUBLE),
+    T.StructField("l_discount", T.DOUBLE),
+    T.StructField("l_tax", T.DOUBLE),
+    T.StructField("l_returnflag", T.STRING),
+    T.StructField("l_linestatus", T.STRING),
+    T.StructField("l_shipdate", T.DATE)])
+_Q1_HOST = {"spark.rapids.tpu.shuffle.mode": "host",
+            "spark.rapids.tpu.sql.variableFloatAgg.enabled": True}
+
+
+def _q1_rows(n, seed):
+    rnd = random.Random(seed)
+    flags = [("A", "F"), ("N", "F"), ("N", "O"), ("R", "F")]
+    picked = [rnd.choice(flags) for _ in range(n)]
+    return {
+        "l_quantity": [float(rnd.randint(1, 50)) for _ in range(n)],
+        "l_extendedprice": [rnd.randint(90_000, 10_000_000) / 100.0
+                            for _ in range(n)],
+        "l_discount": [rnd.randint(0, 10) / 100.0 for _ in range(n)],
+        "l_tax": [rnd.randint(0, 8) / 100.0 for _ in range(n)],
+        "l_returnflag": [p[0] for p in picked],
+        "l_linestatus": [p[1] for p in picked],
+        # days since 1970: some rows ship after Q1's cut of 1998-09-02
+        "l_shipdate": [rnd.randint(10_300, 10_500) for _ in range(n)]}
+
+
+def _q1(df):
+    one = E.lit(1.0)
+    disc_price = E.Multiply(
+        E.col("l_extendedprice"), E.Subtract(one, E.col("l_discount")))
+    charge = E.Multiply(disc_price, E.Add(one, E.col("l_tax")))
+    return (
+        df.where(E.LessThanOrEqual(E.col("l_shipdate"),
+                                   E.Literal(10_471, T.DATE)))
+        .group_by("l_returnflag", "l_linestatus")
+        .agg(A.agg(A.Sum(E.col("l_quantity")), "sum_qty"),
+             A.agg(A.Sum(E.col("l_extendedprice")), "sum_base_price"),
+             A.agg(A.Sum(disc_price), "sum_disc_price"),
+             A.agg(A.Sum(charge), "sum_charge"),
+             A.agg(A.Average(E.col("l_quantity")), "avg_qty"),
+             A.agg(A.Average(E.col("l_extendedprice")), "avg_price"),
+             A.agg(A.Average(E.col("l_discount")), "avg_disc"),
+             A.agg(A.Count(E.col("l_quantity")), "count_order"))
+        .order_by("l_returnflag", "l_linestatus"))
+
+
+def test_q1_over_several_partitions_matches_the_oracle_in_order():
+    # partial -> hash exchange on two string keys -> final -> range
+    # exchange on the same keys -> a local sort a partition, collected in
+    # the partitions' order
+    data = _q1_rows(900, seed=37)
+    assert_tpu_and_cpu_equal(
+        lambda s: _q1(s.create_dataframe(data, _Q1_SCHEMA,
+                                         num_partitions=3)),
+        conf=_Q1_HOST, ignore_order=False, approx_float=True)
+
+
+def test_q1s_second_run_compiles_nothing_and_keeps_no_plan_alive():
+    """The exchanges and the sort are built anew for every query's plan,
+    and the range exchange's sampled bounds are constants in its
+    program's key: the same data samples the same bounds, so the second
+    run of the query finds every program in a process-wide cache (the
+    sort's too since PR 37) and no exec of the first run's plan is kept
+    alive by a cache of its own."""
+    from spark_rapids_tpu.exec.base import compile_snapshot
+    from spark_rapids_tpu.sql.session import TpuSession
+
+    data = _q1_rows(700, seed=41)
+    sess = TpuSession(_Q1_HOST)
+
+    def run():
+        df = _q1(sess.create_dataframe(data, _Q1_SCHEMA, num_partitions=3))
+        rows = df.collect()
+        return rows, sess.last_executed_plan.tree_string()
+
+    first, plan = run()
+    assert "HashPartitioning(keys=[0, 1], n=3)" in plan, plan
+    assert "RangePartitioning(keys=[0, 1], n=3)" in plan, plan
+    assert "mode=partial" in plan and "mode=final" in plan
+    assert not sess.plan_fallbacks()
+    assert [r[:2] for r in first] == [
+        ("A", "F"), ("N", "F"), ("N", "O"), ("R", "F")]
+    _, before = compile_snapshot()
+    second, _ = run()
+    _, after = compile_snapshot()
+    assert second == first
+    new = {site: n - before.get(site, 0) for site, n in after.items()
+           if n != before.get(site, 0)}
+    assert new == {}, new
+    # a per-instance program cache registered with the pipeline caches'
+    # sweep kept every query's sort exec, and with it the query's plan
+    import gc
+
+    from spark_rapids_tpu.exec.sort import TpuSortExec
+
+    for _ in range(3):
+        run()
+    gc.collect()
+    live = [o for o in gc.get_objects() if isinstance(o, TpuSortExec)]
+    assert len(live) <= 2, len(live)  # the last plan, and one being built
