@@ -18,6 +18,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import types as T
 from ..columnar import ColumnarBatch
@@ -25,12 +26,14 @@ from ..conf import RapidsConf
 from ..expr import aggregates as A
 from ..expr import expressions as E
 from ..expr.eval import ColV, DictV, StrV, Val, lower, materialize_dict
+from ..expr.values import val_capacity
 from ..ops import concat as concat_ops
 from ..ops import groupby as groupby_ops
 from ..ops.sort import max_string_len
 from ..types import StructField, StructType
 from ..columnar.column import choose_capacity
 from .base import (
+    NO_SPAN,
     TpuExec,
     batch_from_vals,
     batch_signature,
@@ -284,6 +287,61 @@ def _fused_agg_trace(key_exprs, key_dts, value_exprs, update_ops, merge_ops,
         return merged_vals, nseg
 
     return update_batch, finish
+
+
+#: the merge's concat programs, process-wide and keyed by structure alone:
+#: an aggregate is built anew for every query's plan, and a cache of its
+#: own would re-trace the program (and count a compile miss) every query
+_MERGE_CACHE: dict = {}
+
+
+def _merge_concat(sigs: Tuple[tuple, ...], takes: Tuple[int, ...],
+                  out_cap: int):
+    """ONE program for a synced merge's input: each partial (``sigs``) cut
+    to its first ``takes`` slots where that is under its capacity
+    (``ops/concat.live_prefix``), its dictionary keys expanded there, and
+    the parts spliced into ``out_cap`` rows with their row counts as an
+    operand and their byte counts read from the expanded offsets, so that
+    the host needs no string length. Each string column's byte pool is
+    the sum of its parts' pools, or, where every part held it as a
+    dictionary, what ``out_cap`` rows of its longest entry can hold if
+    that is less."""
+    key = ("merge_concat", sigs, takes, out_cap)
+
+    def build():
+        @program("agg_update")
+        def run(parts, counts):
+            with jax.named_scope("agg_merge"):
+                col_parts = [
+                    concat_ops.live_prefix(vals, take)
+                    if take < val_capacity(vals[0]) else vals
+                    for vals, take in zip(parts, takes)]
+                char_caps = []
+                for j, v in enumerate(col_parts[0]):
+                    if not isinstance(v, (StrV, DictV)):
+                        continue
+                    col = [p[j] for p in col_parts]
+                    cap = sum(c.mat_cap if isinstance(c, DictV)
+                              else int(c.chars.shape[0]) for c in col)
+                    if all(isinstance(c, DictV) for c in col):
+                        cap = min(cap, choose_capacity(
+                            max(1, out_cap * max(c.max_len for c in col)),
+                            128))
+                    char_caps.append(cap)
+                col_parts = [[materialize_dict(v) if isinstance(v, DictV)
+                              else v for v in vals] for vals in col_parts]
+                byte_counts = [[v.offsets[counts[i]] for v in vals
+                                if isinstance(v, StrV)]
+                               for i, vals in enumerate(col_parts)]
+                return concat_ops.concat_pieces_traced(
+                    col_parts, [counts[i] for i in range(len(parts))],
+                    byte_counts, out_cap, char_caps)
+
+        return jax.jit(run)
+
+    from .base import cached_pipeline
+
+    return cached_pipeline(_MERGE_CACHE, key, "agg_update", build)
 
 
 class TpuHashAggregateExec(TpuExec):
@@ -594,17 +652,18 @@ class TpuHashAggregateExec(TpuExec):
         finally:
             self._bound_keys = saved_bound
 
-    def _merge(self, partials: List[ColumnarBatch]) -> ColumnarBatch:
+    def _merge(self, partials: List[ColumnarBatch],
+               span=NO_SPAN) -> ColumnarBatch:
         """Concat partial batches and re-aggregate with merge ops
         (reference: concatenateBatches + merge pass, aggregate.scala:451-476).
         A single partial passes through untouched (dict-encoded group keys
-        stay encoded); multi-partial merges materialize dict keys — the
-        concat kernels splice byte pools."""
-        if len(partials) > 1:
-            from .base import materialized_batch
-
-            with self.section("merge.materialize"):
-                partials = [materialized_batch(b) for b in partials]
+        stay encoded). Two or more merge at the rows they hold: one pull of
+        their row counts, then ONE program (``_merge_concat``) cuts each
+        partial to the bucket of its rows, expands its dictionary keys
+        there and splices them; ``span`` gets the slots that program took
+        and how many partials it cut."""
+        if len(partials) == 1:
+            return partials[0]
         str_cols = [
             j for j, f in enumerate(self._buffer_schema.fields)
             if isinstance(f.dataType, (T.StringType, T.BinaryType))
@@ -615,72 +674,58 @@ class TpuHashAggregateExec(TpuExec):
         # RTT per batch — the right trade only over a high-latency device
         # link. On the CPU backend the pull is free and the synced path
         # merges at the REAL row counts (~group-count rows, not millions)
-        if (len(partials) > 1 and not str_cols
+        if (not str_cols
                 and _jx.default_backend() != "cpu"
                 and sum(max(1, b.capacity) for b in partials)
                 <= self._SYNC_FREE_MERGE_MAX_ROWS):
             return self._merge_fixed_width(partials)
-        while len(partials) > 1:
-            # ONE batched host pull for every row count and string byte
-            # length (each separate pull pays a host round trip)
-            from .base import host_pull
+        from .base import host_pull
 
-            nb = len(partials)
-            with self.section("merge.lengths"):
-                # one eager slice a partial and string column: a dispatch
-                # each, before the pull can start
-                head = [count_scalar(b.num_rows_lazy) for b in partials]
-                for b in partials:
-                    for j in str_cols:
-                        c = b.columns[j]
-                        nr = b.num_rows_lazy
-                        idx = (min(nr, c.offsets.shape[0] - 1)
-                               if isinstance(nr, int) else nr)
-                        head.append(c.offsets[idx])
-            # the one place the merge waits for the device: every update
-            # dispatched so far has to finish before the counts arrive
-            with self.section("merge.pull"):
-                pulled = [int(x) for x in host_pull(head)]
-            lengths = pulled[:nb]
-            for b, n in zip(partials, lengths):
-                if not isinstance(b.num_rows_lazy, int):
-                    b._num_rows = n
-                    for c in b.columns:
-                        c.length = n
-            total = sum(lengths)
-            out_cap = choose_capacity(total, self.conf.shape_bucket_min)
-            ns = len(str_cols)
-            byte_lengths = [
-                pulled[nb + i * ns : nb + (i + 1) * ns]
-                for i in range(nb)
-            ]
-            out_char_caps = [
-                choose_capacity(max(1, sum(bl[k] for bl in byte_lengths)), 128)
-                for k in range(len(str_cols))
-            ]
-            with self.section("merge.concat"):
-                cols, n = concat_ops.concat_batches_cols(
-                    [vals_of_batch(b) for b in partials], lengths,
-                    byte_lengths, out_cap, out_char_caps,
-                )
-            merged_in = batch_from_vals(cols, self._buffer_schema, n)
-            nk = len(self._key_fields)
-            merge_exprs: List[Optional[E.Expression]] = [
-                E.BoundReference(nk + j, f.dataType, True)
-                for j, f in enumerate(self._buf_fields)
-            ]
-            saved_bound = self._bound_keys
-            self._bound_keys = [
-                E.BoundReference(i, f.dataType, f.nullable)
-                for i, f in enumerate(self._key_fields)
-            ]
-            try:
-                with self.section("merge.reduce"):
-                    partials = [self._run_batch(
-                        merged_in, self._merge_ops, merge_exprs)]
-            finally:
-                self._bound_keys = saved_bound
-        return partials[0]
+        with self.section("merge.lengths"):
+            head = [b.num_rows_lazy for b in partials]
+        # the one place the merge waits for the device: every update
+        # dispatched so far has to finish before the counts arrive
+        with self.section("merge.pull"):
+            lengths = [int(x) for x in host_pull(head)]
+        for b, n in zip(partials, lengths):
+            if not isinstance(b.num_rows_lazy, int):
+                b._num_rows = n
+                for c in b.columns:
+                    c.length = n
+        bucket = self.conf.shape_bucket_min
+        # a partial of no rows adds none; the merge keeps one to run on
+        held = [(b, n) for b, n in zip(partials, lengths) if n] or [
+            (partials[0], 0)]
+        # each partial is taken at the bucket of the rows it holds, or
+        # whole where they fill it
+        takes = tuple(min(b.capacity, choose_capacity(n, bucket))
+                      for b, n in held)
+        total = sum(lengths)
+        with self.section("merge.concat"):
+            fn = _merge_concat(
+                tuple(batch_signature(b) for b, _ in held), takes,
+                choose_capacity(total, bucket))
+            cols, _ = fn([vals_of_batch(b) for b, _ in held],
+                         np.asarray([n for _, n in held], np.int32))
+        span.set(slots=sum(takes),
+                 cut=sum(t < b.capacity for t, (b, _) in zip(takes, held)))
+        merged_in = batch_from_vals(cols, self._buffer_schema, total)
+        nk = len(self._key_fields)
+        merge_exprs: List[Optional[E.Expression]] = [
+            E.BoundReference(nk + j, f.dataType, True)
+            for j, f in enumerate(self._buf_fields)
+        ]
+        saved_bound = self._bound_keys
+        self._bound_keys = [
+            E.BoundReference(i, f.dataType, f.nullable)
+            for i, f in enumerate(self._key_fields)
+        ]
+        try:
+            with self.section("merge.reduce"):
+                return self._run_batch(
+                    merged_in, self._merge_ops, merge_exprs)
+        finally:
+            self._bound_keys = saved_bound
 
     def _eval_exprs(self) -> List[E.Expression]:
         """Result projection over [keys..., buffers...]."""
@@ -1080,7 +1125,7 @@ class TpuHashAggregateExec(TpuExec):
         from ..memory.retry import with_oom_retry_nosplit
 
         def merge_and_eval():
-            merged = self._merge(partials)
+            merged = self._merge(partials, span)
             if self.mode == A.PARTIAL:
                 return merged
             with self.section("merge.eval"):

@@ -24,7 +24,7 @@ from ..conf import (
     SHUFFLE_COMPRESSION_CODEC,
     SHUFFLE_TRANSPORT_CLASS,
 )
-from ..expr.eval import ColV, DictV, StrV, Val
+from ..expr.eval import StrV, Val
 from ..ops import concat as concat_ops
 from ..ops import filter_gather
 from ..ops.sort import max_string_len
@@ -135,35 +135,16 @@ _PREFIX_CACHE: Dict[tuple, object] = {}
 
 
 def _live_prefix(batch: ColumnarBatch, live_cap: int) -> ColumnarBatch:
-    """The first ``live_cap`` slots of every plane of a batch whose live
-    rows (a dense prefix, by the batch's contract) fit that bucket: what
-    the map side partitions where a batch holds fewer rows than it was
-    given slots (a ``PARTIAL`` aggregate's 100 groups at the capacity of
-    its stacked row groups). A static slice, so no gather; a string keeps
-    its byte pool, a dictionary column its dictionary, with the byte bound
-    of its expansion cut to what ``live_cap`` rows can hold."""
+    """What the map side partitions where a batch holds fewer rows than it
+    was given slots: the batch cut to the first ``live_cap`` slots of
+    every plane (``ops/concat.live_prefix``) in a program of its own."""
     key = (batch_signature(batch), live_cap)
 
     def build():
         @program("exchange_slice")
         def run(cols):
             with jax.named_scope(EXCHANGE_SCOPE_WORDS[0]):
-                out = []
-                for v in cols:
-                    if isinstance(v, StrV):
-                        out.append(StrV(v.offsets[:live_cap + 1], v.chars,
-                                        v.validity[:live_cap]))
-                    elif isinstance(v, DictV):
-                        out.append(DictV(
-                            v.codes[:live_cap], v.dictionary,
-                            v.validity[:live_cap],
-                            min(v.mat_cap, choose_capacity(
-                                max(1, live_cap * v.max_len), 128)),
-                            v.max_len, v.unique))
-                    else:
-                        out.append(ColV(v.data[:live_cap],
-                                        v.validity[:live_cap]))
-                return out
+                return concat_ops.live_prefix(cols, live_cap)
 
         return jax.jit(run)
 

@@ -14,7 +14,34 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..expr.eval import ColV, StrV, Val
+from ..columnar.column import choose_capacity
+from ..expr.eval import ColV, DictV, StrV, Val
+
+
+def live_prefix(vals: Sequence[Val], live_cap: int) -> List[Val]:
+    """The first ``live_cap`` slots of every plane of a batch whose live
+    rows (a dense prefix, by the batch's contract) fit that bucket: a
+    batch that holds fewer rows than it was given slots (a ``PARTIAL``
+    aggregate's 100 groups at the capacity of its stacked row groups). A
+    static slice, so no gather; a string keeps its byte pool, a dictionary
+    column its dictionary, with the byte bound of its expansion cut to what
+    ``live_cap`` rows can hold. Trace-safe."""
+    out: List[Val] = []
+    for v in vals:
+        if isinstance(v, StrV):
+            out.append(StrV(v.offsets[:live_cap + 1], v.chars,
+                            v.validity[:live_cap]))
+        elif isinstance(v, DictV):
+            out.append(DictV(
+                v.codes[:live_cap], v.dictionary,
+                v.validity[:live_cap],
+                min(v.mat_cap, choose_capacity(
+                    max(1, live_cap * v.max_len), 128)),
+                v.max_len, v.unique))
+        else:
+            out.append(ColV(v.data[:live_cap],
+                            v.validity[:live_cap]))
+    return out
 
 
 def concat_fixed(parts: Sequence[ColV], lengths: Sequence[int], out_cap: int) -> ColV:

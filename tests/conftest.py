@@ -57,7 +57,7 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 _COLLECT_FIRST = (
     "test_tpu_compile_sf100.py", "test_tpu_compile.py",
     "test_tpu_compile_mesh4.py", "test_tpu_compile_full.py",
-    "test_tpu_compile_lineitem_full.py",
+    "test_tpu_compile_lineitem_full.py", "test_tpu_compile_merge.py",
 )
 
 

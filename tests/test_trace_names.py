@@ -207,7 +207,7 @@ def test_every_span_carries_the_query_and_nests_under_it(traced):
             scan + "read_file", scan + "page_plan", scan + "plan_wait",
             scan + "upload", scan + "unpack_dispatch",
             scan + "decode_dispatch", agg + "update", agg + "merge",
-            agg + "merge.materialize", agg + "merge.lengths",
+            agg + "merge.lengths",
             agg + "merge.pull",
             agg + "merge.concat", agg + "merge.reduce", agg + "merge.eval",
             "TpuSortExec.sort", "ColumnarToRowExec.to_rows",
