@@ -203,10 +203,12 @@ ENABLE_TRACE = conf(
     "Wrap operator hot sections and the host phases inside them (file "
     "read, page planning, host decode, upload, merge parts, d2h) in "
     "jax.profiler TraceAnnotations named <Exec>.<section>, each carrying "
-    "query=<id> and its counts (bytes, columns, cache hits); one "
-    "TpuSession.query span a query (reference: NvtxWithMetrics.scala). "
-    "Off, no annotation is built; program and scope names are always on. "
-    "docs/tuning.md lists the names.")
+    "query=<id>, its own thread CPU (cpu_us) and its counts (bytes, "
+    "columns, cache hits, a pool task's queue wait); one TpuSession.query "
+    "span a query, with the process's CPU over it (reference: "
+    "NvtxWithMetrics.scala). Off, no annotation is built and no CPU clock "
+    "is read; program and scope names are always on. docs/tuning.md "
+    "lists the names.")
 METRICS_DEVICE_SYNC = conf(
     "spark.rapids.tpu.metrics.deviceSync.enabled", False,
     "Device-accurate operator timing: every operator blocks until its "

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import resource
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -306,7 +307,10 @@ def program(site: str):
 
 
 #: per thread: ``op`` — the exec whose op_timed section is open here (what
-#: ``phase`` times into), ``query`` — the id every span of the drain carries
+#: ``phase`` times into), ``query`` — the id every span of the drain carries,
+#: ``nested_cpu_ns`` — the thread CPU of the spans closed so far inside the
+#: innermost open span (what its ``cpu_us`` leaves out), ``queued_ns`` — a
+#: pool task's wait from submission to start, for the first span it opens
 _AMBIENT = threading.local()
 
 
@@ -345,6 +349,62 @@ def carry(fn: Callable) -> Callable:
             _AMBIENT.op, _AMBIENT.query = prev
 
     return task
+
+
+def queued(task: Callable) -> Callable:
+    """Stamp a pool task at its submission (``pool.submit(queued(task),
+    ...)``, ``pool.map(queued(task), ...)``): the outermost span the task
+    opens on the pool's thread carries ``queued_us``, its wait from here to
+    its start. Only while the exec whose section is open here traces;
+    otherwise the task itself, with nothing read."""
+    op = getattr(_AMBIENT, "op", None)
+    if op is None or not getattr(op, "_trace", False):
+        return task
+    stamp = time.perf_counter_ns()
+
+    def stamped(*args, **kwargs):
+        _AMBIENT.queued_ns = time.perf_counter_ns() - stamp
+        try:
+            return task(*args, **kwargs)
+        finally:
+            _AMBIENT.queued_ns = None
+
+    return stamped
+
+
+def _process_cpu_ns() -> int:
+    """User + system CPU of the whole process, every thread."""
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    return round((usage.ru_utime + usage.ru_stime) * 1e9)
+
+
+@contextlib.contextmanager
+def _traced(annotation, span: "_Span", process_cpu: bool):
+    """A traced span, open, with its CPU set on it as it closes:
+    ``cpu_us``, the thread's CPU inside the span less that of the spans
+    nested in it on this thread (the CPU twin of a trace's self time), and
+    with ``process_cpu`` also ``process_cpu_us``, the process's CPU over
+    it. What the enclosing span leaves out is this one's CPU from before
+    its annotation opens to after it closes, so neither reads above its
+    own wall."""
+    outer = getattr(_AMBIENT, "nested_cpu_ns", 0)
+    before = time.thread_time_ns()
+    try:
+        with annotation:
+            proc0 = _process_cpu_ns() if process_cpu else 0
+            _AMBIENT.nested_cpu_ns = 0
+            cpu0 = time.thread_time_ns()
+            try:
+                yield
+            finally:
+                own = time.thread_time_ns() - cpu0 - _AMBIENT.nested_cpu_ns
+                counts = {"cpu_us": own // 1000}
+                if process_cpu:
+                    counts["process_cpu_us"] = (
+                        _process_cpu_ns() - proc0) // 1000
+                span.set(**counts)
+    finally:
+        _AMBIENT.nested_cpu_ns = outer + time.thread_time_ns() - before
 
 
 @functools.lru_cache(maxsize=None)
@@ -435,32 +495,41 @@ class Metric:
 @contextlib.contextmanager
 def timed(metric: Optional[Metric], trace_name: str = "", trace: bool = False,
           event_op: Optional[str] = None, event_section: str = "",
-          owner=None, **counts):
+          owner=None, process_cpu: bool = False, **counts):
     """Time a hot section into a metric; optionally emit a profiler range
     (reference: NvtxWithMetrics.scala -> jax.profiler.TraceAnnotation).
-    The range carries ``query=<id>`` of the drain it belongs to and the
-    keyword ``counts``; the yielded span takes counts known only later
-    (``span.set(bytes=n)``). With ``trace`` off no annotation is built.
-    ``owner`` is the exec that ``phase`` sections nested on this thread
-    time into. ``event_op`` (set only while event logging is on)
-    additionally emits a host-lane ``op_span`` event, so the offline
-    timeline shares the same start/dur the metric accumulated."""
+    The range carries ``query=<id>`` of the drain it belongs to, the
+    keyword ``counts``, its own thread CPU as ``cpu_us`` (the process's
+    too, as ``process_cpu_us``, where ``process_cpu``) and, as the first
+    span of a ``queued`` pool task, ``queued_us``; the yielded span takes
+    counts known only later (``span.set(bytes=n)``). With ``trace`` off no
+    annotation is built and no CPU clock is read. ``owner`` is the exec
+    that ``phase`` sections nested on this thread time into. ``event_op``
+    (set only while event logging is on) additionally emits a host-lane
+    ``op_span`` event, so the offline timeline shares the same start/dur
+    the metric accumulated."""
     if trace:
         qid = getattr(_AMBIENT, "query", None)
         if qid is not None:
             counts["query"] = qid
-        ctx = jax.profiler.TraceAnnotation(
+        waited = getattr(_AMBIENT, "queued_ns", None)
+        if waited is not None:
+            _AMBIENT.queued_ns = None
+            counts["queued_us"] = waited // 1000
+        annotation = jax.profiler.TraceAnnotation(
             trace_name or (metric.name if metric else "op"), **counts)
-        span = _Span(ctx)
+        span = _Span(annotation)
     else:
-        ctx = contextlib.nullcontext()
         span = NO_SPAN
     prev = getattr(_AMBIENT, "op", None)
     if owner is not None:
         _AMBIENT.op = owner
     start = time.perf_counter_ns()
     try:
-        with ctx:
+        if trace:
+            with _traced(annotation, span, process_cpu):
+                yield span
+        else:
             yield span
     finally:
         _AMBIENT.op = prev

@@ -19,7 +19,7 @@ from ..columnar.column import DeviceColumn
 from ..conf import RapidsConf
 from ..types import StructType
 from ..columnar.column import choose_capacity
-from .base import TpuExec, carry
+from .base import TpuExec, carry, queued
 
 SCAN_TIME = "scanTime"  # reference metric name (GpuMetricNames)
 DECODE_TIME = "tpuDecodeTime"
@@ -220,7 +220,7 @@ class TpuFileSourceScanExec(TpuExec):
             # reads nobody will consume again
             read = carry(self._host_read_split)
             self._prefetch = [
-                pool.submit(read, i)
+                pool.submit(queued(read), i)
                 if i not in self._consumed_splits else None
                 for i in range(self.scanner.num_splits())
             ]
@@ -407,14 +407,14 @@ class TpuFileSourceScanExec(TpuExec):
             if self._prefetch_dev is None:
                 read_dev = carry(self._prefetch_split_device)
                 self._prefetch_dev = [
-                    _prefetch_pool().submit(read_dev, i)
+                    _prefetch_pool().submit(queued(read_dev), i)
                     if i not in self._consumed_splits else None
                     for i in range(n)
                 ]
         elif self._prefetch is None:
             read = carry(self._host_read_split)
             self._prefetch = [
-                _prefetch_pool().submit(read, i)
+                _prefetch_pool().submit(queued(read), i)
                 if i not in self._consumed_splits else None
                 for i in range(n)
             ]

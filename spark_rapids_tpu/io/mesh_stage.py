@@ -180,14 +180,14 @@ def stage_sharded(
     # pads + uploads shard k
     with ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="srtpu-meshdec") as pool:
-        from ..exec.base import carry
+        from ..exec.base import carry, queued
 
         # the worker's spans belong to the exec and query open here
         decode = carry(decode_shard)
-        nxt = pool.submit(decode, 0) if n_shards else None
+        nxt = pool.submit(queued(decode), 0) if n_shards else None
         for s in range(n_shards):
             payload = nxt.result()
-            nxt = (pool.submit(decode, s + 1)
+            nxt = (pool.submit(queued(decode), s + 1)
                    if s + 1 < n_shards else None)
             upload_shard(s, payload)
 

@@ -1168,7 +1168,7 @@ def _plan_columns(path, pf, rgmd, pqschema, name_to_ci, columns, file_bytes):
             fallback_cols.append(name)
         else:
             candidates.append((name, ci))
-    from ..exec.base import carry, phase
+    from ..exec.base import carry, phase, queued
 
     plans: Dict[str, ChunkPlan] = {}
     if candidates:
@@ -1197,8 +1197,8 @@ def _plan_columns(path, pf, rgmd, pqschema, name_to_ci, columns, file_bytes):
         # GpuParquetScan.scala:900)
         if len(candidates) > 1:
             with phase("plan_wait"):
-                results = list(
-                    _decode_pool().map(carry(plan_one), candidates))
+                results = list(_decode_pool().map(
+                    queued(carry(plan_one)), candidates))
         else:
             results = [plan_one(candidates[0])]
         for name, plan in results:
@@ -1249,7 +1249,7 @@ def read_row_groups_pipelined(
     from .. import obs as _obs
     from ..columnar.batch import ColumnarBatch
     from ..columnar.column import choose_capacity
-    from ..exec.base import carry, phase
+    from ..exec.base import carry, phase, queued
     from ..types import StructType
     from .arrow_convert import arrow_to_batch
 
@@ -1262,8 +1262,9 @@ def read_row_groups_pipelined(
             span.set(file_bytes=len(file_bytes))
     fields_by_name = {f.name: f for f in tpu_fields}
 
+    # the stages' clock reads feed only the pq_pipeline events
     def plan_one(rg, rgmd, name, ci):
-        t0 = _time.perf_counter_ns()
+        t0 = _time.perf_counter_ns() if _events.enabled() else None
         if ci is None:
             return name, None, 0
         pqcol = pqschema.column(ci)
@@ -1276,7 +1277,7 @@ def read_row_groups_pipelined(
                     pqcol.max_repetition_level)
             except Exception:
                 return name, None, 0
-        if _events.enabled():
+        if t0 is not None:
             _events.emit(
                 "pq_pipeline", stage="decode", rg=rg,
                 bytes=int(rgmd.column(ci).total_uncompressed_size),
@@ -1301,7 +1302,7 @@ def read_row_groups_pipelined(
         # page plans are spans of the same scan on the pool's threads
         task = carry(plan_one)
         futs = [
-            pool.submit(task, rg, rgmd, name, name_to_ci.get(name))
+            pool.submit(queued(task), rg, rgmd, name, name_to_ci.get(name))
             for name in columns
         ]
         pending[pos] = (rg, rgmd, futs)
@@ -1334,14 +1335,14 @@ def read_row_groups_pipelined(
         def flush(names):
             if not names:
                 return
-            t0 = _time.perf_counter_ns()
+            t0 = _time.perf_counter_ns() if _events.enabled() else None
             staged = stage_decode_args([decoded[nm][0] for nm in names])
             nbytes = sum(
                 a.size * a.dtype.itemsize
                 for nm in names for a in decoded[nm][0])
             for nm, da in zip(names, staged):
                 dev_args[nm] = da
-            if _events.enabled():
+            if t0 is not None:
                 _events.emit("pq_pipeline", stage="upload", rg=rg,
                              bytes=int(nbytes),
                              dur=_time.perf_counter_ns() - t0)
@@ -1402,7 +1403,7 @@ def read_row_groups_pipelined(
                         StructType((fields_by_name[name],)))
                     host_cols[name] = b.columns[0]
 
-        t0 = _time.perf_counter_ns()
+        t0 = _time.perf_counter_ns() if _events.enabled() else None
         cols = []
         fields = []
         with phase("decode_dispatch") as span:
@@ -1419,7 +1420,7 @@ def read_row_groups_pipelined(
                     cols.append(host_cols[name])
                 fields.append(f)
         batch = ColumnarBatch(cols, StructType(tuple(fields)), n)
-        if _events.enabled():
+        if t0 is not None:
             _events.emit("pq_pipeline", stage="unpack", rg=rg, bytes=0,
                          dur=_time.perf_counter_ns() - t0)
         if _obs.enabled():

@@ -668,11 +668,13 @@ class TpuSession:
 
     def _collect(self, node: LNode) -> List[tuple]:
         """Plan + drain one query, through the serving scheduler when
-        spark.rapids.tpu.serve.enabled is set."""
+        spark.rapids.tpu.serve.enabled is set. Traced, the query's span
+        carries the process's CPU over it (``process_cpu_us``)."""
         from ..exec.base import query_scope, timed
 
         with query_scope(_next_query_id()), \
-                timed(None, "TpuSession.query", self._trace):
+                timed(None, "TpuSession.query", self._trace,
+                      process_cpu=True):
             if not self._serve_enabled():
                 return self._run_collect(self._execute(node))
             return self._collect_serve(node)

@@ -332,3 +332,150 @@ def test_carry_binds_the_section_and_the_query_to_a_pool_task():
     with XB.phase("upload") as span:
         assert span is XB.NO_SPAN
     assert "uploadTime" not in leaf.metrics
+
+
+# ---------------------------------------------------------------------------
+# what a span says of the host: its own CPU, a pool task's wait, the
+# process's CPU over a query
+# ---------------------------------------------------------------------------
+def test_every_span_carries_its_own_cpu_within_its_wall(traced):
+    spans, _ = traced
+    for thread, name, s0, s1, stats in spans:
+        assert isinstance(stats.get("cpu_us"), int), (thread, name, stats)
+        # a thread's CPU clock and the trace's clock tick at rates a few
+        # hundred parts in a million apart on a virtual machine
+        wall_us = (s1 - s0) / 1000
+        assert 0 <= stats["cpu_us"] <= wall_us * 1.001 + 2, (thread, name)
+
+
+def test_the_query_carries_the_process_cpu_over_it(traced):
+    spans, _ = traced
+    (query,) = [s for s in spans if s[1] == "TpuSession.query"]
+    stats = query[4]
+    # every thread's CPU over the query, so at least the query's own
+    assert stats["process_cpu_us"] >= stats["cpu_us"] > 0
+    assert not any("process_cpu_us" in s[4] for s in spans if s is not query)
+
+
+def test_a_pool_task_says_how_long_it_queued(traced):
+    spans, _ = traced
+    main = next(s[0] for s in spans if s[1] == "TpuSession.query")
+    plans = [s for s in spans if s[1] == "TpuFileSourceScanExec.page_plan"]
+    pool = [s for s in plans if s[0] != main]
+    assert pool and all(s[4]["queued_us"] >= 0 for s in pool)
+    # the consumer's own page_plan is no pool task, and no other span is one
+    assert not any("queued_us" in s[4] for s in spans if s not in pool)
+
+
+class _Records:
+    """A stand-in for ``jax.profiler.TraceAnnotation`` that keeps each
+    span's name and counts, the late ones included."""
+
+    def __init__(self, seen, name, **counts):
+        self.name, self.counts = name, dict(counts)
+        seen.append(self)
+
+    def set_metadata(self, **counts):
+        self.counts.update(counts)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _recording(monkeypatch):
+    import jax
+
+    seen = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name, **counts: _Records(seen, name, **counts))
+    return seen
+
+
+def _burn(seconds):
+    import time
+
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def test_cpu_us_is_a_spans_own_cpu(monkeypatch):
+    seen = _recording(monkeypatch)
+    with XB.timed(None, "Outer.section", True):
+        _burn(0.02)
+        with XB.timed(None, "Outer.section.part", True):
+            _burn(0.06)
+    outer, part = (next(r.counts for r in seen if r.name == n)
+                   for n in ("Outer.section", "Outer.section.part"))
+    assert part["cpu_us"] >= 60_000
+    # the outer span burned less than its part, so its own CPU says so
+    assert 20_000 <= outer["cpu_us"] < part["cpu_us"]
+    assert "process_cpu_us" not in outer and "queued_us" not in outer
+
+
+def test_a_task_held_behind_a_sleeping_one_reports_its_wait(monkeypatch):
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    from spark_rapids_tpu.conf import RapidsConf
+
+    seen = _recording(monkeypatch)
+
+    class Leaf(XB.TpuExec):
+        pass
+
+    leaf = Leaf(RapidsConf({TRACE: True}))
+
+    def task():
+        with XB.phase("page_plan"):
+            with XB.phase("upload"):
+                pass
+        return 1
+
+    with ThreadPoolExecutor(1) as pool, leaf.op_timed("decode"):
+        stamped = XB.queued(XB.carry(task))  # stamped before the sleep
+        pool.submit(time.sleep, 0.05)
+        assert pool.submit(stamped).result() == 1
+        # nothing is left on the pool's thread for a task not stamped
+        assert pool.submit(XB.carry(task)).result() == 1
+    plans = [r.counts for r in seen if r.name == "Leaf.page_plan"]
+    assert plans[0]["queued_us"] >= 50_000
+    assert "queued_us" not in plans[1]
+    # on the outermost span the task opens, not on the one nested in it
+    assert not any("queued_us" in r.counts for r in seen
+                   if r.name == "Leaf.upload")
+
+
+def test_with_tracing_off_no_cpu_clock_or_stamp_is_read(tmp_path,
+                                                        monkeypatch):
+    import resource
+    import time
+
+    from spark_rapids_tpu.conf import RapidsConf
+
+    def refuses(*args):
+        raise AssertionError("a CPU clock read with sql.trace.enabled off")
+
+    monkeypatch.setattr(time, "thread_time_ns", refuses)
+    monkeypatch.setattr(resource, "getrusage", refuses)
+
+    class Leaf(XB.TpuExec):
+        pass
+
+    def task():
+        return 1
+
+    with XB.timed(None, "TpuSession.query", False, process_cpu=True) as span:
+        assert span is XB.NO_SPAN
+    assert XB.queued(task) is task  # no exec open: nothing stamped
+    with Leaf(RapidsConf({})).op_timed("decode"):
+        assert XB.queued(task) is task  # the exec open here does not trace
+    directory = _write_table(tmp_path / "t")
+    sess = TpuSession(dict(CONF, **{FUSION: "OFF"}))
+    assert len(_by_string_key(sess, directory).collect()) == 3
+    with pytest.raises(AssertionError, match="CPU clock"):
+        with XB.timed(None, "TpuSession.query", True):
+            pass
