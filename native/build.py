@@ -10,6 +10,7 @@ import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SOURCES = [os.path.join(HERE, "src", "lz4.cpp"),
+           os.path.join(HERE, "src", "snappy.cpp"),
            os.path.join(HERE, "src", "parquet_decode.cpp")]
 OUT = os.path.join(HERE, "libsrtpu.so")
 
@@ -19,8 +20,16 @@ def build(force: bool = False) -> str:
         os.path.getmtime(OUT) >= os.path.getmtime(s) for s in SOURCES
     ):
         return OUT
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", OUT, *SOURCES]
-    subprocess.run(cmd, check=True, capture_output=True)
+    # built aside and renamed into place: processes that build at once (a
+    # test run's workers) each load a whole library, never a half-written one
+    tmp = f"{OUT}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, *SOURCES]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, OUT)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return OUT
 
 

@@ -6,11 +6,13 @@ reads raw column-chunk BYTES and the accelerator decodes pages
 at :1157, cudf's parquet decoder). The TPU split is chosen by what each
 side is fast at:
 
-  * HOST (cheap, vectorized numpy — no per-value python): thrift page
-    headers, codec decompress (pyarrow), RLE/bit-packed hybrid expansion
-    of dictionary INDICES to the narrowest integer (u8/u16/i32 by bit
-    width) via ``np.unpackbits`` reshape tricks, validity BITS re-packed
-    to words.
+  * HOST (no per-value python): thrift page headers, codec decompress,
+    RLE/bit-packed hybrid expansion of dictionary INDICES to the narrowest
+    integer (u8/u16/i32 by bit width), validity BITS re-packed to words.
+    A SNAPPY or UNCOMPRESSED chunk is planned by ONE native call
+    (``native.pq_plan_chunk``) that holds no GIL; other codecs, and hosts
+    without the native library, take the Python page loop, which gives
+    the same ChunkPlan.
   * WIRE: the narrow codes + packed validity + the dictionary — typically
     1-2 bytes/value instead of 4-8 raw, so host->device transfer shrinks
     by the dictionary ratio. That is the same bytes-not-values contract
@@ -288,10 +290,10 @@ def _code_dtype(bw: int):
 
 def hybrid_decode(data, pos: int, end: int, bw: int,
                   n: int) -> Tuple[np.ndarray, int]:
-    """Hybrid-stream decode, native C++ when available (releases the GIL,
-    so the per-column planning pool gets real parallelism; reference
-    analog: cudf's native page decode behind GpuParquetScan.scala:1157).
-    Output dtype is the narrowest holding the bit width."""
+    """Hybrid-stream decode for the Python page loop, native C++ when
+    available (one GIL round trip a stream; reference analog: cudf's
+    native page decode behind GpuParquetScan.scala:1157). Output dtype is
+    the narrowest holding the bit width."""
     if bw == 0:
         return np.zeros(n, np.uint8), pos
     if bw > 24:
@@ -339,6 +341,10 @@ class ChunkPlan:
     # PLAIN page payloads (concatenated raw value bytes, present only)
     plain_bytes: Optional[bytes] = None
     n_present: int = 0
+    # a BYTE_ARRAY dictionary chunk's string bytes, sum of len[code] over
+    # its codes (a code past the dictionary reads its last entry): the
+    # byte pool the device expansion fills
+    string_bytes: Optional[int] = None
 
 
 def _decompress(codec: str, data: bytes, out_size: int) -> bytes:
@@ -354,30 +360,97 @@ def _decompress(codec: str, data: bytes, out_size: int) -> bytes:
     return c.decompress(data, out_size).to_pybytes()
 
 
+#: srtpu_pq_plan_chunk's refusals by reason code (its enum Reason), in
+#: the words the Python page loop raises them with
+_NATIVE_REFUSALS = {
+    1: "dict encoding {detail}",
+    2: "encoding {detail}",
+    3: "PLAIN {phys}",
+    4: "mixed dict/plain pages",
+    5: "bit width {detail}",
+    6: "repeated column (v2)",
+    7: "boolean dictionary",
+    8: "short chunk: {detail}/{n} values",
+    9: "malformed page stream",
+}
+
+
 def plan_chunk(
-    file_bytes: bytes, col_meta, max_def: int, max_rep: int
+    file_bytes: bytes, col_meta, max_def: int, max_rep: int, span=None
 ) -> ChunkPlan:
-    """Parse one column chunk's pages into a ChunkPlan (host side).
+    """Parse one column chunk's pages into a ChunkPlan (host side): in ONE
+    native call that holds no GIL where the library loaded and the codec
+    is SNAPPY or UNCOMPRESSED, else by the Python page loop. One path that
+    adapts to the chunk; both give the same ChunkPlan. ``span``, the
+    chunk's ``page_plan``, takes the counts ``chunks`` (1), ``native`` (1
+    where the native planner walked the chunk, planned or refused) and
+    ``pages`` (the page headers walked).
 
     Raises _FallbackError for unsupported shapes/encodings."""
-    if max_rep != 0:
-        raise _FallbackError("nested (repeated) column")
-    phys = col_meta.physical_type
-    if phys not in _PHYS_NP and phys != "BYTE_ARRAY":
-        raise _FallbackError(f"physical type {phys}")
-    codec = col_meta.compression
-    n = col_meta.num_values
-    st = col_meta.statistics
-    has_nulls = (
-        max_def > 0
-        and (st is None or st.null_count is None or st.null_count > 0)
-    )
+    from .. import native as _native
 
-    doff = col_meta.dictionary_page_offset
-    off = doff if doff is not None and doff > 0 else col_meta.data_page_offset
-    end = off + col_meta.total_compressed_size
+    codec = col_meta.compression.upper()
+    native = codec in _native.PQ_PLAN_CODECS and _native.available()
+    walked = [0]
+    try:
+        if max_rep != 0:
+            raise _FallbackError("nested (repeated) column")
+        phys = col_meta.physical_type
+        if phys not in _PHYS_NP and phys != "BYTE_ARRAY":
+            raise _FallbackError(f"physical type {phys}")
+        n = col_meta.num_values
+        st = col_meta.statistics
+        has_nulls = (
+            max_def > 0
+            and (st is None or st.null_count is None or st.null_count > 0)
+        )
+        doff = col_meta.dictionary_page_offset
+        off = (doff if doff is not None and doff > 0
+               else col_meta.data_page_offset)
+        end = off + col_meta.total_compressed_size
+        plan = ChunkPlan(phys=phys, num_values=n, nullable=max_def > 0)
+        plan_pages = _plan_pages_native if native else _plan_pages
+        plan_pages(plan, file_bytes, off, end, codec, max_def, has_nulls,
+                   walked)
+        return plan
+    finally:
+        if span is not None:
+            span.set(chunks=1, native=int(native), pages=walked[0])
 
-    plan = ChunkPlan(phys=phys, num_values=n, nullable=max_def > 0)
+
+def _plan_pages_native(plan: ChunkPlan, file_bytes, off: int, end: int,
+                       codec: str, max_def: int, has_nulls: bool,
+                       walked: List[int]) -> None:
+    """plan_chunk's pages in one call of ``srtpu_pq_plan_chunk``."""
+    from ..native import pq_plan_chunk
+
+    got = pq_plan_chunk(file_bytes, off, end, codec, plan.phys,
+                        _PHYS_NP.get(plan.phys), plan.num_values, max_def,
+                        has_nulls)
+    walked[0] = got["pages"]
+    if got["reason"]:
+        raise _FallbackError(_NATIVE_REFUSALS[got["reason"]].format(
+            detail=got["detail"], phys=plan.phys, n=plan.num_values))
+    plan.validity = got["validity"]
+    plan.dict_values = got["dict_values"]
+    plan.dict_offsets = got["dict_offsets"]
+    plan.dict_chars = got["dict_chars"]
+    plan.string_bytes = got["string_bytes"]
+    if got["plain"] is not None:
+        plan.plain_bytes = got["plain"]
+        plan.n_present = len(plan.plain_bytes) // _PHYS_NP[plan.phys].itemsize
+    else:
+        plan.codes = got["codes"]
+        plan.n_present = plan.codes.shape[0]
+
+
+def _plan_pages(plan: ChunkPlan, file_bytes, off: int, end: int,
+                codec: str, max_def: int, has_nulls: bool,
+                walked: List[int]) -> None:
+    """plan_chunk's pages by the Python page loop: a header parse, a
+    decompress and a hybrid decode a page."""
+    phys = plan.phys
+    n = plan.num_values
     pos = off
     values_seen = 0
     code_pages: List[np.ndarray] = []
@@ -412,6 +485,7 @@ def plan_chunk(
 
     while pos < end and values_seen < n:
         ph = parse_page_header(file_bytes, pos)
+        walked[0] += 1
         pos += ph.header_len
         payload = file_bytes[pos : pos + ph.compressed_size]
         pos += ph.compressed_size
@@ -452,7 +526,7 @@ def plan_chunk(
                     valid_pages.append(
                         np.ones(ph.num_values, np.bool_))
             vals = payload[ph.def_levels_len :]
-            if ph.v2_is_compressed and codec.upper() != "UNCOMPRESSED":
+            if ph.v2_is_compressed and codec != "UNCOMPRESSED":
                 vals = _decompress(
                     codec, vals, ph.uncompressed_size - ph.def_levels_len)
             handle_values(vals, 0, len(vals), ph.encoding, presents)
@@ -485,7 +559,18 @@ def plan_chunk(
     else:
         plan.n_present = 0
         plan.codes = np.zeros(0, np.uint8)
-    return plan
+    if plan.dict_offsets is not None:
+        plan.string_bytes = _string_bytes(plan.codes, plan.dict_offsets)
+
+
+def _string_bytes(codes: np.ndarray, offsets: np.ndarray) -> int:
+    """Sum of len[min(code, D - 1)] over ``codes``: the bytes a BYTE_ARRAY
+    dictionary chunk's strings fill."""
+    d = offsets.shape[0] - 1
+    if not codes.shape[0] or d < 1:
+        return 0
+    return int(np.bincount(np.clip(codes.astype(np.int64), 0, d - 1),
+                           minlength=d) @ np.diff(offsets))
 
 
 def _load_dictionary(plan: ChunkPlan, raw: bytes, count: int) -> None:
@@ -900,13 +985,7 @@ def plan_decode(plan: ChunkPlan, dtype_tpu, cap: int,
         if is_str:
             D = plan.dict_offsets.shape[0] - 1
             lens = np.diff(plan.dict_offsets)
-            total_bytes = int(
-                np.bincount(
-                    np.clip(plan.codes.astype(np.int64), 0, D - 1),
-                    minlength=D,
-                ) @ lens
-            ) if plan.codes.shape[0] else 0
-            ccap = choose_capacity(max(1, total_bytes), 128)
+            ccap = choose_capacity(max(1, plan.string_bytes), 128)
             max_len = int(lens.max()) if D > 0 and lens.size else 0
             args += [np.ascontiguousarray(plan.dict_offsets.astype(np.int32)),
                      np.ascontiguousarray(plan.dict_chars)]
@@ -1139,11 +1218,16 @@ def _decode_pool():
     """The PROCESS-SHARED srtpu-pqdec host-decode pool. One pool instead
     of one-per-call: the pipelined reader keeps tasks from several row
     groups in flight at once, and per-call pools would serialize at the
-    row-group boundary (plus pay thread churn per row group). The native
-    hybrid-decode calls release the GIL, so the pool gets real
-    parallelism. IMPORTANT: tasks submitted here must never block on
-    other tasks of this pool (deadlock); both submitters — _plan_columns
-    and read_row_groups_pipelined — only submit leaf chunk-decode work."""
+    row-group boundary (plus pay thread churn per row group). A chunk's
+    plan is one native call that holds no GIL (``srtpu_pq_plan_chunk``),
+    so the pool scales: 28 TPC-H lineitem chunks of 2^21 rows on an
+    8-core x86 host plan in 173-250 ms on one thread and 29-66 ms on
+    eight. The Python page loop, a GIL round trip a page, ran them no
+    faster on eight threads than on one (548-965 against 495-640 ms).
+    IMPORTANT:
+    tasks submitted here must never block on other tasks of this pool
+    (deadlock); both submitters — _plan_columns and
+    read_row_groups_pipelined — only submit leaf chunk-decode work."""
     global _PQDEC_POOL
     if _PQDEC_POOL is None:
         with _PQDEC_POOL_LOCK:
@@ -1182,19 +1266,19 @@ def _plan_columns(path, pf, rgmd, pqschema, name_to_ci, columns, file_bytes):
             pqcol = pqschema.column(ci)
             # the chunk's pages are the file bytes this plan touches
             with phase("page_plan",
-                       file_bytes=rgmd.column(ci).total_compressed_size):
+                       file_bytes=rgmd.column(ci).total_compressed_size
+                       ) as span:
                 try:
                     return name, plan_chunk(
                         file_bytes, rgmd.column(ci),
                         pqcol.max_definition_level,
-                        pqcol.max_repetition_level)
+                        pqcol.max_repetition_level, span)
                 except Exception:
                     return name, None
 
-        # chunk planning is native-decode-heavy (the C++ calls release the
-        # GIL): plan all columns of the row group in parallel (reference
-        # analog: the COALESCING reader's copy thread pool,
-        # GpuParquetScan.scala:900)
+        # a chunk's plan is one native call that holds no GIL: plan all
+        # columns of the row group in parallel (reference analog: the
+        # COALESCING reader's copy thread pool, GpuParquetScan.scala:900)
         if len(candidates) > 1:
             with phase("plan_wait"):
                 results = list(_decode_pool().map(
@@ -1269,12 +1353,12 @@ def read_row_groups_pipelined(
             return name, None, 0
         pqcol = pqschema.column(ci)
         with phase("page_plan",
-                   file_bytes=rgmd.column(ci).total_compressed_size):
+                   file_bytes=rgmd.column(ci).total_compressed_size) as span:
             try:
                 plan = plan_chunk(
                     file_bytes, rgmd.column(ci),
                     pqcol.max_definition_level,
-                    pqcol.max_repetition_level)
+                    pqcol.max_repetition_level, span)
             except Exception:
                 return name, None, 0
         if t0 is not None:

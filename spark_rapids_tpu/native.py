@@ -57,6 +57,14 @@ def _load() -> Optional[ctypes.CDLL]:
             lib.srtpu_pq_binary_dict.argtypes = [
                 ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
+            lib.srtpu_pq_plan_chunk.restype = ctypes.c_void_p
+            lib.srtpu_pq_plan_chunk.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+                ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+                ctypes.c_void_p]
+            lib.srtpu_pq_plan_take.restype = None
+            lib.srtpu_pq_plan_take.argtypes = [ctypes.c_void_p] * 7
             _LIB = lib
         except Exception as e:
             _LIB = None
@@ -126,6 +134,71 @@ def pq_binary_dict(raw: bytes, count: int, offsets, chars) -> Optional[int]:
     if rc < 0:
         raise ValueError("malformed binary dictionary page")
     return int(rc)
+
+
+#: the codecs and physical types srtpu_pq_plan_chunk takes, by its numbers
+PQ_PLAN_CODECS = {"UNCOMPRESSED": 0, "SNAPPY": 1}
+_PQ_PHYS = {"INT32": 0, "INT64": 1, "FLOAT": 2, "DOUBLE": 3, "BOOLEAN": 4,
+            "BYTE_ARRAY": 5}
+#: the words of srtpu_pq_plan_chunk's info[] (its enum Info)
+_PLAN_INFO = ("reason", "detail", "pages", "validity", "kind", "count",
+              "width", "dict", "dict_chars", "string_bytes")
+_CODE_DTYPES = {1: "uint8", 2: "uint16", 4: "int32"}
+
+
+def pq_plan_chunk(data, off: int, end: int, codec: str, phys: str,
+                  value_dtype, num_values: int, max_def: int,
+                  has_nulls: bool) -> Optional[dict]:
+    """Plan one parquet column chunk, the pages in ``data[off:end)``, in
+    one native call that holds no GIL (``data``: the file's bytes or its
+    mmap, read in place). Returns None when the library is unavailable,
+    else a dict: ``reason`` (0, or why the chunk was refused, with
+    ``detail``), ``pages`` walked and, where planned, ``validity`` (bool a
+    row, or None), ``codes`` (the narrowest dtype that holds them),
+    ``plain`` (the PLAIN pages' bytes), ``dict_values`` (of
+    ``value_dtype``), ``dict_offsets`` and ``dict_chars`` (a BYTE_ARRAY
+    dictionary), ``string_bytes`` (sum of its lengths over the codes),
+    each None where the chunk has none."""
+    lib = _load()
+    if lib is None:
+        return None
+    import numpy as np
+
+    src = np.frombuffer(data, np.uint8)  # zero-copy view (bytes or mmap)
+    info = np.zeros(len(_PLAN_INFO), np.int64)
+    handle = lib.srtpu_pq_plan_chunk(
+        src.ctypes.data, src.shape[0], off, end, PQ_PLAN_CODECS[codec],
+        _PQ_PHYS[phys], num_values, max_def, int(has_nulls),
+        info.ctypes.data)
+    got = dict(zip(_PLAN_INFO, info.tolist()))
+    if not handle:
+        return got
+    out = dict.fromkeys(("validity", "codes", "plain", "dict_values",
+                         "dict_offsets", "dict_chars"))
+    try:
+        if got["validity"] >= 0:
+            out["validity"] = np.empty(got["validity"], np.bool_)
+        if got["kind"] == 2:
+            out["plain"] = np.empty(got["count"], np.uint8)
+        else:
+            out["codes"] = np.empty(got["count"] if got["kind"] else 0,
+                                    _CODE_DTYPES[got["width"]])
+        if got["dict"] >= 0:
+            if phys == "BYTE_ARRAY":
+                out["dict_offsets"] = np.empty(got["dict"] + 1, np.int64)
+                out["dict_chars"] = np.zeros(max(1, got["dict_chars"]),
+                                             np.uint8)
+            else:
+                out["dict_values"] = np.empty(got["dict"], value_dtype)
+    finally:
+        lib.srtpu_pq_plan_take(handle, *(
+            None if a is None else a.ctypes.data for a in out.values()))
+    if out["plain"] is not None:
+        out["plain"] = out["plain"].tobytes()
+    got.update(out)
+    if got["string_bytes"] < 0:
+        got["string_bytes"] = None
+    return got
 
 
 def lz4_decompress(data: bytes, out_size: int) -> bytes:

@@ -11,6 +11,7 @@ import pyarrow as pa
 import pyarrow.parquet as pq
 import pytest
 
+from spark_rapids_tpu import native
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.conf import RapidsConf
 from spark_rapids_tpu.exec.scan import TpuFileSourceScanExec
@@ -609,3 +610,347 @@ def test_parquet_scan_footprint_and_predict_exec_hbm(tmp_path):
     csv_ex = TpuFileSourceScanExec(
         conf, CsvScanner(csv_path, conf), "csv")
     assert predict_exec_hbm(csv_ex) is None
+
+
+# ---------------------------------------------------------------------------
+# the native chunk planner (srtpu_pq_plan_chunk) against the Python page loop
+# ---------------------------------------------------------------------------
+def _uvarint(v):
+    out = bytearray()
+    while True:
+        b = v & 0x7F
+        v >>= 7
+        if v:
+            out.append(b | 0x80)
+        else:
+            out.append(b)
+            return bytes(out)
+
+
+def _thrift(fields):
+    """A thrift compact struct of ``(field id, value)``, ids ascending: an
+    int is an i32, a bool a bool, a list a nested struct."""
+    out = bytearray()
+    last = 0
+    for fid, value in fields:
+        if isinstance(value, bool):
+            ftype, body = (1 if value else 2), b""
+        elif isinstance(value, int):
+            ftype, body = 5, _uvarint((value << 1) ^ (value >> 63))
+        else:
+            ftype, body = 12, _thrift(value)
+        out.append(((fid - last) << 4) | ftype)
+        out += body
+        last = fid
+    return bytes(out + b"\x00")
+
+
+def _hybrid(values, bw):
+    """An RLE/bit-packed hybrid stream: an RLE run of the first value, then
+    the rest bit-packed in groups of 8."""
+    values = [int(v) for v in values]
+    if not values:
+        return b""
+    out = bytearray(_uvarint(1 << 1))
+    out += values[0].to_bytes((bw + 7) // 8, "little")
+    rest = values[1:] + [0] * (-(len(values) - 1) % 8)
+    bits = 0
+    for i, v in enumerate(rest):
+        bits |= v << (i * bw)
+    out += _uvarint(((len(rest) // 8) << 1) | 1)
+    out += bits.to_bytes(len(rest) * bw // 8, "little")
+    return bytes(out)
+
+
+class _Meta:
+    """What plan_chunk reads of a column chunk's metadata."""
+
+    def __init__(self, **kw):
+        self.statistics = None
+        self.__dict__.update(kw)
+
+
+def _hand_chunk(pages, codec="SNAPPY", nulls=False, dict_len=3,
+                     v2=False):
+    """A file holding one INT32 dictionary chunk made by hand: ``pages``
+    is a list of ``(bit width, codes, validity or None)``; codes may run
+    past the dictionary (the planner does not look them up). Returns
+    ``(file bytes, metadata, max_def)``."""
+    import pyarrow as pa
+
+    snappy = pa.Codec("snappy")
+
+    def squeeze(raw):
+        return (snappy.compress(raw).to_pybytes() if codec == "SNAPPY"
+                else raw)
+
+    dict_raw = np.arange(dict_len, dtype=np.int32).tobytes()
+    body = squeeze(dict_raw)
+    chunk = bytearray(_thrift([(1, 2), (2, len(dict_raw)), (3, len(body)),
+                               (7, [(1, dict_len), (2, 0)])]) + body)
+    dict_size = len(chunk)
+    rows = 0
+    for bw, codes, valid in pages:
+        n = len(codes) if valid is None else len(valid)
+        rows += n
+        values = bytes([bw]) + _hybrid(codes, bw)
+        levels = b"" if valid is None else _hybrid(valid, 1)
+        if v2:
+            payload = levels + squeeze(values)
+            header = [(1, 3), (2, len(levels) + len(values)),
+                      (3, len(payload)),
+                      (8, [(1, n), (2, n - len(codes)), (3, n), (4, 8),
+                           (5, len(levels)), (6, 0),
+                           (7, codec == "SNAPPY")])]
+        else:
+            raw = values
+            if nulls:
+                raw = len(levels).to_bytes(4, "little") + levels + values
+            payload = squeeze(raw)
+            header = [(1, 0), (2, len(raw)), (3, len(payload)),
+                      (5, [(1, n), (2, 8), (3, 3), (4, 3)])]
+        chunk += _thrift(header) + payload
+    meta = _Meta(physical_type="INT32", compression=codec, num_values=rows,
+                 dictionary_page_offset=4, data_page_offset=4 + dict_size,
+                 total_compressed_size=len(chunk))
+    return b"PAR1" + bytes(chunk), meta, int(nulls)
+
+
+def _pages_at(bws, seed, n=300, nulls=False, top=None):
+    rng = np.random.default_rng(seed)
+    pages = []
+    for bw in bws:
+        valid = (rng.random(n) < 0.7).astype(int) if nulls else None
+        k = n if valid is None else int(valid.sum())
+        codes = rng.integers(0, min(1 << bw, top or (1 << bw)), k)
+        codes[: k // 3] = (1 << bw) - 1 if top is None else codes[: k // 3]
+        pages.append((bw, codes, valid))
+    return pages
+
+
+def _written_chunks(tmp_path, codec, version):
+    """Every column chunk of a file pyarrow writes: INT32, INT64, FLOAT,
+    DOUBLE and BYTE_ARRAY dictionaries, nullable with nulls and without,
+    PLAIN INT32/INT64/FLOAT, PLAIN DOUBLE, dictionary pages that overflow
+    into PLAIN ones, PLAIN BYTE_ARRAY, pages of 1,000 rows."""
+    rng = np.random.default_rng(41)
+    n = 6000
+    holes = rng.random(n) < 0.25
+    strings = np.array(["", "a", "bb", "ccc-ddd", "é"], dtype=object)
+    table = pa.table({
+        "i32": pa.array(rng.integers(-5, 60, n).astype(np.int32)),
+        "i64": pa.array(rng.integers(0, 900, n), mask=holes),
+        "i64_full": pa.array(rng.integers(0, 9, n),
+                             type=pa.int64()),  # nullable, no null
+        "f32": pa.array(rng.integers(0, 40, n).astype(np.float32)),
+        "f64": pa.array(rng.integers(0, 700, n) / 8.0, mask=holes),
+        "s": pa.array(strings[rng.integers(0, 5, n)], mask=holes),
+        "s_full": pa.array(strings[rng.integers(0, 5, n)]),
+        "p32": pa.array(rng.integers(-2 ** 31, 2 ** 31, n).astype(np.int32)),
+        "p64": pa.array(rng.integers(-2 ** 62, 2 ** 62, n), mask=holes),
+        "pf32": pa.array(rng.normal(size=n).astype(np.float32)),
+        "pf64": pa.array(rng.normal(size=n)),
+        "ps": pa.array([f"u{i}" for i in range(n)]),
+        "mixed": pa.array(rng.integers(0, 2 ** 40, n)),
+    })
+    path = os.path.join(str(tmp_path), "w.parquet")
+    pq.write_table(
+        table, path, compression=codec, data_page_version=version,
+        row_group_size=4000, data_page_size=1000, write_batch_size=500,
+        dictionary_pagesize_limit=8000,
+        use_dictionary=["i32", "i64", "i64_full", "f32", "f64", "s",
+                        "s_full", "mixed"])
+    pf = pq.ParquetFile(path)
+    with open(path, "rb") as f:
+        data = f.read()
+    for rg in range(pf.metadata.num_row_groups):
+        rgmd = pf.metadata.row_group(rg)
+        for ci in range(rgmd.num_columns):
+            col = pf.schema.column(ci)
+            yield (f"{col.name}@{rg}", data, rgmd.column(ci),
+                   col.max_definition_level, None)
+
+
+def _planner_cases():
+    for codec in ("snappy", "none"):
+        for version in ("1.0", "2.0"):
+            yield pytest.param(("written", codec, version),
+                               id=f"written-{codec}-v{version}")
+    yield pytest.param(("empty",), id="written-empty")
+    for bw in range(1, 25):
+        yield pytest.param(("bw", bw), id=f"bit-width-{bw}")
+    yield pytest.param(("widths", (3, 12, 20, 5), None), id="widths-to-int32")
+    yield pytest.param(("widths", (2, 12, 9), 200), id="widths-narrowed")
+    yield pytest.param(("v2", "SNAPPY"), id="v2-nulls-snappy")
+    yield pytest.param(("v2", "UNCOMPRESSED"), id="v2-nulls-uncompressed")
+    yield pytest.param(("all-null",), id="all-null-pages")
+    yield pytest.param(("truncated", "SNAPPY"), id="truncated-snappy")
+    yield pytest.param(("truncated", "UNCOMPRESSED"),
+                       id="truncated-uncompressed")
+    yield pytest.param(("no-library",), id="python-loop-without-library")
+
+
+def _chunks_of(case, tmp_path):
+    """``(name, file bytes, metadata, max_def, pages)`` of a case's chunks;
+    ``pages`` are what a chunk made by hand holds (None for pyarrow's)."""
+    kind = case[0]
+    if kind == "written":
+        yield from _written_chunks(tmp_path, case[1], case[2])
+    elif kind == "empty":
+        path = os.path.join(str(tmp_path), "e.parquet")
+        pq.write_table(pa.table({"x": pa.array([], pa.int32()),
+                                 "s": pa.array([], pa.string())}), path)
+        pf = pq.ParquetFile(path)
+        with open(path, "rb") as f:
+            data = f.read()
+        for rg in range(pf.metadata.num_row_groups):
+            for ci in range(2):
+                yield (f"empty{ci}", data,
+                       pf.metadata.row_group(rg).column(ci), 1, None)
+    elif kind == "bw":
+        bw = case[1]
+        for codec in ("SNAPPY", "UNCOMPRESSED"):
+            for nulls in (False, True):
+                pages = _pages_at((bw, bw, bw), bw, nulls=nulls)
+                data, meta, max_def = _hand_chunk(pages, codec, nulls=nulls)
+                yield f"{codec}-{nulls}", data, meta, max_def, pages
+    elif kind == "widths":
+        pages = _pages_at(case[1], 7, top=case[2])
+        data, meta, max_def = _hand_chunk(pages)
+        yield "widths", data, meta, max_def, pages
+    elif kind == "v2":
+        pages = _pages_at((4, 4), 9, nulls=True)
+        data, meta, max_def = _hand_chunk(pages, case[1], nulls=True, v2=True)
+        yield "v2", data, meta, max_def, pages
+    elif kind == "all-null":
+        pages = [(10, [], np.zeros(50, int)), (10, [], np.zeros(30, int))]
+        data, meta, max_def = _hand_chunk(pages, nulls=True)
+        yield "all-null", data, meta, max_def, pages
+    elif kind == "truncated":
+        pages = _pages_at((6, 6), 3)
+        data, meta, max_def = _hand_chunk(pages, case[1])
+        yield "truncated", data[: len(data) - 40], meta, max_def, pages
+    else:
+        yield from _written_chunks(tmp_path, "snappy", "1.0")
+
+
+class _Counts:
+    def __init__(self):
+        self.counts = {}
+
+    def set(self, **counts):
+        self.counts.update(counts)
+
+
+def _plan_or_refusal(data, meta, max_def):
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    span = _Counts()
+    try:
+        got = PD.plan_chunk(data, meta, max_def, 0, span)
+    except Exception as e:  # noqa: BLE001 - any refusal is compared
+        got = e
+    return got, span.counts
+
+
+_PHYS_T = {"INT32": T.INT, "INT64": T.LONG, "FLOAT": T.FLOAT,
+           "DOUBLE": T.DOUBLE, "BYTE_ARRAY": T.STRING}
+
+
+def _holds_what_was_written(plan, pages, where):
+    """A hand-made chunk's plan holds the codes and validity its pages were
+    written with, the codes in the narrowest dtype their largest fits."""
+    codes = np.concatenate([np.asarray(c, np.int64) for _, c, _ in pages])
+    assert np.array_equal(plan.codes.astype(np.int64), codes), where
+    if codes.shape[0]:
+        top = int(codes.max())
+        assert plan.codes.dtype == (np.uint8 if top < 256 else np.uint16
+                                    if top < 65536 else np.int32), where
+    assert plan.n_present == codes.shape[0], where
+    if pages[0][2] is None:
+        assert plan.validity is None, where
+    else:
+        valid = np.concatenate([v for _, _, v in pages]) == 1
+        assert plan.validity.dtype == np.bool_, where
+        assert np.array_equal(plan.validity, valid), where
+
+
+def _same_plan(a, b, where):
+    import dataclasses
+
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    for f in dataclasses.fields(PD.ChunkPlan):
+        x, y = getattr(a, f.name), getattr(b, f.name)
+        assert type(x) is type(y), (where, f.name, type(x), type(y))
+        if isinstance(x, np.ndarray):
+            assert x.dtype == y.dtype, (where, f.name, x.dtype, y.dtype)
+            assert np.array_equal(x, y), (where, f.name)
+        else:
+            assert x == y, (where, f.name, x, y)
+
+
+@pytest.mark.skipif(not native.available(),
+                    reason="native toolchain unavailable")
+@pytest.mark.parametrize("case", list(_planner_cases()))
+def test_native_planner_matches_the_python_loop(case, tmp_path, monkeypatch):
+    """``srtpu_pq_plan_chunk`` against the Python page loop, chunk by chunk:
+    the same ChunkPlan (every field, arrays and dtypes), the same
+    ``plan_decode`` key and upload arrays, the same refusals (a chunk the
+    loop refuses, the native planner refuses with ``_FallbackError``, in
+    the loop's words unless the input is corrupt), the same pages walked,
+    and a BYTE_ARRAY chunk's ``string_bytes`` equal to the ``bincount``
+    form ``plan_decode`` ran before. A chunk made by hand plans to the codes
+    and validity it was written with, so the hybrid decoder both planners
+    share is held to its input too. The last case runs the loop as a host
+    without the native library does (numpy hybrid decode)."""
+    from spark_rapids_tpu.columnar.column import choose_capacity
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    chunks = list(_chunks_of(case, tmp_path))
+    assert chunks
+    natives = [_plan_or_refusal(d, m, md) for _, d, m, md, _ in chunks]
+    if case[0] == "no-library":
+        monkeypatch.setattr(native, "_load", lambda: None)
+        assert not native.available()
+    else:
+        monkeypatch.setattr(native, "PQ_PLAN_CODECS", {})
+    loops = [_plan_or_refusal(d, m, md) for _, d, m, md, _ in chunks]
+    refused = set()
+    for (name, _, meta, _, pages), (a, a_span), (b, b_span) in zip(
+            chunks, natives, loops):
+        assert a_span == dict(b_span, native=1), name
+        assert b_span["native"] == 0 and a_span["chunks"] == 1, name
+        if isinstance(b, Exception):
+            refused.add(name.partition("@")[0])
+            assert isinstance(a, PD._FallbackError), (name, a, b)
+            if (isinstance(b, PD._FallbackError)
+                    and str(a) != "malformed page stream"):
+                assert str(a) == str(b), name
+            continue
+        assert not isinstance(a, Exception), (name, a)
+        _same_plan(a, b, name)
+        if pages is not None:
+            _holds_what_was_written(a, pages, name)
+        if a.dict_offsets is not None:
+            d = max(1, a.dict_offsets.shape[0] - 1)
+            lens = np.diff(a.dict_offsets if a.dict_offsets.shape[0] > 1
+                           else np.zeros(2, np.int64))
+            want = int(np.bincount(
+                np.clip(a.codes.astype(np.int64), 0, d - 1),
+                minlength=d) @ lens) if a.codes.shape[0] else 0
+            assert a.string_bytes == want, name
+        cap = choose_capacity(max(1, a.num_values))
+        a_args, a_key, _ = PD.plan_decode(a, _PHYS_T[a.phys], cap)
+        b_args, b_key, _ = PD.plan_decode(b, _PHYS_T[b.phys], cap)
+        assert a_key == b_key, name
+        assert len(a_args) == len(b_args)
+        for x, y in zip(a_args, b_args):
+            assert x.dtype == y.dtype and np.array_equal(x, y), name
+    if case[0] == "truncated":
+        assert refused == {"truncated"}
+    elif case[0] in ("written", "no-library"):
+        # PLAIN DOUBLE, dictionary overflowing into PLAIN, PLAIN BYTE_ARRAY
+        assert refused == {"pf64", "mixed", "ps"}
+    else:
+        assert not refused

@@ -242,6 +242,27 @@ def test_counts_sit_on_the_boundary_they_size(traced):
     assert "host_decode" not in by_name  # every column took the device
 
 
+def test_a_chunks_plan_says_which_planner_walked_its_pages(traced):
+    """A column chunk's ``page_plan`` carries ``chunks`` 1, ``native`` (1:
+    the snappy chunks went to ``srtpu_pq_plan_chunk`` in one call each)
+    and the ``pages`` it walked; the consumer's own ``page_plan`` spans
+    (``plan_decode``) carry none of the three."""
+    from spark_rapids_tpu import native
+
+    spans, _ = traced
+    plans = [s[4] for s in spans
+             if s[1] == "TpuFileSourceScanExec.page_plan"]
+    chunks = [st for st in plans if "chunks" in st]
+    assert len(chunks) == 3 * 3  # three row groups of the three read columns
+    for st in chunks:
+        assert st["chunks"] == 1
+        assert st["native"] == int(native.available())
+        assert st["pages"] >= 2  # a dictionary page and a data page
+    assert all("native" not in st and "pages" not in st
+               for st in plans if "chunks" not in st)
+    assert len(plans) > len(chunks)
+
+
 def test_sections_are_metrics_whether_or_not_they_are_spans(traced):
     _, sess = traced
     report = sess.explain_metrics()
