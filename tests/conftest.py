@@ -10,11 +10,12 @@ backend so the differential suites validate Spark-exactness ON the chip
 against the CPU backend. Usage:
     SRTPU_TEST_TPU=1 python -m pytest tests/ -q -m "not cpu_only"
 """
-import collections
 import os
 import sys
 
 import pytest
+
+from placement import order
 
 ON_TPU = os.environ.get("SRTPU_TEST_TPU", "") == "1"
 
@@ -34,37 +35,21 @@ import jax  # noqa: E402
 if not ON_TPU:
     jax.config.update("jax_platforms", "cpu")
 
-# Persistent compilation cache: the suite is compile-dominated (~500 XLA
-# programs); caching compiled executables across runs cuts the full-suite
-# wall time (SURVEY §4 test-strategy analog of the reference's reuse of
-# warmed Spark sessions across its pytest modules). The directory is
-# JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_compile_cache —
-# envinfo.use_compile_cache is the one helper that decides
-# (benchmarks/run.py uses it too).
+# No persistent compilation cache, even where a test points jax at one (the
+# benchmark's harness does, run in-process). In a run from a fresh checkout
+# it hits only between xdist workers (a sixth of the suite's CPU seconds),
+# and every store calls jaxlib 0.9.0's CPU ``serialize()``, which has
+# crashed workers (SIGSEGV in test_merge_at_rows_held.py), failing the run.
+jax.config.update("jax_enable_compilation_cache", False)
+
+# the package, from this checkout
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from spark_rapids_tpu.envinfo import use_compile_cache  # noqa: E402
-
-use_compile_cache()
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
-
-
-#: Under ``--dist loadfile`` a file is one worker's, so the run cannot end
-#: before its longest file does. The compile asks are the longest by far
-#: (minutes each, one compile that no cache can hold) and have the fewest
-#: tests, so they are collected, and so handed out, first.
-_COLLECT_FIRST = (
-    "test_tpu_compile_sf100.py", "test_tpu_compile.py",
-    "test_tpu_compile_mesh4.py", "test_tpu_compile_full.py",
-    "test_tpu_compile_lineitem_full.py", "test_tpu_compile_merge.py",
-)
 
 
 def pytest_configure(config):
-    # xdist hands files out by their number of tests, most first, unless
-    # told not to: a compile ask is one test and would start last. The
-    # order is made in pytest_collection_modifyitems instead.
+    # under --dist loadfile xdist hands files out by their number of
+    # tests, most first, unless told not to: a compile ask is one test and
+    # would start last. placement.order makes the order instead.
     config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers", "cpu_only: needs the multi-device virtual CPU mesh; "
@@ -99,13 +84,22 @@ def _hbm_leak_guard():
                 for r in leaks[:5]))
 
 
+@pytest.hookimpl(optionalhook=True)
+def pytest_configure_node(node):
+    # xdist resets a worker's own --dist and -n; it collects, so it is
+    # handed the mode its tests are scheduled under
+    node.workerinput["dist"] = node.config.option.dist
+
+
+@pytest.hookimpl(trylast=True)  # after -m and --deselect: the count is xdist's
 def pytest_collection_modifyitems(config, items):
-    # _COLLECT_FIRST, then xdist's own order: the files with most tests
-    # first. Deterministic: every xdist worker must collect the same order.
-    rank = {name: i for i, name in enumerate(_COLLECT_FIRST)}
-    tests_in = collections.Counter(item.path for item in items)
-    items.sort(key=lambda item: (rank.get(item.path.name, len(rank)),
-                                 -tests_in[item.path]))
+    # the same on every xdist worker, or xdist aborts the run
+    worker = getattr(config, "workerinput", {})
+    place = {t: i for i, t in enumerate(order(
+        [item.nodeid for item in items], worker.get("dist", "no"),
+        worker.get("workercount", 1),
+        config.getoption("maxschedchunk", None)))}
+    items.sort(key=lambda item: place[item.nodeid])
     if not ON_TPU:
         return
     skip = pytest.mark.skip(reason="needs 8-device CPU mesh (on-TPU run)")

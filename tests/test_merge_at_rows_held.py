@@ -190,9 +190,22 @@ def _write(directory, row_groups):
     return str(directory)
 
 
+@pytest.fixture
+def own_merge_cache():
+    """The merge cache holding only what the test builds. It is
+    process-wide: an entry another test built while a cost consumer was on
+    is an ``xla_cost.CostProbe``, with no closure of its own to read, and
+    which tests ran first in the process differs from run to run."""
+    others = dict(XA._MERGE_CACHE)
+    XA._MERGE_CACHE.clear()
+    yield
+    XA._MERGE_CACHE.clear()
+    XA._MERGE_CACHE.update(others)
+
+
 @pytest.mark.parametrize("row_groups", [2, 5])
 def test_a_later_querys_merge_compiles_nothing_and_keeps_no_plan(
-        row_groups, tmp_path, monkeypatch):
+        row_groups, tmp_path, monkeypatch, own_merge_cache):
     from spark_rapids_tpu.exec.base import TpuExec, compile_snapshot
     from spark_rapids_tpu.sql import TpuSession
 
@@ -226,6 +239,7 @@ def test_a_later_querys_merge_compiles_nothing_and_keeps_no_plan(
             if n != before.get(site, 0)} == {}
     assert dispatched == [row_groups] * 2  # one program a merge
     # the program closes over its key, not over an exec of the plan
+    assert len(XA._MERGE_CACHE) == 1
     for fn in XA._MERGE_CACHE.values():
         cells = [c.cell_contents for c in fn.__wrapped__.__closure__ or ()]
         assert not any(isinstance(c, TpuExec) for c in cells), cells

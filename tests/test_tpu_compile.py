@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_compile_asks import (  # noqa: F401  (fixtures)
-    CAP, HBM_BYTES, compile_all, load_cell, no_persistent_cache, on,
+    CAP, HBM_BYTES, compile_all, load_cell, on,
     one_chip, row_sized_scatters, topo)
 
 
@@ -77,7 +77,7 @@ def report_programs(tmp_path_factory):
 @pytest.mark.parametrize("site", [
     "upload_unpack", "pq_decode", "fused_chain", "project"])
 def test_smoke_query_piece_compiles_for_v5e(
-        site, report_programs, one_chip, no_persistent_cache):
+        site, report_programs, one_chip):
     programs = report_programs.get(site)
     assert programs, (
         f"the cell's query dispatched no {site!r} program; captured "
@@ -93,7 +93,7 @@ def test_smoke_query_piece_compiles_for_v5e(
 
 
 def test_smoke_query_aggregate_compiles_for_v5e(
-        report_programs, one_chip, no_persistent_cache):
+        report_programs, one_chip):
     """The program the chip really runs under the cell's conf: the fused
     scan->filter->aggregate stage (decode + chain + MATMUL update + merge
     + result projection), here over one row group of 2^21 rows. Minutes,
@@ -149,7 +149,7 @@ def _s(shape, dt):
     "Mosaic: failed to legalize operation 'func.return' (i32, i64) — the "
     "BlockSpec index maps trace to i64 under jax_enable_x64"))
 def test_pallas_groupby_reduce_compiles_for_v5e(
-        one_chip, no_persistent_cache):
+        one_chip):
     from spark_rapids_tpu.ops import pallas_groupby as PG
 
     def reduce_(seg, iv, valid, fv):
@@ -165,7 +165,7 @@ def test_pallas_groupby_reduce_compiles_for_v5e(
     "Mosaic: Reductions over unsigned integers not implemented (the "
     "winner kernel's u32 min/max)"))
 def test_pallas_groupby_winner_compiles_for_v5e(
-        one_chip, no_persistent_cache):
+        one_chip):
     from spark_rapids_tpu.ops import pallas_groupby as PG
 
     _mosaic_compile(
@@ -178,7 +178,7 @@ def test_pallas_groupby_winner_compiles_for_v5e(
 @pytest.mark.xfail(strict=True, reason=(
     "RecursionError: maximum recursion depth exceeded while lowering the "
     "probe kernel for Mosaic"))
-def test_pallas_join_probe_compiles_for_v5e(one_chip, no_persistent_cache):
+def test_pallas_join_probe_compiles_for_v5e(one_chip):
     from spark_rapids_tpu.ops import pallas_join as PJ
 
     def probe(bhi, blo, cnt, phi, plo, live):
@@ -194,7 +194,7 @@ def test_pallas_join_probe_compiles_for_v5e(one_chip, no_persistent_cache):
     "Mosaic: Not implemented: changeBitwidth when minor tiling is not 128 "
     "(1-D u8 blocks widened to i32)"))
 def test_pallas_udf_word_starts_compiles_for_v5e(
-        one_chip, no_persistent_cache):
+        one_chip):
     from spark_rapids_tpu.udf import native as UN
 
     _mosaic_compile(UN, UN._word_starts_pallas,

@@ -20,8 +20,7 @@ import pytest
 import jax
 
 from tpu_compile_asks import (  # noqa: F401  (fixtures)
-    HBM_BYTES, REFUSED_ON_V5E, compile_all, load_cell, no_persistent_cache,
-    on, one_chip, topo)
+    HBM_BYTES, REFUSED_ON_V5E, compile_all, load_cell, on, one_chip, topo)
 
 CELL = "store_sales_full.quantity_report"
 #: capacity of the final merge: two splits x 100 groups, rounded up
@@ -80,7 +79,7 @@ def full_programs(tmp_path_factory):
 
 
 def test_final_merge_of_the_exchanged_partials_compiles_for_v5e(
-        full_programs, one_chip, no_persistent_cache):
+        full_programs, one_chip):
     programs = full_programs.get("agg_plan")
     assert programs, sorted(full_programs)
     (fn, args, kw), = programs
@@ -113,7 +112,7 @@ def test_map_programs_partition_the_bucket_of_a_partials_groups(
 @pytest.mark.parametrize("word", [
     "exchange", "exchange_slice", "exchange_concat"])
 def test_shuffle_program_compiles_for_v5e(
-        word, full_programs, one_chip, no_persistent_cache):
+        word, full_programs, one_chip):
     programs = full_programs.get(word)
     assert programs, sorted(full_programs)
     for secs, mem in compile_all(programs, one_chip):
@@ -123,7 +122,7 @@ def test_shuffle_program_compiles_for_v5e(
 
 @pytest.mark.xfail(strict=True, reason=REFUSED_ON_V5E["RADIX"])
 def test_radix_merge_at_the_final_capacity_compiles_for_v5e(
-        one_chip, no_persistent_cache):
+        one_chip):
     """What AUTO resolved for this merge before it stopped offering RADIX
     on the chip; the ask stays so the day the compiler takes it the suite
     says so."""

@@ -23,7 +23,7 @@ import pytest
 import jax
 
 from tpu_compile_asks import (  # noqa: F401  (fixtures)
-    HBM_BYTES, compile_all, load_cell, no_persistent_cache, one_chip, topo)
+    HBM_BYTES, compile_all, load_cell, one_chip, topo)
 
 CELL = "lineitem_full.q1"
 #: slots of every program past a split's ``PARTIAL`` merge: the bucket of
@@ -99,7 +99,7 @@ def _ask(programs, one_chip):
 
 @pytest.mark.parametrize("kind", ["Hash", "Range"])
 def test_string_key_exchange_compiles_for_v5e(
-        kind, q1_programs, one_chip, no_persistent_cache):
+        kind, q1_programs, one_chip):
     """``jit_exchange`` with ``murmur3`` over string bytes (hash) and with
     the chunked string comparison against the sampled bounds, which are
     constants of the program (range); both sort and gather byte planes."""
@@ -117,7 +117,7 @@ def test_string_key_exchange_compiles_for_v5e(
 
 @pytest.mark.parametrize("word", ["exchange_slice", "exchange_concat"])
 def test_shuffle_piece_program_with_byte_planes_compiles_for_v5e(
-        word, q1_programs, one_chip, no_persistent_cache):
+        word, q1_programs, one_chip):
     programs = q1_programs.get(word)
     assert programs, sorted(q1_programs)
     for fn, args, kw, key in programs:
@@ -126,7 +126,7 @@ def test_shuffle_piece_program_with_byte_planes_compiles_for_v5e(
 
 
 def test_merge_on_plain_string_keys_compiles_for_v5e(
-        q1_programs, one_chip, no_persistent_cache):
+        q1_programs, one_chip):
     """The ``agg_update`` program that merges partials whose keys are
     plain strings: a split's ``PARTIAL`` merge of its row groups'
     partials, and the ``FINAL`` merge of the exchanged ones."""
@@ -139,7 +139,7 @@ def test_merge_on_plain_string_keys_compiles_for_v5e(
 
 
 def test_local_sort_on_string_keys_compiles_for_v5e(
-        q1_programs, one_chip, no_persistent_cache):
+        q1_programs, one_chip):
     programs = q1_programs.get("sort")
     assert programs and len(programs) == 2, sorted(q1_programs)  # a partition
     _ask(programs, one_chip)

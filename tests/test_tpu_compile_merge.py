@@ -20,7 +20,7 @@ import pytest
 import jax
 
 from tpu_compile_asks import (  # noqa: F401  (fixtures)
-    CAP, compile_all, load_cell, no_persistent_cache, one_chip, topo)
+    CAP, compile_all, load_cell, one_chip, topo)
 
 ROW_GROUP = 16384
 #: the bucket of Q1's four groups
@@ -84,7 +84,7 @@ def _at_timed_capacity(partial):
 @pytest.mark.parametrize("parts", [(29,), (16, 13)],
                          ids=["lineitem.q1", "lineitem_full.q1"])
 def test_merge_at_the_rows_held_compiles_for_v5e(
-        parts, captured_merge, one_chip, no_persistent_cache):
+        parts, captured_merge, one_chip):
     from spark_rapids_tpu.columnar.column import choose_capacity
     from spark_rapids_tpu.exec import aggregate as XA
     from spark_rapids_tpu.expr.values import DictV

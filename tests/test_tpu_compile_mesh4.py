@@ -3,12 +3,11 @@
 for four DESCRIBED v5e chips. The rules are ``tpu_compile_asks``'s
 docstring."""
 from tpu_compile_asks import (  # noqa: F401  (fixtures)
-    compile_mesh_program_for_four_chips, load_cell, no_persistent_cache,
-    topo)
+    compile_mesh_program_for_four_chips, load_cell, topo)
 
 
 def test_mesh_aggregate_compiles_for_four_v5e_chips(
-        topo, no_persistent_cache, tmp_path):
+        topo, tmp_path):
     """The ``shuffle.mode=ici`` program of the quantity report:
     ``TpuMeshAggregateExec``'s shard_map groupby with its all_to_all
     exchange, compiled for four DESCRIBED chips. Without

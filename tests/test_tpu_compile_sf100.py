@@ -4,13 +4,14 @@ cell runs it. The rules are ``tpu_compile_asks``'s docstring."""
 from unittest import mock
 
 import numpy as np
+import pytest
 
 import jax
 
 from tpu_compile_asks import (  # noqa: F401  (fixtures)
     HBM_BYTES, IN_CHUNK_BRANCH, chunk_updates,
     compile_mesh_program_for_four_chips, computations_under_a_conditional,
-    instructions_named, load_cell, no_persistent_cache, row_sized_scatters,
+    instructions_named, load_cell, row_sized_scatters,
     topo)
 
 #: slots a shard of ``tpcds_sf100_store_sales_mesh4``: up to 73.4 M rows padded
@@ -18,8 +19,13 @@ from tpu_compile_asks import (  # noqa: F401  (fixtures)
 SF100_SHARD_CAP = 1 << 27
 
 
+# slow: 474 s beside only the other compile asks, 733-822 s of one worker
+# in a cold run of the suite under six workers on an 8-core host: more than
+# half the suite's 1,470 s budget. The benchmark's four-chip cell runs this
+# program on the chip; run the file by hand after a change to exec/mesh.py.
+@pytest.mark.slow
 def test_mesh_aggregate_compiles_at_sf100_shard_capacity(
-        topo, no_persistent_cache, tmp_path):
+        topo, tmp_path):
     """The same SPMD aggregate at the capacity the benchmark's four-chip
     cell runs it: 2^27 slots a shard, the planes of a cached relation
     (``store_sales_sf100.cached_report.mesh4``). 12.9 GB of planes are not

@@ -10,13 +10,14 @@ Rules the asks keep (the suite runs under six xdist workers, each of which
 imports every test file): the topology is described inside a module-scoped
 fixture that skips when it cannot be — never at import (this module
 describes nothing when imported), never in conftest, never autouse; no
-child process; the persistent compile cache is off around the compiles (a
-TPU executable written there cannot be read back without a chip). One file
-a program family, so that ``--dist loadfile`` gives each its own worker,
-and ``conftest.py`` collects the three first: they are the suite's longest
-files and must not start last. Three workers then hold the TPU's library at
-once, which the driver's command allows (``ALLOW_MULTIPLE_LIBTPU_LOAD=1``,
-never set by this repo); without it the second and third file skip.
+child process; no persistent compile cache (``conftest.py`` keeps it off:
+a TPU executable written there cannot be read back without a chip). One file
+a program family, each named in ``placement.COMPILE_ASKS``: they are the
+suite's longest files and must not start last, so ``conftest.py`` starts
+each at once, whole, on a worker of its own, under xdist's ``load`` and
+``loadfile`` modes alike. Several workers then hold the TPU's library at
+once, which the tier-1 command allows (``ALLOW_MULTIPLE_LIBTPU_LOAD=1``,
+never set by this repo); without it all but the first file skip.
 
 Engine code that asks ``jax.default_backend()`` while planning or tracing
 would take its CPU branch here, so the asks patch that answer to ``"tpu"``
@@ -85,18 +86,6 @@ def one_chip(topo):
     from jax.sharding import SingleDeviceSharding
 
     return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module")
-def no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
 
 
 def on(sharding, tree):
